@@ -56,13 +56,24 @@ def cmd_info(args) -> int:
     jax.config.update("jax_enable_x64", True)
     from blaze_tpu.runtime import native
 
+    from blaze_tpu.config import get_config, resolve_core_choice
+
     lib = native.get_lib()
+    cfg = get_config()
     info = {
         "version": __import__("blaze_tpu").__version__,
         "backend": jax.default_backend(),
         "devices": [str(d) for d in jax.devices()],
         "native_host_lib": bool(lib),
         "x64": bool(jax.config.jax_enable_x64),
+        # what `auto` resolves to on this backend (config.py)
+        "cores": {
+            "group": resolve_core_choice(
+                "BLAZE_GROUP_CORE", cfg.group_core),
+            "join": resolve_core_choice("BLAZE_JOIN_CORE", cfg.join_core),
+            "sort": resolve_core_choice("BLAZE_SORT_CORE", cfg.sort_core),
+        },
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
     }
     print(json.dumps(info, indent=2))
     return 0
@@ -334,8 +345,8 @@ def cmd_mesh_dryrun(args) -> int:
     TaskDefinition differential) on an n-device virtual CPU mesh in a
     FRESH subprocess (the platform choice freezes at first backend
     init), and emit {n_devices, rc, ok, skipped, tail} JSON. Skips
-    cleanly (skipped=true, rc 0) when jax lacks shard_map or the
-    repo-root driver entry is not importable."""
+    cleanly (skipped=true, rc 0) when the repo-root driver entry is
+    not importable."""
     import os
     import subprocess
 
@@ -356,17 +367,6 @@ def cmd_mesh_dryrun(args) -> int:
             print(text)
         return 0 if (doc["ok"] or doc["skipped"]) else 1
 
-    try:
-        from jax import shard_map  # noqa: F401
-    except ImportError:
-        try:
-            from jax.experimental.shard_map import (  # noqa: F401
-                shard_map,
-            )
-        except ImportError:
-            doc.update(skipped=True,
-                       tail="jax lacks shard_map; mesh tier skipped\n")
-            return emit()
     if not os.path.exists(os.path.join(root, "__graft_entry__.py")):
         doc.update(skipped=True,
                    tail="__graft_entry__.py not found at repo root\n")
@@ -435,16 +435,6 @@ def cmd_mesh_attr(args) -> int:
     root = os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))
     )
-    skip = None
-    try:
-        from jax import shard_map  # noqa: F401
-    except ImportError:
-        try:
-            from jax.experimental.shard_map import (  # noqa: F401
-                shard_map,
-            )
-        except ImportError:
-            skip = "jax lacks shard_map; mesh tier skipped"
 
     def emit(doc) -> int:
         text = json.dumps(doc, indent=2)
@@ -458,10 +448,6 @@ def cmd_mesh_attr(args) -> int:
         else:
             print(text)
         return 0 if (doc.get("ok", True) or doc.get("skipped")) else 1
-
-    if skip is not None:
-        return emit({"format": "blaze-meshattr-v1", "ok": False,
-                     "skipped": True, "tail": skip})
 
     def child(n_dev: int) -> dict:
         env = dict(os.environ)
@@ -824,6 +810,27 @@ def cmd_regress(args) -> int:
     return 0
 
 
+def _place_compile_cache() -> None:
+    """The persistent compile cache is placed from outside: where
+    JAX_COMPILATION_CACHE_DIR is set jax already reads it and nothing
+    is set here; otherwise it lives at a fixed path in the checkout
+    (the path is part of the cache key - bench.py and run_tests.py
+    hand their children the same one)."""
+    import os
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", ".jax_cache",
+        ),
+    )
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="blaze_tpu")
     sub = p.add_subparsers(dest="cmd", required=True)
@@ -1146,6 +1153,7 @@ def main(argv=None) -> int:
                     help="include every class in the report, not "
                          "just _all")
     args = p.parse_args(argv)
+    _place_compile_cache()
     return {
         "info": cmd_info,
         "run-task": cmd_run_task,

@@ -28,9 +28,11 @@ source-row permutation. Data columns of ANY dtype then move by a
 single bit-exact gather - one kernel launch serves every column
 compacted by the same mask.
 
-Tested with interpret=True on CPU (tests/test_pallas_kernels.py);
-hardware enablement follows the same bench-gated path as the
-segmented-reduce kernel.
+Tested with interpret=True on CPU (tests/test_pallas_kernels.py) and
+nowhere else: the v5e compiler REFUSES this kernel (the (1, 1) SMEM
+count block is not a legal TPU block shape - the refusal is pinned in
+tests/test_chip_compile.py), it has never run on a chip, and nothing in
+blaze_tpu/ calls it (ROADMAP D7).
 """
 
 from __future__ import annotations

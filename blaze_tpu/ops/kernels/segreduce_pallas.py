@@ -21,10 +21,15 @@ direct-domain branch lives: group counts bounded by a few thousand
 same contraction with +/-inf masking and a max-reduction instead of a
 dot - still VPU/MXU shaped, no scatter anywhere.
 
-Tested with interpret=True on CPU (tests/test_pallas_kernels.py);
-auto-enabled on TPU hardware via ops/hash_aggregate's segops once the
-end-of-round bench validates it against the XLA scatter path
-(bench.py tpu_core_probe).
+Tested with interpret=True on CPU (tests/test_pallas_kernels.py) and
+nowhere else: the v5e compiler REFUSES both kernels. First the
+(_K_BLK // 128, 128) = (4, 128) output block is not a legal TPU block
+shape (pinned in tests/test_chip_compile.py); with a 1024-wide k-tile
+Mosaic next refuses the in-kernel (8, 128) -> (1024, 1) relayout of
+`gid` ("unsupported shape cast") and the sum kernel trips the 32-bit
+scalar rule under x64. It has never run on a chip, and the
+BLAZE_SEGREDUCE selector that reached it from ops/hash_aggregate is
+gone (ROADMAP D7 decides the file's fate).
 """
 
 from __future__ import annotations

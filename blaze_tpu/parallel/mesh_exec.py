@@ -58,10 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax exposes it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from blaze_tpu.batch import Column, ColumnBatch
 from blaze_tpu.errors import ErrorClass, classify

@@ -81,15 +81,14 @@ class MeshGroupByExec(PhysicalOp):
             elif a.fn is AggFn.AVG:
                 agg_fields.append(Field(n, DataType.float64(), True))
             else:
-                agg_fields.append(
-                    Field(
-                        n,
-                        infer_dtype(
-                            ir.bind(a.child, in_schema), in_schema
-                        ),
-                        True,
-                    )
-                )
+                ct = infer_dtype(ir.bind(a.child, in_schema), in_schema)
+                if a.fn is AggFn.SUM and (ct.is_integer
+                                          or ct.is_floating):
+                    # SUM widens (exprs/typing.py): the mesh result
+                    # carries the single-device aggregate's type
+                    ct = (DataType.int64() if ct.is_integer
+                          else DataType.float64())
+                agg_fields.append(Field(n, ct, True))
         self._schema = Schema(key_fields + agg_fields)
         # program identity is structural (fleet/program_cache): a fresh
         # lowering of the same plan shape on the same mesh reuses the

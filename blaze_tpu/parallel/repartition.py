@@ -29,10 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax exposes it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from blaze_tpu.types import DataType
 from blaze_tpu.exprs.hashing import hash_columns_device, pmod

@@ -27,10 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax exposes it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from blaze_tpu.types import DataType, Schema, TypeId
 from blaze_tpu.exprs import ir
@@ -189,7 +186,15 @@ class DistributedGroupBy:
                         )
                     )
                 elif a.fn in (AggFn.SUM, AggFn.AVG):
-                    v = jnp.where(s_live, sx, jnp.zeros_like(sx))
+                    # accumulate in SUM's result type (int64 /
+                    # float64, exprs/typing.py) like the single-device
+                    # aggregate: an int32 or f32 column must not wrap
+                    # or round in its own width
+                    wide = (jnp.float64
+                            if jnp.issubdtype(sx.dtype, jnp.floating)
+                            else jnp.int64)
+                    v = jnp.where(s_live, sx, jnp.zeros_like(sx)).astype(
+                        wide)
                     states.append(
                         jax.ops.segment_sum(v, gid, num_segments=cap)
                     )

@@ -62,6 +62,17 @@ _MAX_RETAINED = 1024  # terminal queries kept for poll/report
 _service_instance_ids = itertools.count()
 
 
+def _device_info() -> dict:
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+
+
 class QueryService:
     def __init__(
         self,
@@ -730,6 +741,10 @@ class QueryService:
                 # by a dead router's abandoned detached queries is
                 # reclaimed after this long (null = disabled)
                 "orphan_ttl_s": self.orphan_ttl_s,
+                # the device this process holds, as jax reports it: a
+                # client cannot ask a second process while this one
+                # owns the chip
+                "device": _device_info(),
             },
             # streaming data plane (service/stream.py): the ring cap +
             # stall budget, and the high-water gauge the slow-consumer

@@ -69,8 +69,6 @@ def exchange_query_names():
 
 
 def _env(rows=None):
-    import tempfile
-
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -82,7 +80,7 @@ def _env(rows=None):
     # the time with a fraction of the live compilations)
     env.setdefault(
         "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "blaze_jax_cache"),
+        os.path.join(REPO, "benchmarks", ".jax_cache"),
     )
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
     # disable the aggregate ladder's small first tier in the suite:
@@ -282,19 +280,7 @@ def mesh_smoke() -> bool:
     differential battery, chaos `mesh.exchange` coverage, and the
     QueryService mesh-mode acceptance pin. Forces an 8-device virtual
     host mesh via XLA_FLAGS ITSELF (the repo conftest does the same
-    for plain pytest runs, but this suite must not depend on it) and
-    skips cleanly when the installed jax lacks shard_map."""
-    probe = subprocess.run(
-        [sys.executable, "-c",
-         "try:\n"
-         "    from jax import shard_map\n"
-         "except ImportError:\n"
-         "    from jax.experimental.shard_map import shard_map\n"],
-        capture_output=True, text=True, env=_env(),
-    )
-    if probe.returncode != 0:
-        print("[SKIP] mesh suite (jax lacks shard_map)", flush=True)
-        return True
+    for plain pytest runs, but this suite must not depend on it)."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         flags = (
@@ -312,19 +298,7 @@ def fleet_smoke() -> bool:
     differential battery, the `fleet.exchange` chaos degrade ladder,
     the SIGKILL-mid-stage failover, and the device-claim plane
     (tenant budgets / DRAINING-shaped capacity denials / waiter
-    wake). Same 8-device forcing and shard_map skip as the mesh
-    suite."""
-    probe = subprocess.run(
-        [sys.executable, "-c",
-         "try:\n"
-         "    from jax import shard_map\n"
-         "except ImportError:\n"
-         "    from jax.experimental.shard_map import shard_map\n"],
-        capture_output=True, text=True, env=_env(),
-    )
-    if probe.returncode != 0:
-        print("[SKIP] fleet suite (jax lacks shard_map)", flush=True)
-        return True
+    wake). Same 8-device forcing as the mesh suite."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         flags = (
@@ -569,8 +543,7 @@ def main():
                          "schema")
     ap.add_argument("--mesh", action="store_true",
                     help="mesh execution tier suite only: forces an "
-                         "8-device virtual host mesh itself; skips "
-                         "cleanly if jax lacks shard_map")
+                         "8-device virtual host mesh itself")
     ap.add_argument("--stream", action="store_true",
                     help="streaming suite only: bounded-ring "
                          "backpressure, slow-consumer stall aborts, "

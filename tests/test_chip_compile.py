@@ -1,0 +1,203 @@
+"""Compile checks for the branches only the chip takes.
+
+tier-1 runs on the CPU (conftest.py) and the engine asks
+`jax.default_backend()`, so the TPU's own branches - the murmur3 Pallas
+kernel in the shuffle writer, the (hi, lo) f64 pack route, the
+segmented-reduce Pallas kernel - are never walked there, and interpret
+mode cannot see what the Mosaic compiler refuses (block tiling, X64
+rewrites). The TPU compiler is installed here and compiles for a chip
+that is DESCRIBED, not attached: each test lowers one kernel or jitted
+step for one device of a `v5e:2x2` topology at the size the chip smoke
+runs (8,388,608 rows). A compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file), everything compiles in the test's own
+process, and the persistent compile cache is off around the compiles
+(an entry written without a chip cannot be read back).
+"""
+
+import os
+from functools import partial
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROWS = 8 << 20
+N_PARTS = 200
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower + compile `fn` for the described chip from shapes alone;
+    raises what the chip's compiler would raise."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [16384, ROWS])
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.int64])
+def test_murmur3_partition_ids(one_chip, dtype, rows):
+    """The one Pallas kernel on the default chip path
+    (ops/shuffle_writer.py selects it when the backend is "tpu"), at
+    the serve batch capacity and at the whole fact table."""
+    from blaze_tpu.ops.kernels import murmur3_pallas as mp
+
+    fn = (mp.partition_ids_int32 if dtype == jnp.int32
+          else mp.partition_ids_int64)
+    compiled = _compile(
+        partial(fn, n_parts=N_PARTS, interpret=False), one_chip,
+        ((rows,), dtype),
+    )
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_masked_stats(one_chip, dtype):
+    """stats_pallas has no call site yet; it is the murmur3 layout and
+    the compiler accepts it."""
+    from blaze_tpu.ops.kernels import stats_pallas as sp
+
+    assert sp.supports(ROWS, dtype)
+    compiled = _compile(
+        partial(sp.masked_stats, interpret=False), one_chip,
+        ((ROWS,), dtype), ((ROWS,), jnp.bool_),
+    )
+    assert _has_kernel(compiled)
+
+
+# one batch's worth of every engine dtype, f64 included
+_PACK_SHAPES = (
+    ((ROWS,), jnp.int32), ((ROWS,), jnp.int64), ((ROWS,), jnp.float32),
+    ((ROWS,), jnp.float64), ((ROWS,), jnp.bool_),
+)
+
+
+def test_pack_f64_pairs(one_chip):
+    """D2H pack with f64 as (hi, lo) f32 pairs - the route
+    runtime/pack.py takes on any non-CPU backend."""
+    from blaze_tpu.runtime.pack import _build_pack
+
+    pack = _build_pack(None, f64_pairs=True)
+    _compile(lambda *bufs: pack(list(bufs)), one_chip, *_PACK_SHAPES)
+
+
+def test_pack_f64_direct_bitcast_is_refused(one_chip):
+    """Why the pair route exists: the TPU compiler's X64 rewrite does
+    not implement bitcast-convert from f64. If this starts compiling,
+    `_f64_pairs()` can go."""
+    from blaze_tpu.runtime.pack import _build_pack
+
+    pack = _build_pack(None, f64_pairs=False)
+    with pytest.raises(Exception, match="(?i)x64|bitcast"):
+        _compile(lambda b: pack([b]), one_chip, ((ROWS,), jnp.float64))
+
+
+def test_unpack_f64_pairs(one_chip):
+    from blaze_tpu.runtime.pack import _build_unpack, _packed_nbytes
+
+    metas = tuple(
+        (str(np.dtype(dt)), shape) for shape, dt in _PACK_SHAPES
+    )
+    total = sum(
+        # f64 travels as two f32: same 8 bytes per value
+        _packed_nbytes(shape, np.dtype(dt)) for shape, dt in _PACK_SHAPES
+    )
+    _compile(
+        _build_unpack(metas, f64_pairs=True), one_chip,
+        ((total,), jnp.uint8),
+    )
+
+
+def test_q6_step(one_chip):
+    """`__graft_entry__.entry()`: the q6 filter/project/aggregate step."""
+    import __graft_entry__ as graft
+
+    step, args = graft.entry()
+    shapes = [((ROWS,), a.dtype) for a in args[:3]] + [((), args[3].dtype)]
+    _compile(step, one_chip, *shapes)
+
+
+# ---- kernels with no path from blaze_tpu/: the refusal, on record ----
+# Interpret mode passes all of these (tests/test_pallas_kernels.py);
+# the v5e compiler does not. The BLAZE_SEGREDUCE selector that reached
+# segreduce_pallas is gone and compact_pallas never had a call site
+# (ROADMAP D7 decides their fate). A test here that starts FAILING means
+# the kernel now compiles: move it up among the checks above.
+
+def _refused(one_chip, fn, *shapes):
+    with pytest.raises(Exception) as info:
+        _compile(fn, one_chip, *shapes)
+    return str(info.value)
+
+
+@pytest.mark.parametrize("k", [1024, 4096])
+def test_segreduce_sum_is_refused(one_chip, monkeypatch, k):
+    from blaze_tpu.ops.kernels import segreduce_pallas as sr
+
+    # the kernel asks the backend whether to interpret: steer it here
+    monkeypatch.setattr(sr, "_interpret", lambda: False)
+    msg = _refused(
+        one_chip, lambda gid, v: sr._call(sr._sum_kernel, gid, v, k),
+        ((ROWS,), jnp.int32), ((ROWS,), jnp.float32),
+    )
+    # the (4, 128) output block: _K_BLK // 128 is not a multiple of 8
+    assert "divisible by 8 and 128" in msg
+
+
+@pytest.mark.parametrize("k", [1024, 4096])
+def test_segreduce_minmax_is_refused(one_chip, monkeypatch, k):
+    from blaze_tpu.ops.kernels import segreduce_pallas as sr
+
+    monkeypatch.setattr(sr, "_interpret", lambda: False)
+    msg = _refused(
+        one_chip,
+        lambda gid, v: sr._call(
+            partial(sr._minmax_kernel, is_min=True), gid, v, k),
+        ((ROWS,), jnp.int32), ((ROWS,), jnp.float32),
+    )
+    assert "divisible by 8 and 128" in msg
+
+
+def test_compact_perm_is_refused(one_chip, monkeypatch):
+    from blaze_tpu.ops.kernels import compact_pallas as cp
+
+    monkeypatch.setattr(cp, "_interpret", lambda: False)
+    # the undecorated function, freshly jitted: the module's own jit
+    # may hold an interpret-mode trace from another test in this worker
+    msg = _refused(
+        one_chip, lambda keep: cp.compact_perm.__wrapped__(keep),
+        ((ROWS,), jnp.bool_),
+    )
+    # outputs[1], the (1, 1) SMEM count block
+    assert "divisible by 8 and 128" in msg

@@ -155,6 +155,30 @@ def test_mesh_groupby_differential_vs_single_device():
     pd.testing.assert_frame_equal(got, want, check_dtype=False)
 
 
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+def test_mesh_groupby_sum_widens_like_single_device(dtype):
+    """SUM over a narrow column: the mesh result carries the
+    single-device aggregate's type (int64 / float64) and accumulates in
+    it - 4 x 2^30 wraps in int32, and 2^24 + 1 + 1 + 1 stays 2^24 in
+    f32."""
+    vals = ([1 << 30] * 4 if dtype is np.int32
+            else [float(1 << 24), 1.0, 1.0, 1.0])
+    cb = ColumnBatch.from_arrow(pa.record_batch({
+        "k": np.zeros(4, dtype=np.int64),
+        "v": np.asarray(vals, dtype=dtype),
+    }))
+
+    def plan():
+        return agg_plan(MemoryScanExec([[cb]], cb.schema))
+
+    want = table_sorted(plan())
+    low = lower_plan_to_mesh(plan(), mode="on")
+    assert isinstance(low, MeshGroupByExec)
+    got = table_sorted(low)
+    pd.testing.assert_frame_equal(got, want, check_dtype=True)
+    assert got["s"][0] == sum(vals)
+
+
 def test_mesh_groupby_skewed_keys():
     """Every row hashes to ONE owner device: the all_to_all exchange
     funnels all partial states to a single shard."""

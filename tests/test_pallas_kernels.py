@@ -1,5 +1,8 @@
-"""Pallas kernel tests (interpret mode on the CPU test mesh; the same
-kernels compile to Mosaic on real TPU - validated in bench/driver runs)."""
+"""Pallas kernel tests, interpret mode on the CPU test mesh: semantics
+only. Whether the TPU compiler accepts a kernel is another matter -
+tests/test_chip_compile.py compiles each for a described v5e (murmur3
+and stats are accepted, segreduce and compact are refused), and only
+murmur3 has run on a chip (chip_smoke.py)."""
 
 import numpy as np
 import jax.numpy as jnp
