@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from blaze_tpu.config import get_config
+from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.types import (
     DataType,
     Field,
@@ -270,6 +271,16 @@ class ColumnBatch:
         round trip regardless of column count), sliced on device to the
         smallest shape bucket covering the live rows so padding beyond it
         never crosses the wire."""
+        if obs_trace.ACTIVE:
+            # obs seam: the d2h stage - the packed transfer, its wait
+            # on the device, and the Arrow assembly
+            with obs_trace.span("d2h", rows=self.num_rows) as sp:
+                rb = self._to_arrow()
+                sp.tag(bytes=rb.nbytes)
+                return rb
+        return self._to_arrow()
+
+    def _to_arrow(self):
         import pyarrow as pa
 
         from blaze_tpu.runtime.pack import get_packed

@@ -501,8 +501,10 @@ def run_attr_probe(n_dev: int, rows: int = 1 << 20,
             "blaze_mesh_retrace_total", op=op_key
         )
         # re-trace demonstration: a FRESH lowering of the SAME logical
-        # plan re-pays the trace the process already did - the
-        # cache-key-churn cost the retrace counter exists to expose
+        # plan. Since the program cache (fleet/program_cache.py) it
+        # finds the traced holder and `retrace_total` stays 0; a
+        # count here is the cache-key churn the counter exists to
+        # expose
         if mesh_lowered:
             relowered = lower_plan_to_mesh(sandwich(), mode="on")
             t0 = time.perf_counter()

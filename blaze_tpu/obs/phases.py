@@ -43,6 +43,8 @@ import json
 import threading
 from typing import Any, Dict, List, Optional
 
+from blaze_tpu.obs import trace as obs_trace
+
 # canonical phase order (rendering + artifact stability)
 PHASES = (
     "queue_wait",   # SUBMIT -> ADMITTED (admission queue)
@@ -62,6 +64,7 @@ PHASES = (
     "mesh_stage_in",  # stack_partitions host stack + device_put
     "mesh_launch",    # the compiled mesh program call
     "mesh_sync",      # block_until_ready on the outputs
+    "mesh_dcn",       # fleet tier: DCN exchange round trips
     "mesh_gather",    # batched device_get at the mesh boundary
     "execute",      # RUNNING -> terminal (the whole execution)
     "stream",       # FETCH result streaming
@@ -93,7 +96,25 @@ SPAN_PHASE = {
     "mesh_stage_in": "mesh_stage_in",
     "mesh_launch": "mesh_launch",
     "mesh_sync": "mesh_sync",
+    "mesh_dcn": "mesh_dcn",
     "mesh_gather": "mesh_gather",
+    # host stages (obs/trace.py STAGE_SPANS) fold under their own
+    # names into POLL's per-task `stages` table only: none is in
+    # PHASES, so the rollup and PHASE_BASELINE.json stay as they were
+    "decode_batch": "decode_batch",
+    "compact": "compact",
+    "d2h": "d2h",
+    "shuffle_partition": "shuffle_partition",
+    "shuffle_encode": "shuffle_encode",
+    "shuffle_finalize": "shuffle_finalize",
+    "frame_encode": "frame_encode",
+    "frame_send": "frame_send",
+}
+
+# POLL's `stages` (service/query.py): the stage spans that are phases
+STAGE_PHASE = {
+    n: SPAN_PHASE[n] for n in sorted(obs_trace.STAGE_SPANS)
+    if SPAN_PHASE.get(n)
 }
 
 ALL_CLASS = "_all"
@@ -338,6 +359,7 @@ PHASE_BANDS: Dict[str, tuple] = {
     "mesh_stage_in": (3.0, 0.25),
     "mesh_launch": (3.0, 0.25),
     "mesh_sync": (3.0, 0.25),
+    "mesh_dcn": (3.0, 0.25),
     "mesh_gather": (3.0, 0.25),
 }
 

@@ -49,6 +49,7 @@ from __future__ import annotations
 import collections
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -69,6 +70,53 @@ _MAX_RETAINED_TRACES = 256
 # they start and finish on different threads, so they get their own
 # strictly-sequential track instead of a real thread's
 LIFECYCLE_TID = 0
+
+# Stage spans: a host stage that begins and ends on one thread with no
+# `yield` inside it. `_SpanCtx` (and nothing else) gives these two more
+# things: a `jax.profiler.TraceAnnotation("blaze.<name>")`, so the span
+# also lies on the profiler's host plane whenever a profiler session
+# runs (one TraceMe level check when none does), and the thread's CPU
+# time between its two ends (`Span.cpu_ns`; wall less cpu is what the
+# thread waited: GIL, device, disk). Not stages: spans a generator is
+# suspended inside (execute_partition, attempt, execute, the file
+# range's parquet_decode), the lifecycle spans `record_span` writes
+# after the fact, and kernel_dispatch, which the runtime's own
+# PjitFunction events already name on the profiler's side.
+STAGE_SPANS = frozenset({
+    "decode_batch", "h2d", "compact", "d2h",
+    "shuffle_partition", "shuffle_encode", "shuffle_finalize",
+    "frame_encode", "frame_send",
+    "cache_probe", "service_admit",
+})
+
+
+# one getpid for the process, not one a span: a system call is dear
+# where the serving host is sandboxed (PERF.md, PR 25)
+_pid = os.getpid()
+
+
+def _after_fork() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork)
+
+_TRACE_ME = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _stage_annotation(name: str):
+    """The profiler-side twin of a stage span, or None in a process
+    that has not imported jax (the router): never imported for this."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _TRACE_ME = jax.profiler.TraceAnnotation
+    if not _TRACE_ME.is_enabled():  # no profiler session: TraceMe's check
+        return None
+    return _TRACE_ME("blaze." + name)
 
 
 def enable() -> None:
@@ -98,7 +146,7 @@ def _reset_for_tests() -> None:
 
 class Span:
     __slots__ = ("name", "span_id", "parent_id", "start_ns", "end_ns",
-                 "pid", "tid", "tags", "events")
+                 "pid", "tid", "tags", "events", "cpu_ns")
 
     def __init__(self, name: str, span_id: int, parent_id: int,
                  start_ns: int, pid: int, tid: int,
@@ -112,6 +160,7 @@ class Span:
         self.tid = tid
         self.tags: Dict[str, Any] = dict(tags) if tags else {}
         self.events: List[Dict[str, Any]] = []
+        self.cpu_ns = 0  # stage spans only: thread CPU time inside
 
     def tag(self, **tags: Any) -> None:
         self.tags.update(tags)
@@ -162,7 +211,7 @@ class TraceRecorder:
                 self.dropped += 1
                 return None
             s = Span(name, next(self._ids), parent_id, start_ns,
-                     os.getpid(), tid, tags)
+                     _pid, tid, tags)
             self.spans.append(s)
             # invariant: the root contains every span. Retroactive
             # spans (queue_wait starts at SUBMIT, microseconds before
@@ -219,8 +268,8 @@ class TraceRecorder:
         with self._lock:
             return [s.to_dict() for s in self.spans]
 
-    def phase_totals(self, phase_of: Dict[str, Optional[str]]
-                     ) -> Dict[str, float]:
+    def phase_totals(self, phase_of: Dict[str, Optional[str]],
+                     stage_table: bool = False) -> Dict[str, Any]:
         """Sum span durations into phase totals (seconds) keyed by
         `phase_of[span.name]` - the allocation-free form of
         `phases.fold_span_dicts(rec.to_dicts())`. The terminal hook
@@ -229,20 +278,42 @@ class TraceRecorder:
         a retried multi-partition query that is thousands of
         allocations per query on the serving path, for a result this
         fold immediately throws away. One pass over the live Span
-        objects, one small output dict."""
-        totals: Dict[str, float] = {}
+        objects, one small output dict.
+
+        With `stage_table` each value is `{"wall_s", "cpu_s", "n"}`
+        (POLL's `stages`), and a span folded into a phase is taken out
+        of the folded span that encloses it (h2d out of decode_batch),
+        so that the stages of one thread never count a second twice."""
+        acc: Dict[str, List[int]] = {}  # phase -> [wall_ns, cpu_ns, n]
         with self._lock:
-            for s in self.spans:
+            spans = self.spans
+            for s in spans:
                 phase = phase_of.get(s.name)
                 if not phase:
                     continue
                 end = s.end_ns
                 if end is None or end < s.start_ns:
                     continue
-                totals[phase] = (
-                    totals.get(phase, 0.0) + (end - s.start_ns) / 1e9
-                )
-        return totals
+                a = acc.get(phase)
+                if a is None:
+                    a = acc[phase] = [0, 0, 0]
+                a[0] += end - s.start_ns
+                a[1] += s.cpu_ns
+                a[2] += 1
+                if stage_table and 0 < s.parent_id <= len(spans):
+                    # span ids count from 1 in append order
+                    up = spans[s.parent_id - 1]
+                    outer = acc.get(phase_of.get(up.name))
+                    if outer is not None and up.end_ns is not None:
+                        outer[0] -= end - s.start_ns
+                        outer[1] -= s.cpu_ns
+        if stage_table:
+            return {
+                p: {"wall_s": round(max(w, 0) / 1e9, 6),
+                    "cpu_s": round(max(c, 0) / 1e9, 6), "n": n}
+                for p, (w, c, n) in acc.items()
+            }
+        return {p: a[0] / 1e9 for p, a in acc.items()}
 
     def attach_subtree(self, span_dicts: List[Dict[str, Any]],
                        parent: Optional[Span] = None) -> int:
@@ -327,7 +398,8 @@ NULL = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("_rec", "_name", "_tags", "span", "_pushed")
+    __slots__ = ("_rec", "_name", "_tags", "span", "_pushed", "_ann",
+                 "_cpu0")
 
     def __init__(self, rec: TraceRecorder, name: str,
                  tags: Dict[str, Any]):
@@ -336,6 +408,7 @@ class _SpanCtx:
         self._tags = tags
         self.span: Optional[Span] = None
         self._pushed = False
+        self._ann = self._cpu0 = None
 
     def __enter__(self):
         st = _stack()
@@ -346,11 +419,20 @@ class _SpanCtx:
         st.append((self._rec, sp))
         self.span = sp
         self._pushed = True
+        if self._name in STAGE_SPANS:
+            self._ann = _stage_annotation(self._name)
+            if self._ann is not None:
+                self._ann.__enter__()
+            self._cpu0 = time.thread_time_ns()
         return sp
 
     def __exit__(self, exc_type, exc, tb):
         if not self._pushed:
             return False
+        if self._cpu0 is not None:
+            self.span.cpu_ns = time.thread_time_ns() - self._cpu0
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1][1] is self.span:
             st.pop()

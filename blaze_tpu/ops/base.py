@@ -60,6 +60,9 @@ class ExecContext:
     # (planner/distribute.resolve_mesh_mode) - the serving tier's
     # mesh_mode knob threads through here
     mesh_mode: Optional[str] = None
+    # kernel launches made on behalf of this task alone
+    # (runtime/dispatch.py counts them; POLL's `task_dispatches`)
+    task_dispatches: int = 0
 
 
 class PhysicalOp:

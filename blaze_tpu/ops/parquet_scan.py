@@ -186,37 +186,51 @@ class ParquetScanExec(PhysicalOp):
                     groups = self._select_row_groups(pf, fr, filters)
                     if not groups:
                         continue
-                    for rb in pf.iter_batches(
+                    batches = pf.iter_batches(
                         batch_size=cfg.batch_size, row_groups=groups,
                         columns=read_names, use_threads=True,
-                    ):
-                        ctx.metrics.add("input_rows", rb.num_rows)
-                        ctx.metrics.add("input_batches", 1)
-                        if filters and cfg.host_filter_pushdown:
-                            before = rb.num_rows
-                            rb = _apply_host_filters(rb, filters)
-                            ctx.metrics.add(
-                                "pushdown_filtered_rows",
-                                before - rb.num_rows,
-                            )
-                        if rb.num_rows == 0:
-                            continue
-                        if present is None:
-                            yield ColumnBatch.from_arrow(rb)
-                        else:
-                            import pyarrow as pa
-
-                            sub = pa.record_batch(
-                                [rb.column(c) for c in keep_names],
-                                names=keep_names,
-                            )
-                            yield ColumnBatch.from_arrow_pruned(
-                                sub, self._schema, present
-                            )
+                    )
+                    while True:
+                        # obs seam: one batch's decode to host arrays
+                        # and its packing, a stage (no yield inside;
+                        # the range span above also holds the waits on
+                        # a full prefetch queue)
+                        with (obs_trace.span("decode_batch")
+                              if obs_trace.ACTIVE else obs_trace.NULL):
+                            rb = next(batches, None)
+                            if rb is None:
+                                break
+                            cb = self._decode_batch(
+                                rb, ctx, filters, keep_names, present)
+                        if cb is not None:
+                            yield cb
 
         # overlap parquet decode + H2D with downstream device compute
         # (SURVEY 7 streaming model: double-buffered host pipeline)
         yield from prefetch(decode(), depth=2)
+
+    def _decode_batch(self, rb, ctx: ExecContext, filters, keep_names,
+                      present) -> Optional[ColumnBatch]:
+        """One decoded RecordBatch to a packed device batch (None when
+        the pushed-down filters leave no row)."""
+        ctx.metrics.add("input_rows", rb.num_rows)
+        ctx.metrics.add("input_batches", 1)
+        if filters and ctx.config.host_filter_pushdown:
+            before = rb.num_rows
+            rb = _apply_host_filters(rb, filters)
+            ctx.metrics.add(
+                "pushdown_filtered_rows", before - rb.num_rows,
+            )
+        if rb.num_rows == 0:
+            return None
+        if present is None:
+            return ColumnBatch.from_arrow(rb)
+        import pyarrow as pa
+
+        sub = pa.record_batch(
+            [rb.column(c) for c in keep_names], names=keep_names,
+        )
+        return ColumnBatch.from_arrow_pruned(sub, self._schema, present)
 
     # ------------------------------------------------------------------
     def _select_row_groups(self, pf, fr: FileRange,

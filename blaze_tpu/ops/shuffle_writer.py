@@ -39,6 +39,7 @@ from blaze_tpu.exprs.hashing import (
 )
 from blaze_tpu.exprs.typing import infer_dtype
 from blaze_tpu.io.ipc import encode_ipc_segment
+from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.host_lower import lower_strings_host
 from blaze_tpu.ops.util import ensure_compacted, take_batch
@@ -77,7 +78,11 @@ class PartitionBuffers:
         )
         offsets = [0] * (self.num_partitions + 1)
         pos = 0
-        with open(path, "wb") as f:
+        # obs seam: a spill is file writing too (the thread that ran
+        # short of memory pays, whichever task's buffers these are)
+        with (obs_trace.span("shuffle_finalize", spill=True)
+              if obs_trace.ACTIVE else obs_trace.NULL), \
+                open(path, "wb") as f:
             for p in range(self.num_partitions):
                 offsets[p] = pos
                 f.write(self.buffers[p])
@@ -402,38 +407,50 @@ class ShuffleWriterExec(PhysicalOp):
                 rb_sorted = rb.take(order)
                 sorted_pids = pids[order]
             else:
-                pids = spark_partition_ids(
-                    aug, exprs, self.num_partitions
-                )
-                # scatter = one stable device argsort by partition id
-                pid_full = jnp.full(
-                    cb.capacity, self.num_partitions, dtype=jnp.int32
-                )
-                pid_full = pid_full.at[: len(pids)].set(
-                    jnp.asarray(pids)
-                )
-                order_dev = jnp.argsort(pid_full, stable=True)
-                rb_sorted = take_batch(
-                    cb, order_dev, cb.num_rows
-                ).to_arrow()
+                # obs seam: hash, sort by partition and gather, up to
+                # the read-back (`d2h`, inside to_arrow)
+                with (obs_trace.span("shuffle_partition")
+                      if obs_trace.ACTIVE else obs_trace.NULL):
+                    pids = spark_partition_ids(
+                        aug, exprs, self.num_partitions
+                    )
+                    # scatter = one stable device argsort by
+                    # partition id
+                    pid_full = jnp.full(
+                        cb.capacity, self.num_partitions,
+                        dtype=jnp.int32,
+                    )
+                    pid_full = pid_full.at[: len(pids)].set(
+                        jnp.asarray(pids)
+                    )
+                    order_dev = jnp.argsort(pid_full, stable=True)
+                    cb_sorted = take_batch(cb, order_dev, cb.num_rows)
+                rb_sorted = cb_sorted.to_arrow()
                 sorted_pids = np.sort(pids, kind="stable")
             counts = np.bincount(
                 sorted_pids, minlength=self.num_partitions
             )
-            start = 0
-            for p in range(self.num_partitions):
-                c = int(counts[p])
-                if c == 0:
-                    continue
-                part_rb = rb_sorted.slice(start, c)
-                bufs.append(
-                    p,
-                    encode_ipc_segment(
-                        part_rb, cfg.ipc_compression_level
-                    ),
-                )
-                start += c
+            # obs seam: one span a batch, not one a segment (200
+            # partitions x 64 batches would pass the span cap)
+            with (obs_trace.span("shuffle_encode")
+                  if obs_trace.ACTIVE else obs_trace.NULL) as sp:
+                start = segments = nbytes = 0
+                for p in range(self.num_partitions):
+                    c = int(counts[p])
+                    if c == 0:
+                        continue
+                    seg = encode_ipc_segment(
+                        rb_sorted.slice(start, c),
+                        cfg.ipc_compression_level,
+                    )
+                    bufs.append(p, seg)
+                    start += c
+                    segments += 1
+                    nbytes += len(seg)
+                sp.tag(segments=segments, bytes=nbytes)
             ctx.metrics.add("shuffle_rows_written", cb.num_rows)
-        lengths = bufs.finalize(self.data_file, self.index_file)
+        with (obs_trace.span("shuffle_finalize")
+              if obs_trace.ACTIVE else obs_trace.NULL):
+            lengths = bufs.finalize(self.data_file, self.index_file)
         ctx.metrics.add("shuffle_bytes_written", sum(lengths))
         return iter(())

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from blaze_tpu.config import get_config
+from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.types import Schema, TypeId
 from blaze_tpu.batch import Column, ColumnBatch, row_mask
 
@@ -51,6 +52,16 @@ def _compact_indices(mask: jax.Array, capacity: int):
 def compact(cb: ColumnBatch, mask: Optional[jax.Array] = None) -> ColumnBatch:
     """Keep rows where mask (AND the batch's own selection) is True, packed
     to the front (one D2H sync for the surviving row count)."""
+    if obs_trace.ACTIVE:
+        # obs seam: the compact stage - the live mask's three eager
+        # launches, the index program, the wait for the row count and
+        # the gather launch
+        with obs_trace.span("compact"):
+            return _compact(cb, mask)
+    return _compact(cb, mask)
+
+
+def _compact(cb: ColumnBatch, mask: Optional[jax.Array]) -> ColumnBatch:
     live = cb.live_mask()
     if mask is not None:
         live = live & mask

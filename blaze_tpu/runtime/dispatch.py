@@ -91,6 +91,36 @@ def record(kind: str, n: int = 1) -> None:
         _counts[kind] = _counts.get(kind, 0) + n
 
 
+# the task this thread launches for (`.ctx`, an ExecContext): the
+# executor's scope around each pull of a partition's stream, handed on
+# to prefetch workers. `_wrap_dispatch` counts each launch on it
+# (`task_dispatches`, POLL), whatever else runs in the process and
+# whether or not tracing is on
+_task = threading.local()
+
+
+def current_task():
+    return getattr(_task, "ctx", None)
+
+
+class task_scope:
+    """`with task_scope(ctx):` - `ctx` is this thread's task inside
+    (re-enterable: the executor enters it once a pull)."""
+
+    __slots__ = ("_ctx", "_outer")
+
+    def __init__(self, ctx):
+        self._ctx = ctx
+
+    def __enter__(self):
+        self._outer = current_task()
+        _task.ctx = self._ctx
+
+    def __exit__(self, *exc):
+        _task.ctx = self._outer
+        return False
+
+
 def snapshot() -> Dict[str, int]:
     with _lock:
         return dict(_counts)
@@ -136,7 +166,11 @@ def _wrap_dispatch(fn: Callable, kind: str,
             # (device reset, interconnect error) - off path is one
             # module-attribute load
             chaos.fire("kernel.dispatch", kind=kind)
-        record(kind)
+        ctx = getattr(_task, "ctx", None)
+        with _lock:
+            _counts[kind] = _counts.get(kind, 0) + 1
+            if ctx is not None:
+                ctx.task_dispatches += 1
         if obs_trace.ACTIVE:
             # obs seam: one span per kernel dispatch (the unit of the
             # perf model); no-op when no recorder is in scope. XLA

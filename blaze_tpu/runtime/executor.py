@@ -172,11 +172,20 @@ def execute_partition(op: PhysicalOp, partition: int, ctx: ExecContext
                     "task.execute", partition=partition,
                     task_id=ctx.task_id,
                 )
-            for cb in op.execute(partition, ctx):
-                cb = ensure_compacted(cb)
-                if cb.num_rows == 0:
-                    continue
-                rb = cb.to_arrow()
+            # every launch inside a pull is this task's
+            # (`task_dispatches`); the consumer runs between pulls
+            mine = dispatch.task_scope(ctx)
+            with mine:  # an operator may do all its work right here
+                stream = iter(op.execute(partition, ctx))
+            while True:
+                with mine:
+                    cb = next(stream, None)
+                    if cb is None:
+                        break
+                    cb = ensure_compacted(cb)
+                    if cb.num_rows == 0:
+                        continue
+                    rb = cb.to_arrow()
                 ctx.metrics.add("output_rows", rb.num_rows)
                 ctx.metrics.add("output_batches", 1)
                 yield rb

@@ -239,6 +239,16 @@ def put_packed_padded_lazy(
     dispatch total (batch.PackedColumnBatch owns that deferral)."""
     if not entries:
         return None, (), _f64_pairs()
+    if obs_trace.ACTIVE:
+        # obs seam: the scan path's h2d stage (pad + pack +
+        # device_put); `put_packed` above has no caller left in the
+        # engine, so its span alone never showed in a served task
+        with obs_trace.span("h2d", n_arrays=len(entries)):
+            return _put_packed_padded_lazy(entries)
+    return _put_packed_padded_lazy(entries)
+
+
+def _put_packed_padded_lazy(entries):
     pairs = _f64_pairs()
     norm = []
     for vals, cap, fill in entries:

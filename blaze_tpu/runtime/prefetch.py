@@ -15,6 +15,8 @@ import queue
 import threading
 from typing import Iterator, TypeVar
 
+from blaze_tpu.runtime import dispatch
+
 T = TypeVar("T")
 
 _SENTINEL = object()
@@ -26,13 +28,15 @@ def prefetch(it: Iterator[T], depth: int = 2) -> Iterator[T]:
     early consumer exit stops the producer."""
     q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
+    task = dispatch.current_task()  # the consumer's: the worker's too
 
     def worker():
         try:
-            for item in it:
-                if stop.is_set():
-                    return
-                q.put(item)
+            with dispatch.task_scope(task):
+                for item in it:
+                    if stop.is_set():
+                        return
+                    q.put(item)
             q.put(_SENTINEL)
         except BaseException as e:  # noqa: BLE001 - forwarded to consumer
             q.put(e)

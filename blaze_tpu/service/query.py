@@ -382,6 +382,18 @@ class Query:
             if k in m:
                 out[k] = m[k]
         out["dispatches"] = m.get("dispatch.dispatches", 0)
+        # this task's launches alone; `dispatches` above is a delta of
+        # the process's counters and takes in its neighbours'
+        out["task_dispatches"] = self.ctx.task_dispatches
+        if self.tracer is not None and self.state in TERMINAL_STATES:
+            # per-task stage table, folded from the task's own spans:
+            # {stage: {wall_s, cpu_s, n}}. A POLL after FETCH carries
+            # the wire's stages (frame_encode, frame_send) too
+            from blaze_tpu.obs.phases import STAGE_PHASE
+
+            out["stages"] = self.tracer.phase_totals(
+                STAGE_PHASE, stage_table=True
+            )
         if self._fingerprint is not None and self._fingerprint_stable:
             # stable content fingerprint: the affinity key replica
             # routing and the runtime-history store share

@@ -702,8 +702,6 @@ class ServiceVerbBackend:
         before the first part), so clients - and the router relay -
         need no new protocol: the count-based part-skip resume simply
         starts working mid-query."""
-        from blaze_tpu.io.ipc import encode_ipc_segment
-
         service = self.service
         qid = q.query_id
         deadline = (
@@ -753,7 +751,7 @@ class ServiceVerbBackend:
                     # rollback (delivered-prefix consistency)
                     sb.mark_consumed(i)
                     try:
-                        sock.sendall(encode_ipc_segment(payload))
+                        _send_part(sock, q, _encode_part(q, payload))
                     except (socket.timeout, TimeoutError) as e:
                         service._note_stream_event("stall")
                         raise ConnectionError(
@@ -830,7 +828,6 @@ class ServiceVerbBackend:
         """Legacy materialize-then-stream FETCH: only reachable when
         the service runs with streaming disabled
         (stream_buffer_bytes <= 0)."""
-        from blaze_tpu.io.ipc import encode_ipc_segment
         from blaze_tpu.service.query import QueryState
 
         service = self.service
@@ -855,7 +852,7 @@ class ServiceVerbBackend:
                     # it)
                     chaos.fire("gateway.stream", query_id=qid,
                                partition=i)
-                sock.sendall(encode_ipc_segment(rb))
+                _send_part(sock, q, _encode_part(q, rb))
                 sent += 1
                 # per-part activity: a stream slower than the orphan
                 # TTL is still a COLLECTING client, not a dead router
@@ -908,6 +905,29 @@ class ServiceVerbBackend:
                     "result_stream", stream_start, time.monotonic(),
                     **tags,
                 )
+
+
+def _encode_part(q, payload) -> bytes:
+    """One result part as an Arrow IPC segment: the `frame_encode` stage
+    of the query's trace (both wire planes)."""
+    from blaze_tpu.io.ipc import encode_ipc_segment
+
+    if obs_trace.ACTIVE and getattr(q, "tracer", None) is not None:
+        with obs_trace.span("frame_encode", rec=q.tracer) as sp:
+            seg = encode_ipc_segment(payload)
+            sp.tag(bytes=len(seg))
+            return seg
+    return encode_ipc_segment(payload)
+
+
+def _send_part(sock, q, seg: bytes) -> None:
+    """The socket write and its wait: the `frame_send` stage."""
+    if obs_trace.ACTIVE and getattr(q, "tracer", None) is not None:
+        with obs_trace.span("frame_send", rec=q.tracer,
+                            bytes=len(seg)):
+            sock.sendall(seg)
+    else:
+        sock.sendall(seg)
 
 
 def handle_service_connection(sock, service) -> None:

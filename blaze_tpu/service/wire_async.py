@@ -459,6 +459,28 @@ async def _serve_arena_async(backend, writer, q,
     return True
 
 
+async def _send_part(writer, q, seg: bytes,
+                     stall_s: float = 0.0) -> None:
+    """Write one part and wait for the transport to drain. The
+    `frame_send` stage is the write, which is the loop thread's own
+    work; other coroutines run inside the drain, so the wait only
+    moves the span's end (wall) and is neither CPU time nor a name on
+    the profiler's side."""
+    sp = None
+    if obs_trace.ACTIVE and getattr(q, "tracer", None) is not None:
+        with obs_trace.span("frame_send", rec=q.tracer,
+                            bytes=len(seg)) as sp:
+            writer.write(seg)
+    else:
+        writer.write(seg)
+    if stall_s > 0:
+        await asyncio.wait_for(writer.drain(), stall_s)
+    else:
+        await writer.drain()
+    if isinstance(sp, obs_trace.Span):
+        sp.end_ns = time.monotonic_ns()
+
+
 async def _fetch_incremental_async(backend, writer, q, sb,
                                    timeout_ms: int) -> None:
     """Stream-as-produced FETCH on the loop. Ready-part probes are
@@ -468,7 +490,7 @@ async def _fetch_incremental_async(backend, writer, q, sb,
     probe-clear-reprobe-await pattern closing the lost-wakeup window.
     Slow clients park in `drain()` against the stall budget instead of
     a socket send timeout - same classified outcome, no thread."""
-    from blaze_tpu.io.ipc import encode_ipc_segment
+    from blaze_tpu.service import wire
 
     service = backend.service
     qid = q.query_id
@@ -536,12 +558,11 @@ async def _fetch_incremental_async(backend, writer, q, sb,
                 if not q.done:
                     live_parts += 1
                 sb.mark_consumed(i)
-                writer.write(encode_ipc_segment(payload))
                 try:
-                    if stall_s > 0:
-                        await asyncio.wait_for(writer.drain(), stall_s)
-                    else:
-                        await writer.drain()
+                    await _send_part(
+                        writer, q, wire._encode_part(q, payload),
+                        stall_s,
+                    )
                 except (asyncio.TimeoutError, TimeoutError) as e:
                     service._note_stream_event("stall")
                     raise ConnectionError(
@@ -606,7 +627,7 @@ async def _fetch_materialized_async(backend, writer, q,
     """Legacy materialize-then-stream FETCH (stream_buffer_bytes <= 0)
     on the loop: the DONE wait is an adaptive poll (no thread parked),
     the part loop is drain-aware."""
-    from blaze_tpu.io.ipc import encode_ipc_segment
+    from blaze_tpu.service import wire
     from blaze_tpu.service.query import QueryState
 
     service = backend.service
@@ -642,8 +663,7 @@ async def _fetch_materialized_async(backend, writer, q,
                     partial(chaos.fire, "gateway.stream",
                             query_id=qid, partition=i),
                 )
-            writer.write(encode_ipc_segment(rb))
-            await writer.drain()
+            await _send_part(writer, q, wire._encode_part(q, rb))
             sent += 1
             q.note_activity()
         writer.write(_U64.pack(0))

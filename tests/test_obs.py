@@ -27,6 +27,7 @@ from blaze_tpu.ops import (
     FilterExec,
     HashAggregateExec,
 )
+from blaze_tpu.ops.base import PhysicalOp
 from blaze_tpu.ops.parquet_scan import FileRange, ParquetScanExec
 from blaze_tpu.service import QueryService
 from blaze_tpu.testing import chaos
@@ -637,6 +638,251 @@ def test_trace_cluster_worker_spans_stitch_into_driver(tmp_path):
     assert trace.validate_chrome(doc) == []
     # worker spans keep their own pid track in the export
     assert len({e["pid"] for e in doc["traceEvents"]}) == 2
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: one clock - stage spans beside the profiler's, the per-task
+# stage table and dispatch count in POLL
+# ---------------------------------------------------------------------------
+
+
+class _AnnotationStub:
+    """In place of jax.profiler.TraceAnnotation: what was built,
+    entered and exited, in order, while a profiler session "runs"."""
+
+    log = []
+    session = True
+
+    @staticmethod
+    def is_enabled():
+        return _AnnotationStub.session
+
+    def __init__(self, name):
+        self.name = name
+        _AnnotationStub.log.append(("new", name))
+
+    def __enter__(self):
+        _AnnotationStub.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        _AnnotationStub.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(trace, "_TRACE_ME", _AnnotationStub)
+    monkeypatch.setattr(_AnnotationStub, "session", True)
+    _AnnotationStub.log = []
+    return _AnnotationStub.log
+
+
+@pytest.mark.parametrize("name", sorted(trace.STAGE_SPANS))
+def test_stage_span_lies_on_the_profilers_side(name, annotations):
+    rec = trace.TraceRecorder("t")
+    with trace.span("attempt", rec=rec):
+        with trace.span(name) as sp:
+            sum(range(20000))
+    label = "blaze." + name
+    assert annotations == [("new", label), ("enter", label),
+                           ("exit", label)]
+    assert sp.cpu_ns > 0 and sp.end_ns - sp.start_ns >= sp.cpu_ns // 2
+
+
+def test_stage_span_without_a_profiler_session_builds_nothing(
+        annotations, monkeypatch):
+    """No session: the stage pays TraceMe's own check and no object;
+    its CPU time is still taken."""
+    monkeypatch.setattr(_AnnotationStub, "session", False)
+    rec = trace.TraceRecorder("t")
+    with trace.span("d2h", rec=rec) as sp:
+        sum(range(20000))
+    assert annotations == [] and sp.cpu_ns > 0
+
+
+def test_stage_annotation_is_jax_profilers():
+    import jax
+
+    trace._TRACE_ME = None
+    assert trace._stage_annotation("d2h") is None  # no session runs
+    assert trace._TRACE_ME is jax.profiler.TraceAnnotation
+
+
+@pytest.mark.parametrize(
+    "name", ["execute_partition", "attempt", "execute",
+             "kernel_dispatch", "parquet_decode", "record_span"])
+def test_other_spans_stay_off_the_profilers_side(name, annotations):
+    rec = trace.TraceRecorder("t")
+    if name == "record_span":
+        for lifecycle in ("queue_wait", "admission", "result_stream"):
+            rec.record_span(lifecycle, 1.0, 2.0)
+        # not even under a stage's name: the async wire's drain wait
+        rec.record_span("frame_send", 1.0, 2.0)
+    else:
+        with trace.span(name, rec=rec) as sp:
+            pass
+        assert sp.cpu_ns == 0
+    assert annotations == []
+
+
+def test_obs_trace_alone_does_not_import_jax():
+    """The router imports obs.trace and stays off JAX: a stage span in
+    a process without jax records, and imports nothing for it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location('t', sys.argv[1])\n"
+        "t = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(t)\n"
+        "rec = t.TraceRecorder('x')\n"
+        "with t.span('d2h', rec=rec) as sp:\n"
+        "    pass\n"
+        "assert sp.end_ns is not None and 'jax' not in sys.modules\n"
+        "assert rec.phase_totals({'d2h': 'd2h'}, stage_table=True)"
+        "['d2h']['n'] == 1\n"
+    )
+    subprocess.run([sys.executable, "-c", code, trace.__file__],
+                   check=True, timeout=60)
+
+
+def test_stage_table_takes_a_nested_stage_out_of_its_parent():
+    rec = trace.TraceRecorder("t")
+    with trace.span("decode_batch", rec=rec) as outer:
+        with trace.span("h2d") as inner:
+            time.sleep(0.002)
+    from blaze_tpu.obs.phases import STAGE_PHASE
+
+    table = rec.phase_totals(STAGE_PHASE, stage_table=True)
+    whole = (outer.end_ns - outer.start_ns) / 1e9
+    h2d = (inner.end_ns - inner.start_ns) / 1e9
+    assert table["h2d"]["wall_s"] == pytest.approx(h2d, abs=2e-6)
+    assert table["decode_batch"]["wall_s"] == pytest.approx(
+        whole - h2d, abs=2e-6)
+    # the rollup's fold stays inclusive
+    assert rec.phase_totals(STAGE_PHASE)["decode_batch"] == \
+        pytest.approx(whole, abs=2e-6)
+
+
+@pytest.fixture
+def keyed_parquet(tmp_path):
+    rng = np.random.default_rng(25)
+    n = 40000  # three batches of spark.blaze.batchSize
+    p = str(tmp_path / "keyed.parquet")
+    pq.write_table(pa.table({
+        "k": pa.array(rng.integers(0, 500, n).astype(np.int32),
+                      mask=rng.random(n) < 0.05),
+        "v": rng.integers(0, 100, n).astype(np.int32),
+    }), p)
+    return p
+
+
+SCAN_STAGES = {"decode_batch", "h2d", "d2h"}
+SHUFFLE_STAGES = {"shuffle_partition", "shuffle_encode",
+                  "shuffle_finalize"}
+WIRE_STAGES = {"frame_encode", "frame_send"}
+
+
+@pytest.mark.parametrize("wire_plane", ["async", "threaded"])
+@pytest.mark.parametrize("shape", ["q6_scan", "shuffle_write"])
+def test_poll_stage_table(shape, wire_plane, keyed_parquet, tmp_path):
+    from blaze_tpu.ops.shuffle_writer import ShuffleWriterExec
+    from blaze_tpu.plan.serde import task_to_proto
+    from blaze_tpu.runtime.gateway import TaskGatewayServer
+    from blaze_tpu.service import ServiceClient
+
+    scan = ParquetScanExec([[FileRange(keyed_parquet)]])
+    if shape == "q6_scan":
+        plan = FilterExec(scan, Col("v") > 10)
+        want = SCAN_STAGES | {"compact"} | WIRE_STAGES
+    else:
+        plan = ShuffleWriterExec(
+            scan, [Col("k")], 8, str(tmp_path / "s.data"),
+            str(tmp_path / "s.index"))
+        want = SCAN_STAGES | SHUFFLE_STAGES
+    with QueryService(max_concurrency=1) as svc:
+        with TaskGatewayServer(service=svc,
+                               wire=wire_plane) as srv:
+            with ServiceClient(*srv.address) as c:
+                st = c.submit(task_to_proto(plan, 0))
+                c.fetch(st["query_id"])
+                poll = c.poll(st["query_id"])
+    assert poll["state"] == "DONE"
+    stages = poll["stages"]
+    assert set(stages) == want
+    for name, row in stages.items():
+        assert row["n"] > 0 and row["wall_s"] >= 0, name
+        assert 0 <= row["cpu_s"], name
+    assert stages["d2h"]["n"] == 3  # one a batch
+    # a thread's stages never overlap, so each thread's sum fits in
+    # the execution; the scan's prefetch worker decodes batch n+1
+    # while the draining thread works on batch n, so the two sums
+    # together may not
+    prefetch = {"decode_batch", "h2d"}
+    for thread in (prefetch, set(stages) - prefetch - WIRE_STAGES):
+        assert sum(stages[name]["wall_s"] for name in thread) \
+            <= poll["execution_s"], thread
+    assert poll["task_dispatches"] == poll["dispatches"] > 0
+
+
+class _MeetAfterFirstBatch(PhysicalOp):
+    """Passes its child's batches through and waits at a barrier once
+    the first is out: two tasks built on one barrier are in flight
+    together from there on, whatever the scheduler does."""
+
+    def __init__(self, child, barrier):
+        self.children = [child]
+        self._barrier = barrier
+
+    @property
+    def schema(self):
+        return self.children[0].schema
+
+    def execute(self, partition, ctx):
+        for i, cb in enumerate(self.children[0].execute(partition,
+                                                        ctx)):
+            yield cb
+            if i == 0:
+                self._barrier.wait(timeout=60)
+
+
+def test_task_dispatches_are_the_tasks_own(keyed_parquet):
+    import threading
+
+    def plan(barrier):
+        return _MeetAfterFirstBatch(
+            FilterExec(ParquetScanExec([[FileRange(keyed_parquet)]]),
+                       Col("v") > 10), barrier)
+
+    def run(svc, n):
+        barrier = threading.Barrier(n)
+        qs = [svc.submit_plan(plan(barrier), use_cache=False)
+              for _ in range(n)]
+        for q in qs:
+            svc.result(q.query_id, timeout=120)
+        return [q.status() for q in qs]
+
+    with QueryService(max_concurrency=2, enable_trace=False) as svc:
+        run(svc, 1)  # builds the kernels
+        (alone,) = run(svc, 1)
+        both = run(svc, 2)
+    assert alone["task_dispatches"] == alone["dispatches"] > 0
+    assert [p["task_dispatches"] for p in both] == \
+        [alone["task_dispatches"]] * 2
+    # the process-wide delta takes in the neighbour's launches
+    assert max(p["dispatches"] for p in both) > alone["dispatches"]
+
+
+def test_trace_off_poll_counts_launches_and_builds_no_annotation(
+        two_part_plan, annotations):
+    assert not trace.ACTIVE
+    with QueryService(max_concurrency=1, enable_trace=False) as svc:
+        q = svc.submit_plan(two_part_plan())
+        svc.result(q.query_id, timeout=60)
+        poll = q.status()
+    assert poll["task_dispatches"] > 0
+    assert "stages" not in poll
+    assert annotations == []
 
 
 # ---------------------------------------------------------------------------
