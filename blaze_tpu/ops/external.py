@@ -29,11 +29,7 @@ import jax.numpy as jnp
 from blaze_tpu.types import Schema
 from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.exprs import ir
-from blaze_tpu.io.ipc import (
-    encode_ipc_segment,
-    partition_ranges,
-    read_file_segment,
-)
+from blaze_tpu.io.ipc import partition_ranges, read_file_segment
 from blaze_tpu.ops.base import ExecContext
 from blaze_tpu.ops.shuffle_writer import (
     PartitionBuffers,
@@ -102,7 +98,10 @@ def bucket_stream(
                                      dir=d)
     os.close(fd)
     index_path = data_path[:-5] + ".index"
-    bufs = PartitionBuffers(n_buckets, d)
+    bufs = PartitionBuffers(
+        n_buckets, d, ctx.config.batch_size,
+        ctx.config.ipc_compression_level,
+    )
 
     def feed(cb: ColumnBatch) -> None:
         cb = ensure_compacted(cb)
@@ -116,20 +115,7 @@ def bucket_stream(
         pid_full = pid_full.at[: len(pids)].set(jnp.asarray(pids))
         order = jnp.argsort(pid_full, stable=True)
         rb_sorted = take_batch(cb, order, cb.num_rows).to_arrow()
-        sorted_pids = np.sort(pids, kind="stable")
-        counts = np.bincount(sorted_pids, minlength=n_buckets)
-        start = 0
-        for p in range(n_buckets):
-            c = int(counts[p])
-            if c:
-                bufs.append(
-                    p,
-                    encode_ipc_segment(
-                        rb_sorted.slice(start, c),
-                        ctx.config.ipc_compression_level,
-                    ),
-                )
-                start += c
+        bufs.stage(rb_sorted, np.bincount(pids, minlength=n_buckets))
 
     for cb in head:
         feed(cb)

@@ -7,8 +7,8 @@ host-side into the SAME segmented-IPC `.data`/`.index` format the native
 writer produces, so the read side never knows which tier wrote a block.
 This module is that second producer: pyarrow batches in, bit-exact
 Spark murmur3/pmod partition ids computed with the numpy/C++ host
-hashing tier (no HBM touch), per-partition zstd IPC segments assembled
-through the shared PartitionBuffers spill ladder.
+hashing tier (no HBM touch), rows staged per partition and frozen to
+zstd IPC parts through the shared PartitionBuffers ladder.
 
 Used by host-fallback subtrees feeding an exchange, and as the format
 witness: tests assert host-written and device-written shuffles are
@@ -22,6 +22,7 @@ from typing import Iterable, List, Optional, Sequence
 import numpy as np
 import pyarrow as pa
 
+from blaze_tpu.config import get_config
 from blaze_tpu.types import from_arrow_schema
 from blaze_tpu.io.ipc import encode_ipc_segment
 from blaze_tpu.ops.shuffle_writer import PartitionBuffers, _chain_fixed
@@ -70,7 +71,8 @@ def host_shuffle_write(batches: Iterable[pa.RecordBatch],
     import tempfile
 
     bufs = PartitionBuffers(
-        num_partitions, spill_dir or tempfile.gettempdir()
+        num_partitions, spill_dir or tempfile.gettempdir(),
+        get_config().batch_size, compression_level,
     )
     for rb in batches:
         if rb.num_rows == 0:
@@ -81,18 +83,7 @@ def host_shuffle_write(batches: Iterable[pa.RecordBatch],
         pids = host_partition_ids(rb, key_names, num_partitions)
         order = np.argsort(pids, kind="stable")
         rb_sorted = rb.take(pa.array(order))
-        sorted_pids = pids[order]
-        counts = np.bincount(sorted_pids, minlength=num_partitions)
-        start = 0
-        for p in range(num_partitions):
-            c = int(counts[p])
-            if c == 0:
-                continue
-            bufs.append(
-                p,
-                encode_ipc_segment(
-                    rb_sorted.slice(start, c), compression_level
-                ),
-            )
-            start += c
+        bufs.stage(
+            rb_sorted, np.bincount(pids, minlength=num_partitions)
+        )
     return bufs.finalize(data_file, index_file)

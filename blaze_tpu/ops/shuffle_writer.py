@@ -7,6 +7,19 @@ i64 offsets index, committed by Spark (ArrowShuffleExchangeExec301.scala:
 531-602). Single-partition (no-key) and round-robin variants cover the
 JVM fallback paths' semantics.
 
+Staging, freeze, spill (PartitionBuffers, as the reference's
+PartitionBuffer): a partition-sorted batch is kept as zero-copy slices,
+one a non-empty partition, with no IPC and no zstd. A partition is
+frozen - its slices concatenated and encoded as ONE part - before a slice
+that would take its staged rows past `batch_size`, and whatever is still
+staged is frozen by `finalize` and, under memory pressure, by `spill`
+before the spill file is written. So a 64-batch task into 200 partitions
+writes some 200 parts, not 12,800, and a reader meets no part larger than
+a batch unless one slice alone was. Staged Arrow bytes count against the
+MemoryPool like encoded bytes. A partition's rows keep batch order, and
+the stable sort's order within a batch, across freezes and spills; the
+file format (io/ipc.py) does not change.
+
 TPU-first layout (SURVEY 7 step 5): partition ids are computed on-device
 (bit-exact Spark murmur3 over the key columns) and the row scatter is ONE
 stable device argsort by partition id - the counting-sort scatter of the
@@ -19,9 +32,11 @@ is not bit-exact - exprs/hashing.device_hash_supported).
 from __future__ import annotations
 
 import os
+import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
+import pyarrow as pa
 
 import jax
 import jax.numpy as jnp
@@ -48,63 +63,167 @@ from blaze_tpu.runtime.memory import get_pool
 
 
 class PartitionBuffers:
-    """Per-partition compressed segment buffers with the reference's
-    buffer->spill->merge ladder (PartitionBuffer/spill_into,
-    shuffle_writer_exec.rs:66-194, :522-556)."""
+    """Per-partition staging and part buffers with the reference's
+    stage->freeze->spill->merge ladder (PartitionBuffer/spill_into,
+    shuffle_writer_exec.rs:66-194, :522-556).
 
-    def __init__(self, num_partitions: int, spill_dir: str):
+    `stage` keeps a partition-sorted batch's zero-copy slices, one list
+    a partition. A partition is frozen (its slices concatenated, encoded
+    as ONE part and appended to its byte buffer) before a slice that
+    would take its staged rows past `batch_size`, so a reader meets no
+    part larger than a batch unless one slice alone was; `spill` and
+    `finalize` freeze whatever is still staged. Staged Arrow bytes and
+    encoded bytes are both accounted to the MemoryPool. The pool calls
+    `spill` on whichever thread ran short, so stage, freeze, spill and
+    finalize hold `_lock`; the pool is only ever grown outside it (a
+    grow may spill a neighbour, which takes the neighbour's lock)."""
+
+    def __init__(self, num_partitions: int, spill_dir: str,
+                 batch_size: int, compression_level: int):
         self.num_partitions = num_partitions
+        self.batch_size = batch_size
+        self.compression_level = compression_level
         self.buffers: List[bytearray] = [
             bytearray() for _ in range(num_partitions)
         ]
+        self._staged: List[List[pa.RecordBatch]] = [
+            [] for _ in range(num_partitions)
+        ]
+        self._staged_rows = [0] * num_partitions
+        self._staged_bytes = [0] * num_partitions
         self.spills: List[Tuple[str, List[int]]] = []
         self.spill_dir = spill_dir
         self.mem_used = 0
+        self.segments = 0  # parts encoded so far
+        self.segment_bytes = 0
+        self._lock = threading.Lock()
         self._pool = get_pool()
         self._pool.register(id(self), self.spill)
 
     def append(self, partition: int, part: bytes) -> None:
-        self.buffers[partition] += part
-        self.mem_used += len(part)
+        """An already encoded part (the single-partition writer)."""
+        # accounted before it is held: a spill between the two lines
+        # releases what the pool has been told of, no more
         self._pool.grow(id(self), len(part))
+        with self._lock:
+            self.buffers[partition] += part
+            self.mem_used += len(part)
+            self.segments += 1
+            self.segment_bytes += len(part)
+
+    def stage(self, rb_sorted: pa.RecordBatch, counts) -> None:
+        """Keep `rb_sorted`'s rows, which lie partition by partition
+        (`counts[p]` of them for partition p, in order), as one slice a
+        non-empty partition; no IPC and no zstd but for the partitions
+        a slice fills past `batch_size`, which are frozen first."""
+        n = rb_sorted.num_rows
+        if n == 0:
+            return
+        nbytes = rb_sorted.nbytes
+        # obs seam: one span a batch, not one a part
+        with (obs_trace.span("shuffle_encode")
+              if obs_trace.ACTIVE else obs_trace.NULL) as sp:
+            self._pool.grow(id(self), nbytes)  # as in `append`
+            with self._lock:
+                seg0, bytes0 = self.segments, self.segment_bytes
+                self.mem_used += nbytes
+                owed = start = 0
+                for p, c in enumerate(np.asarray(counts).tolist()):
+                    if c == 0:
+                        continue
+                    if (self._staged_rows[p]
+                            and self._staged_rows[p] + c > self.batch_size):
+                        owed += self._freeze(p)
+                    self._staged[p].append(rb_sorted.slice(start, c))
+                    self._staged_rows[p] += c
+                    # shares of the batch's bytes that add up to them
+                    self._staged_bytes[p] += (
+                        (start + c) * nbytes // n - start * nbytes // n
+                    )
+                    start += c
+                sp.tag(segments=self.segments - seg0,
+                       bytes=self.segment_bytes - bytes0)
+            if owed > 0:  # parts larger than their rows: tiny slices
+                self._pool.grow(id(self), owed)
+            else:
+                self._pool.shrink(id(self), -owed)
+
+    def _freeze(self, p: int) -> int:
+        """Partition p's staged slices become one part at the end of
+        its buffer (caller holds the lock). Returns the change in bytes
+        held, which the caller owes the pool."""
+        slices = self._staged[p]
+        part = encode_ipc_segment(
+            slices[0] if len(slices) == 1 else pa.concat_batches(slices),
+            self.compression_level,
+        )
+        self.buffers[p] += part
+        delta = len(part) - self._staged_bytes[p]
+        self._staged[p] = []
+        self._staged_rows[p] = self._staged_bytes[p] = 0
+        self.mem_used += delta
+        self.segments += 1
+        self.segment_bytes += len(part)
+        return delta
+
+    def _freeze_all(self, **tags) -> None:
+        """Every partition still staged (caller holds the lock, and
+        settles `mem_used` with the pool itself)."""
+        staged = [p for p in range(self.num_partitions)
+                  if self._staged_rows[p]]
+        if not staged:
+            return
+        # obs seam: the same stage as `stage`'s, nested in the
+        # `shuffle_finalize` around it, which the fold takes it out of
+        with (obs_trace.span("shuffle_encode", **tags)
+              if obs_trace.ACTIVE else obs_trace.NULL) as sp:
+            seg0, bytes0 = self.segments, self.segment_bytes
+            for p in staged:
+                self._freeze(p)
+            sp.tag(segments=self.segments - seg0,
+                   bytes=self.segment_bytes - bytes0)
 
     def spill(self) -> int:
-        """Write current buffers to a spill file; returns bytes released."""
-        if self.mem_used == 0:
-            return 0
-        path = os.path.join(
-            self.spill_dir,
-            f"blz-spill-{id(self):x}-{len(self.spills)}.tmp",
-        )
-        offsets = [0] * (self.num_partitions + 1)
-        pos = 0
-        # obs seam: a spill is file writing too (the thread that ran
-        # short of memory pays, whichever task's buffers these are)
-        with (obs_trace.span("shuffle_finalize", spill=True)
-              if obs_trace.ACTIVE else obs_trace.NULL), \
-                open(path, "wb") as f:
-            for p in range(self.num_partitions):
-                offsets[p] = pos
-                f.write(self.buffers[p])
-                pos += len(self.buffers[p])
-                self.buffers[p] = bytearray()
-        offsets[self.num_partitions] = pos
-        self.spills.append((path, offsets))
-        released = self.mem_used
-        self.mem_used = 0
-        return released
+        """Freeze what is staged and write every buffer to a spill
+        file; returns bytes released."""
+        with self._lock:
+            released = self.mem_used
+            if released == 0:
+                return 0
+            path = os.path.join(
+                self.spill_dir,
+                f"blz-spill-{id(self):x}-{len(self.spills)}.tmp",
+            )
+            offsets = [0] * (self.num_partitions + 1)
+            pos = 0
+            # obs seam: a spill is file writing too (the thread that ran
+            # short of memory pays, whichever task's buffers these are)
+            with (obs_trace.span("shuffle_finalize", spill=True)
+                  if obs_trace.ACTIVE else obs_trace.NULL):
+                self._freeze_all(spill=True)
+                with open(path, "wb") as f:
+                    for p in range(self.num_partitions):
+                        offsets[p] = pos
+                        f.write(self.buffers[p])
+                        pos += len(self.buffers[p])
+                        self.buffers[p] = bytearray()
+            offsets[self.num_partitions] = pos
+            self.spills.append((path, offsets))
+            self.mem_used = 0
+            return released
 
     def finalize(self, data_path: str, index_path: str) -> List[int]:
-        """Assemble .data/.index (native C++ fast path); returns partition
-        lengths. Cleans up spill files."""
-        native.shuffle_assemble(
-            data_path, index_path,
-            [bytes(b) for b in self.buffers],
-            self.num_partitions, self.spills,
-        )
-        self._pool.shrink(id(self), self.mem_used)
+        """Freeze what is staged and assemble .data/.index (native C++
+        fast path); returns partition lengths. Cleans up spill files."""
+        with (obs_trace.span("shuffle_finalize")
+              if obs_trace.ACTIVE else obs_trace.NULL), self._lock:
+            self._freeze_all()
+            native.shuffle_assemble(
+                data_path, index_path, self.buffers,
+                self.num_partitions, self.spills,
+            )
+            self.mem_used = 0
         self._pool.unregister(id(self))
-        self.mem_used = 0
         for path, _ in self.spills:
             try:
                 os.remove(path)
@@ -360,7 +479,10 @@ class ShuffleWriterExec(PhysicalOp):
     def execute(self, partition: int, ctx: ExecContext
                 ) -> Iterator[ColumnBatch]:
         cfg = ctx.config
-        bufs = PartitionBuffers(self.num_partitions, cfg.spill_dir())
+        bufs = PartitionBuffers(
+            self.num_partitions, cfg.spill_dir(), cfg.batch_size,
+            cfg.ipc_compression_level,
+        )
         rr_next = partition  # round-robin start varies by map partition
         for cb in self.children[0].execute(partition, ctx):
             cb = ensure_compacted(cb)
@@ -389,7 +511,6 @@ class ShuffleWriterExec(PhysicalOp):
                          np.arange(cb.num_rows, cb.capacity)])),
                     cb.num_rows,
                 ).to_arrow()
-                sorted_pids = pids[order]
             elif self.mode == "range":
                 # host path: key ordering incl. strings/NULLs needs real
                 # values (ordering on dictionary codes would be wrong);
@@ -405,7 +526,6 @@ class ShuffleWriterExec(PhysicalOp):
                 )
                 order = np.argsort(pids, kind="stable")
                 rb_sorted = rb.take(order)
-                sorted_pids = pids[order]
             else:
                 # obs seam: hash, sort by partition and gather, up to
                 # the read-back (`d2h`, inside to_arrow)
@@ -426,31 +546,11 @@ class ShuffleWriterExec(PhysicalOp):
                     order_dev = jnp.argsort(pid_full, stable=True)
                     cb_sorted = take_batch(cb, order_dev, cb.num_rows)
                 rb_sorted = cb_sorted.to_arrow()
-                sorted_pids = np.sort(pids, kind="stable")
-            counts = np.bincount(
-                sorted_pids, minlength=self.num_partitions
-            )
-            # obs seam: one span a batch, not one a segment (200
-            # partitions x 64 batches would pass the span cap)
-            with (obs_trace.span("shuffle_encode")
-                  if obs_trace.ACTIVE else obs_trace.NULL) as sp:
-                start = segments = nbytes = 0
-                for p in range(self.num_partitions):
-                    c = int(counts[p])
-                    if c == 0:
-                        continue
-                    seg = encode_ipc_segment(
-                        rb_sorted.slice(start, c),
-                        cfg.ipc_compression_level,
-                    )
-                    bufs.append(p, seg)
-                    start += c
-                    segments += 1
-                    nbytes += len(seg)
-                sp.tag(segments=segments, bytes=nbytes)
+            bufs.stage(rb_sorted, np.bincount(
+                pids, minlength=self.num_partitions
+            ))
             ctx.metrics.add("shuffle_rows_written", cb.num_rows)
-        with (obs_trace.span("shuffle_finalize")
-              if obs_trace.ACTIVE else obs_trace.NULL):
-            lengths = bufs.finalize(self.data_file, self.index_file)
+        lengths = bufs.finalize(self.data_file, self.index_file)
+        ctx.metrics.add("shuffle_segments_written", bufs.segments)
         ctx.metrics.add("shuffle_bytes_written", sum(lengths))
         return iter(())

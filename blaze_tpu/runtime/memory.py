@@ -65,7 +65,8 @@ class MemoryPool:
                     continue
                 released = fn()
                 with self._lock:
-                    self._used[v] = max(0, self._used[v] - released)
+                    if v in self._used:  # not finalized meanwhile
+                        self._used[v] = max(0, self._used[v] - released)
                 self.spill_count += 1
                 self.spilled_bytes += released
                 freed += released

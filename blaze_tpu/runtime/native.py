@@ -360,9 +360,8 @@ def _shuffle_assemble_py(data_path, index_path, partition_buffers,
         pos = 0
         for p in range(num_partitions):
             offsets[p] = pos
-            buf = partition_buffers[p]
-            out.write(buf)
-            pos += len(buf)
+            # spills first, oldest first, then what was still in memory:
+            # a partition's parts stay in the order they were written
             for path, so in spills:
                 length = so[p + 1] - so[p]
                 if length > 0:
@@ -370,6 +369,9 @@ def _shuffle_assemble_py(data_path, index_path, partition_buffers,
                         f.seek(so[p])
                         out.write(f.read(length))
                     pos += length
+            buf = partition_buffers[p]
+            out.write(buf)
+            pos += len(buf)
         offsets[num_partitions] = pos
     with open(index_path, "wb") as idx:
         for off in offsets:

@@ -385,6 +385,9 @@ class Query:
         # this task's launches alone; `dispatches` above is a delta of
         # the process's counters and takes in its neighbours'
         out["task_dispatches"] = self.ctx.task_dispatches
+        if "shuffle_segments_written" in m:
+            # parts a shuffle write encoded (ops/shuffle_writer.py)
+            out["shuffle_segments"] = m["shuffle_segments_written"]
         if self.tracer is not None and self.state in TERMINAL_STATES:
             # per-task stage table, folded from the task's own spans:
             # {stage: {wall_s, cpu_s, n}}. A POLL after FETCH carries

@@ -196,9 +196,10 @@ void blz_pmod(const uint32_t* hashes, int64_t n, int32_t num_partitions,
 // shuffle .data/.index assembly (reference shuffle_writer_exec.rs:437-506)
 // ---------------------------------------------------------------------------
 
-// Concatenate per-partition in-memory buffers plus per-partition ranges of
-// spill files into one data file; write (num_partitions+1) LE i64 offsets
-// into the index file. Buffers are passed as one blob + offsets.
+// Concatenate per-partition ranges of spill files (oldest first) and then
+// the per-partition in-memory buffers into one data file, so a partition's
+// parts keep the order they were written in; write (num_partitions+1) LE
+// i64 offsets into the index file. Buffers are passed as one blob + offsets.
 //
 // spill_paths: array of C strings; spill_offsets: [n_spills][n_part+1].
 // Returns 0 on success, negative errno-style code on failure.
@@ -214,15 +215,6 @@ int64_t blz_shuffle_assemble(const char* data_path, const char* index_path,
   int64_t pos = 0;
   for (int32_t p = 0; p < num_partitions; p++) {
     offsets[p] = pos;
-    int64_t len = buf_offsets[p + 1] - buf_offsets[p];
-    if (len > 0) {
-      if (fwrite(buffers + buf_offsets[p], 1, (size_t)len, out) !=
-          (size_t)len) {
-        fclose(out);
-        return -2;
-      }
-      pos += len;
-    }
     for (int32_t s = 0; s < n_spills; s++) {
       const int64_t* so = spill_offsets + (int64_t)s * (num_partitions + 1);
       int64_t slen = so[p + 1] - so[p];
@@ -256,6 +248,15 @@ int64_t blz_shuffle_assemble(const char* data_path, const char* index_path,
         pos += (int64_t)got;
       }
       fclose(in);
+    }
+    int64_t len = buf_offsets[p + 1] - buf_offsets[p];
+    if (len > 0) {
+      if (fwrite(buffers + buf_offsets[p], 1, (size_t)len, out) !=
+          (size_t)len) {
+        fclose(out);
+        return -2;
+      }
+      pos += len;
     }
   }
   offsets[num_partitions] = pos;

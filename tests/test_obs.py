@@ -823,6 +823,23 @@ def test_poll_stage_table(shape, wire_plane, keyed_parquet, tmp_path):
         assert sum(stages[name]["wall_s"] for name in thread) \
             <= poll["execution_s"], thread
     assert poll["task_dispatches"] == poll["dispatches"] > 0
+    if shape == "q6_scan":
+        assert "shuffle_segments" not in poll
+    else:
+        # the parts the task encoded are the parts in its files: the
+        # split's three batches staged, one part a partition
+        from blaze_tpu.io.ipc import partition_ranges, read_file_segment
+
+        parts = sum(
+            len(list(read_file_segment(
+                str(tmp_path / "s.data"), off, length)))
+            for off, length in partition_ranges(
+                str(tmp_path / "s.index"))
+        )
+        assert poll["shuffle_segments"] == parts == 8
+        # a span a batch for staging and one for finalize's freezes
+        assert stages["shuffle_encode"]["n"] == 4
+        assert stages["shuffle_finalize"]["n"] == 1
 
 
 class _MeetAfterFirstBatch(PhysicalOp):

@@ -3,6 +3,7 @@ spill merge, partition placement vs Spark's hash semantics."""
 
 import os
 import struct
+import sys
 
 import numpy as np
 import pyarrow as pa
@@ -103,36 +104,235 @@ def test_shuffle_string_keys(tmp_path):
     assert all(len(ps) == 1 for ps in groups.values())
 
 
-def test_shuffle_spill_merge(tmp_path):
-    """Force spills with a tiny budget; the merged file must still contain
-    every row in the right partition order."""
+def parts_of(tmp_path, stem):
+    """Per partition, the parts of its segment as lists of row tuples."""
+    out = []
+    for off, length in partition_ranges(str(tmp_path / f"{stem}.index")):
+        out.append([
+            list(zip(*[c.to_pylist() for c in rb.columns]))
+            for rb in read_file_segment(
+                str(tmp_path / f"{stem}.data"), off, length
+            )
+        ] if length else [])
+    return out
+
+
+def spark_pmod(k, n):
+    p = int(np.int32(np.uint32(hash_long_host(k) & 0xFFFFFFFF))) % n
+    return p + n if p < 0 else p
+
+
+@pytest.fixture
+def tiny_pool():
+    """A pool so small that whatever grows it spills."""
     from blaze_tpu.runtime import memory
 
     old_pool = memory._POOL
-    memory._POOL = memory.MemoryPool(budget=64)  # absurdly small -> spills
+    memory._POOL = memory.MemoryPool(budget=64)
     try:
-        batches = [
-            ColumnBatch.from_pydict(
-                {"k": list(range(i * 20, (i + 1) * 20))}
-            )
-            for i in range(5)
-        ]
-        scan = MemoryScanExec([batches], batches[0].schema)
-        op = ShuffleWriterExec(
-            scan, [Col("k")], 3,
-            str(tmp_path / "s.data"), str(tmp_path / "s.index"),
-        )
-        drain(op, 0, ExecContext())
-        assert memory._POOL.spill_count > 0
-        seen = []
-        for off, length in partition_ranges(str(tmp_path / "s.index")):
-            for rb in read_file_segment(
-                str(tmp_path / "s.data"), off, length
-            ):
-                seen += rb.column(0).to_pylist()
-        assert sorted(seen) == list(range(100))
+        yield memory._POOL
     finally:
         memory._POOL = old_pool
+
+
+def test_shuffle_spill_merge(tmp_path, tiny_pool):
+    """Force spills with a tiny budget: staged rows are frozen before
+    each spill, and the merged file holds every row, each partition's
+    in the order they came (batch order, across the spill boundaries)."""
+    batches = [
+        ColumnBatch.from_pydict(
+            {"k": list(range(i * 20, (i + 1) * 20))}
+        )
+        for i in range(5)
+    ]
+    scan = MemoryScanExec([batches], batches[0].schema)
+    op = ShuffleWriterExec(
+        scan, [Col("k")], 3,
+        str(tmp_path / "s.data"), str(tmp_path / "s.index"),
+    )
+    ctx = ExecContext()
+    drain(op, 0, ctx)
+    assert tiny_pool.spill_count > 0
+    assert tiny_pool.spilled_bytes > 0  # rows went out, frozen
+    assert tiny_pool.total_used() == 0
+    parts = parts_of(tmp_path, "s")
+    got = [[k for part in parts[p] for (k,) in part] for p in range(3)]
+    # a batch's rows spilled when the next batch grew the pool: one
+    # part a batch and partition, the keys ascending as they came
+    assert got == [
+        [k for k in range(100) if spark_pmod(k, 3) == p]
+        for p in range(3)
+    ]
+    assert ctx.metrics.counters["shuffle_segments_written"] == sum(
+        len(ps) for ps in parts
+    ) > 3
+
+
+def expected_slices(mode, batches, n, bounds):
+    """What the per-slice writer wrote: for each partition, one list
+    of rows a batch (empty ones left out), computed from the input."""
+    out = [[] for _ in range(n)]
+    rr_next = 0
+    for b in batches:
+        rows = list(zip(b["k"], b["v"]))
+        if mode == "hash":
+            pids = [spark_pmod(k, n) for k, _ in rows]
+        elif mode == "round_robin":
+            pids = [(i + rr_next) % n for i in range(len(rows))]
+            rr_next = (rr_next + len(rows)) % n
+        else:
+            pids = [sum(k > bound for (bound,) in bounds)
+                    for k, _ in rows]
+        for p in range(n):
+            mine = [r for r, pid in zip(rows, pids) if pid == p]
+            if mine:
+                out[p].append(mine)
+    return out
+
+
+@pytest.mark.parametrize("mode", ["hash", "round_robin", "range"])
+def test_staged_parts_fill_a_batch(mode, tmp_path):
+    """Many small batches into few partitions: rows are staged and a
+    partition is frozen just before a slice would take it past
+    batch_size, so parts are as large as a batch allows and no larger,
+    and a partition's rows stay in the per-slice writer's order."""
+    batch_size, n = 64, 3
+    rng = np.random.default_rng(26)
+    batches, v = [], 0
+    for _ in range(40):
+        rows = int(rng.integers(5, 50))
+        batches.append({
+            "k": rng.integers(0, 1000, rows).tolist(),
+            "v": list(range(v, v + rows)),
+        })
+        v += rows
+    bounds = [(300,), (650,)]
+    cbs = [ColumnBatch.from_pydict(b) for b in batches]
+    op = ShuffleWriterExec(
+        MemoryScanExec([cbs], cbs[0].schema),
+        [] if mode == "round_robin" else [Col("k")], n,
+        str(tmp_path / "s.data"), str(tmp_path / "s.index"),
+        mode=mode, range_bounds=bounds if mode == "range" else None,
+    )
+    ctx = ExecContext(config=EngineConfig(batch_size=batch_size))
+    drain(op, 0, ctx)
+    parts = parts_of(tmp_path, "s")
+    slices = expected_slices(mode, batches, n, bounds)
+    for p in range(n):
+        sizes = [len(part) for part in parts[p]]
+        assert len(sizes) > 2 and max(sizes) <= batch_size, (p, sizes)
+        # no two neighbours would have fitted in one part
+        assert all(a + b > batch_size
+                   for a, b in zip(sizes, sizes[1:])), (p, sizes)
+        assert [r for part in parts[p] for r in part] == \
+            [r for sl in slices[p] for r in sl], p
+        # and fewer parts than slices by far
+        assert len(sizes) < len(slices[p]) / 2
+    assert ctx.metrics.counters["shuffle_segments_written"] == sum(
+        len(ps) for ps in parts
+    )
+    assert ctx.metrics.counters["shuffle_rows_written"] == v
+
+
+@pytest.mark.parametrize("rows, want_parts", [
+    # one batch: the parts the per-slice writer wrote, one a partition
+    ([90], [[30], [30], [30]]),
+    # a slice that alone passes batch_size is a part of its own, and
+    # the staged rows before it are frozen first
+    ([30, 240, 30], [[10, 80, 10], [10, 80, 10], [10, 80, 10]]),
+    # 30 + 30 fit in 64, the third 30 does not
+    ([90, 90, 90], [[60, 30], [60, 30], [60, 30]]),
+])
+def test_part_sizes_follow_the_batch_rule(rows, want_parts, tmp_path):
+    v, cbs = 0, []
+    for r in rows:
+        cbs.append(ColumnBatch.from_pydict({"v": list(range(v, v + r))}))
+        v += r
+    op = ShuffleWriterExec(
+        MemoryScanExec([cbs], cbs[0].schema), [], 3,
+        str(tmp_path / "s.data"), str(tmp_path / "s.index"),
+        mode="round_robin",
+    )
+    drain(op, 0, ExecContext(config=EngineConfig(batch_size=64)))
+    parts = parts_of(tmp_path, "s")
+    assert [[len(part) for part in ps] for ps in parts] == want_parts
+    for p in range(3):
+        # round robin from 0, every batch a multiple of 3 rows long
+        assert [x for part in parts[p] for (x,) in part] == \
+            list(range(p, v, 3))
+
+
+def test_two_writers_spill_each_other(tmp_path, tiny_pool):
+    """Two PartitionBuffers on two threads under a pool that spills
+    whoever holds bytes whenever either grows: the pool runs a
+    victim's spill on the growing thread, which may be the other
+    writer's, while the victim stages. No row is lost or doubled and
+    each partition keeps its order."""
+    import threading
+
+    from blaze_tpu.ops.shuffle_writer import PartitionBuffers
+
+    n, steps, rows = 8, 40, 400  # 50-row slices: a freeze a stage
+    barrier = threading.Barrier(2)
+    foreign = []
+    errors = []
+
+    def writer(name, base):
+        try:
+            bufs = PartitionBuffers(n, str(tmp_path), 64, 1)
+            spill, me = bufs.spill, threading.get_ident()
+
+            def watched_spill():
+                released = spill()
+                if released and threading.get_ident() != me:
+                    foreign.append(name)
+                return released
+
+            tiny_pool.register(id(bufs), watched_spill)
+            for i in range(steps):
+                vals = np.arange(rows) + base + i * rows
+                pids = vals % n
+                order = np.argsort(pids, kind="stable")
+                rb = pa.RecordBatch.from_pydict({"v": vals[order]})
+                # the first two steps in turn, so that each writer is
+                # surely spilled from the other's thread once; the rest
+                # side by side
+                first = i < 2 and name == "ab"[i]
+                if not first:
+                    barrier.wait(timeout=60)
+                bufs.stage(rb, np.bincount(pids, minlength=n))
+                if first or i >= 2:
+                    barrier.wait(timeout=60)
+            bufs.finalize(str(tmp_path / f"{name}.data"),
+                          str(tmp_path / f"{name}.index"))
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append((name, e))
+            barrier.abort()
+
+    threads = [threading.Thread(target=writer, args=(name, base))
+               for name, base in (("a", 0), ("b", 10 ** 6))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # threads change places often
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    for name, base in (("a", 0), ("b", 10 ** 6)):
+        parts = parts_of(tmp_path, name)
+        for p in range(n):
+            assert [x for part in parts[p] for (x,) in part] == [
+                x for x in range(base, base + steps * rows)
+                if x % n == p
+            ], (name, p)
+    # either thread's grow spills both writers
+    assert set(foreign) == {"a", "b"}
+    assert tiny_pool.total_used() == 0
+    assert [f for f in os.listdir(tmp_path) if f.endswith(".tmp")] == []
 
 
 def test_ipc_reader_modes(tmp_path):
