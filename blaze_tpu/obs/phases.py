@@ -104,6 +104,7 @@ SPAN_PHASE = {
     "decode_batch": "decode_batch",
     "compact": "compact",
     "d2h": "d2h",
+    "agg_fetch": "agg_fetch",
     "shuffle_partition": "shuffle_partition",
     "shuffle_encode": "shuffle_encode",
     "shuffle_finalize": "shuffle_finalize",

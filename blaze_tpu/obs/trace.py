@@ -83,7 +83,7 @@ LIFECYCLE_TID = 0
 # after the fact, and kernel_dispatch, which the runtime's own
 # PjitFunction events already name on the profiler's side.
 STAGE_SPANS = frozenset({
-    "decode_batch", "h2d", "compact", "d2h",
+    "decode_batch", "h2d", "compact", "d2h", "agg_fetch",
     "shuffle_partition", "shuffle_encode", "shuffle_finalize",
     "frame_encode", "frame_send",
     "cache_probe", "service_admit",

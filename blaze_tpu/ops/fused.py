@@ -43,6 +43,7 @@ from blaze_tpu.exprs.typing import infer_dtype
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.filter import FilterExec
 from blaze_tpu.ops.project import ProjectExec, _unflatten_cvs
+from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.runtime.dispatch import cached_kernel
 
 
@@ -295,7 +296,9 @@ class FusedAggregateExec(PhysicalOp):
         agg_sig = tuple((a.fn, a.child) for a, _ in self.agg.aggs)
         carry = None
         packed = None
+        batches = 0
         for cb in leaf.execute(partition, ctx):
+            batches += 1
             pv = packed_view(cb)
             if pv is not None:
                 shape_key = ("packed", pv.key)
@@ -332,7 +335,9 @@ class FusedAggregateExec(PhysicalOp):
                 carry, packed = fn(bufs, cb.selection, num_rows)
         if carry is None:
             return  # empty stream: HostFinalAggExec emits the global row
-        yield _fetch_packed_states(carry, packed, self._schema)
+        # batches merged into the device's carry with no read-back
+        ctx.metrics.add("agg_carry_batches", batches)
+        yield _PackedStateBatch(carry, packed, self._schema)
 
     # ------------------------------------------------------------------
     # keyed streaming device carry (the grouped twin of the keyless form)
@@ -801,7 +806,7 @@ class FusedAggregateExec(PhysicalOp):
             [e for e, _ in self.agg.keys],
         )
 
-        def fetch(outs, n_groups):
+        def sync(outs, n_groups):
             # the single-batch-per-partition hot path: states + count
             # in ONE packed transfer (a single device round trip
             # however many state columns). Later batches (multi-batch
@@ -821,13 +826,22 @@ class FusedAggregateExec(PhysicalOp):
                     for i in range(len(outs))
                 ]
                 return host_outs, int(host[0])
-            if not self.agg.keys:
+            return outs, host_int(n_groups)
+
+        def fetch(outs, n_groups):
+            if not (self.fetch_host and first) and not self.agg.keys:
                 # keyless partial: exactly one group, no collision /
                 # overflow retry possible - skip the per-batch
                 # blocking scalar sync (each one stalls the host on
                 # the device queue)
                 return outs, 1
-            return outs, host_int(n_groups)
+            if obs_trace.ACTIVE:
+                # obs seam: the agg_fetch stage - the wait for this
+                # batch's program and the read-back of its states or
+                # of its group count
+                with obs_trace.span("agg_fetch"):
+                    return sync(outs, n_groups)
+            return sync(outs, n_groups)
 
         # group-capacity slicing: state arrays leave the kernel cut
         # to a static slot count so a small grouped result never
@@ -1038,10 +1052,37 @@ class FusedAggregateExec(PhysicalOp):
         return kernel
 
 
-def _fetch_packed_states(states, packed, schema: Schema) -> ColumnBatch:
-    """Turn a kernel's (state cols, in-kernel-packed u8) pair into a
-    host-resident single-row state batch: ONE plain fetch, no pack
-    dispatch (the kernel already packed)."""
+class _PackedStateBatch(ColumnBatch):
+    """A kernel's state batch that is still on the device, inside the
+    u8 buffer the kernel packed it into. Nothing waits for the kernel
+    until `.columns` is read: then ONE plain fetch brings the states to
+    the host (`_fetch_packed_states`). HostFinalAggExec reads them
+    inside its `agg_fetch` stage, so the wait for the stream's last
+    program, the read-back and the finalize are one span on one
+    thread."""
+
+    def __init__(self, states, packed, schema: Schema):
+        self.schema = schema
+        self.num_rows = len(states[0][0]) if states else 1
+        self.selection = None
+        self._states = states
+        self._packed = packed
+        self._cols: Optional[List[Column]] = None
+
+    @property
+    def columns(self) -> List[Column]:  # type: ignore[override]
+        if self._cols is None:
+            self._cols = _fetch_packed_states(
+                self._states, self._packed, self.schema
+            )
+            self._states = self._packed = None
+        return self._cols
+
+
+def _fetch_packed_states(states, packed, schema: Schema) -> List[Column]:
+    """Turn a kernel's (state cols, in-kernel-packed u8) pair into
+    host-resident state columns: ONE plain fetch, no pack dispatch (the
+    kernel already packed)."""
     from blaze_tpu.runtime.dispatch import record
     from blaze_tpu.runtime.pack import unpack_host
 
@@ -1057,7 +1098,7 @@ def _fetch_packed_states(states, packed, schema: Schema) -> ColumnBatch:
         hv = next(host)
         hm = next(host) if m is not None else None
         cols.append(Column(field.dtype, hv, hm, None))
-    return ColumnBatch(schema, cols, len(cols[0].values) if cols else 1)
+    return cols
 
 
 class FusedWindowAggExec(PhysicalOp):
@@ -1136,7 +1177,7 @@ class FusedWindowAggExec(PhysicalOp):
                 lambda: self._build_kernel(layout, keys, with_idx=True),
             )
             outs, packed = fn(bufs, num_rows, idx)
-        yield _fetch_packed_states(outs, packed, self._schema)
+        yield _PackedStateBatch(outs, packed, self._schema)
 
     def _build_kernel(self, layout, keys, with_idx: bool):
         from blaze_tpu.runtime.pack import pack_in_kernel
@@ -1355,7 +1396,16 @@ class HostFinalAggExec(PhysicalOp):
             return
         second = next(stream, None)
         if second is None:
-            yield self._finalize_host(first)
+            if obs_trace.ACTIVE:
+                # obs seam: the agg_fetch stage - the wait for the
+                # stream's last program, the read-back of its packed
+                # state (a _PackedStateBatch fetches here) and the
+                # host's finalize
+                with obs_trace.span("agg_fetch"):
+                    out = self._finalize_host(first)
+            else:
+                out = self._finalize_host(first)
+            yield out
             return
         # multi-batch: hand the STREAM to the device FINAL kernel, whose
         # execute() owns the max_materialize_rows cap and grace-spill

@@ -388,6 +388,10 @@ class Query:
         if "shuffle_segments_written" in m:
             # parts a shuffle write encoded (ops/shuffle_writer.py)
             out["shuffle_segments"] = m["shuffle_segments_written"]
+        if "agg_carry_batches" in m:
+            # batches a keyless aggregate merged into its device carry
+            # with no read-back (ops/fused.py)
+            out["agg_carry_batches"] = m["agg_carry_batches"]
         if self.tracer is not None and self.state in TERMINAL_STATES:
             # per-task stage table, folded from the task's own spans:
             # {stage: {wall_s, cpu_s, n}}. A POLL after FETCH carries

@@ -148,6 +148,82 @@ def test_q6_step(one_chip):
     _compile(step, one_chip, *shapes)
 
 
+@pytest.fixture(scope="module")
+def sales_parquet(tmp_path_factory):
+    """Two batches of the two columns query 9's subqueries read, typed
+    as the benchmark's store_sales has them: a nullable int32 and a
+    nullable decimal(7,2) stored as parquet INT32."""
+    import decimal
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(27)
+    n = 2 * 16384
+    cents = rng.integers(0, 3_000_000, n)
+    path = str(tmp_path_factory.mktemp("q9") / "sales.parquet")
+    pq.write_table(pa.table({
+        "ss_quantity": pa.array(rng.integers(1, 101, n).astype(np.int32),
+                                mask=rng.random(n) < 0.045),
+        "ss_net_paid": pa.array(
+            [decimal.Decimal(int(c)).scaleb(-2) for c in cents],
+            pa.decimal128(7, 2), mask=rng.random(n) < 0.045),
+    }), path, store_decimal_as_integer=True)
+    return path
+
+
+@pytest.mark.parametrize("with_carry", [False, True],
+                         ids=["first_batch", "carry"])
+@pytest.mark.parametrize("agg", ["count", "avg"])
+def test_keyless_carry_kernel(one_chip, sales_parquet, agg, with_carry):
+    """The per-batch program of `FusedAggregateExec._execute_keyless_carry`
+    (unpack of the packed wire buffer, the filter, the keyless partial
+    aggregate with its i64 decimal limbs and count, the merge into the
+    carry, `pack_in_kernel`) as query 9's scalar subqueries build it:
+    the cell `q9_scalar.s4` launches it 170 times a task."""
+    from blaze_tpu.batch import packed_view
+    from blaze_tpu.exprs import AggExpr, AggFn, Col
+    from blaze_tpu.ops import (
+        AggMode, ExecContext, FilterExec, HashAggregateExec,
+    )
+    from blaze_tpu.ops.fused import (
+        _build_carry_kernel, _keyless_merge_plan, fuse_pipelines,
+    )
+    from blaze_tpu.ops.parquet_scan import FileRange, ParquetScanExec
+
+    columns = ["ss_quantity"] + (["ss_net_paid"] if agg == "avg" else [])
+    fused = fuse_pipelines(HashAggregateExec(
+        FilterExec(
+            ParquetScanExec([[FileRange(sales_parquet)]],
+                            projection=columns),
+            (Col("ss_quantity") >= 21) & (Col("ss_quantity") <= 40)),
+        keys=[],
+        aggs=[(AggExpr(AggFn.COUNT_STAR, None), "cnt") if agg == "count"
+              else (AggExpr(AggFn.AVG, Col("ss_net_paid")), "avg")],
+        mode=AggMode.COMPLETE,
+    )).children[0]
+    cb = next(iter(fused.children[0].execute(0, ExecContext())))
+    pv = packed_view(cb)
+    assert pv is not None and cb.capacity == 16384
+    plan = _keyless_merge_plan(fused.agg.aggs, fused.agg.schema.fields)
+    inner = fused._build_kernel_packed(pv, group_cap=1)
+    buf = jax.ShapeDtypeStruct(pv.buf.shape, pv.buf.dtype,
+                               sharding=one_chip)
+    first = _build_carry_kernel(inner, plan, False)
+    if not with_carry:
+        jax.jit(lambda b: first(b, None, None)).lower(buf).compile()
+        return
+    states, _packed = jax.eval_shape(lambda b: first(b, None, None), buf)
+    carry = [
+        tuple(None if x is None else jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip) for x in pair)
+        for pair in states
+    ]
+    merge = _build_carry_kernel(inner, plan, True)
+    jax.jit(lambda b, c: merge(b, None, None, c)).lower(buf, carry) \
+        .compile()
+
+
 # ---- kernels with no path from blaze_tpu/: the refusal, on record ----
 # Interpret mode passes all of these (tests/test_pallas_kernels.py);
 # the v5e compiler does not. The BLAZE_SEGREDUCE selector that reached

@@ -842,6 +842,45 @@ def test_poll_stage_table(shape, wire_plane, keyed_parquet, tmp_path):
         assert stages["shuffle_finalize"]["n"] == 1
 
 
+@pytest.mark.parametrize("core, fetches", [("scatter", 1), ("sort", 3)])
+def test_poll_stage_table_names_the_grouped_aggregates_waits(
+        core, fetches, keyed_parquet, monkeypatch):
+    """`agg_fetch` in a grouped aggregate: on the sort core (what `auto`
+    resolves to on a TPU) `FusedAggregateExec._run_agg` waits for every
+    batch's program, a stage a batch; on the scatter core the grouped
+    carry syncs without a stage and `HostFinalAggExec` finalizes the one
+    state batch in one."""
+    from blaze_tpu.exprs import AggExpr, AggFn
+    from blaze_tpu.ops import AggMode, HashAggregateExec
+    from blaze_tpu.plan.serde import task_to_proto
+    from blaze_tpu.runtime.gateway import TaskGatewayServer
+    from blaze_tpu.service import ServiceClient
+
+    monkeypatch.setenv("BLAZE_GROUP_CORE", core)
+    plan = HashAggregateExec(
+        FilterExec(ParquetScanExec([[FileRange(keyed_parquet)]]),
+                   Col("v") > 10),
+        keys=[(Col("k"), "k")],
+        aggs=[(AggExpr(AggFn.SUM, Col("v")), "s")],
+        mode=AggMode.COMPLETE,
+    )
+    # mesh off: a chip's `serve` has one device, conftest.py's eight
+    # virtual ones would take a grouped aggregate to the mesh tier
+    with QueryService(max_concurrency=1, mesh_mode="off") as svc:
+        with TaskGatewayServer(service=svc) as srv:
+            with ServiceClient(*srv.address) as c:
+                st = c.submit(task_to_proto(plan, 0))
+                rows = sum(b.num_rows for b in c.fetch(st["query_id"]))
+                poll = c.poll(st["query_id"])
+    assert poll["state"] == "DONE" and rows == 501  # 500 keys and NULL
+    stages = poll["stages"]
+    assert stages["agg_fetch"]["n"] == fetches
+    assert "compact" not in stages
+    assert stages["agg_fetch"]["wall_s"] <= poll["execution_s"]
+    # the keyless carry's counter is not a grouped task's
+    assert "agg_carry_batches" not in poll
+
+
 class _MeetAfterFirstBatch(PhysicalOp):
     """Passes its child's batches through and waits at a barrier once
     the first is out: two tasks built on one barrier are in flight
