@@ -270,7 +270,10 @@ class ColumnBatch:
         All device buffers travel in ONE packed transfer (a single device
         round trip regardless of column count), sliced on device to the
         smallest shape bucket covering the live rows so padding beyond it
-        never crosses the wire."""
+        never crosses the wire. A pending selection travels with them
+        and the rows are trimmed by it on the host: the result sinks
+        (`ops/util.py: sink_arrow`) read a filtered batch back this way,
+        with no device compaction and no wait for a row count."""
         if obs_trace.ACTIVE:
             # obs seam: the d2h stage - the packed transfer, its wait
             # on the device, and the Arrow assembly
@@ -301,10 +304,11 @@ class ColumnBatch:
             host_cols.append((v, m))
 
         n = self.num_rows
-        sel = None
+        keep = None
         if self.selection is not None:
-            sel = np.asarray(host_sel)[:n]
-            n = int(sel.sum())
+            # the host trim: one index vector shared by every column
+            keep = np.flatnonzero(np.asarray(host_sel)[:n])
+            n = len(keep)
         arrays = []
         fields = []
         for field, col, (hv, hm) in zip(
@@ -314,10 +318,10 @@ class ColumnBatch:
             mask = None
             if hm is not None:
                 mask = ~np.asarray(hm)[: self.num_rows]
-            if sel is not None:
-                vals = vals[sel]
+            if keep is not None:
+                vals = vals[keep]
                 if mask is not None:
-                    mask = mask[sel]
+                    mask = mask[keep]
             dt = field.dtype
             if dt.is_dictionary_encoded:
                 codes = vals.astype(np.int32)

@@ -8,7 +8,11 @@ cross-op fusion. The
 `fuse_pipelines` pass rewrites maximal stateless chains into a
 FusedPipelineExec whose whole chain traces into a single program; the
 deferred selection vector (batch.ColumnBatch.selection) carries filter
-results through without any host sync.
+results through without any host sync. A result sink takes the batch
+with that selection and trims on the host after its one read-back
+(ops/util.py: sink_arrow); only an operator that goes on computing on
+the device with the rows packs them there first (ops/util.py: compact,
+one sync for the row count).
 
 Aggregate folding goes further (the reference's one-native-call-per-task
 model, exec.rs:196-255): a PARTIAL aggregate fuses into the producing

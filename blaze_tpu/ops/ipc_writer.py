@@ -13,7 +13,7 @@ from blaze_tpu.types import Schema
 from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.io.ipc import encode_ipc_segment
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
-from blaze_tpu.ops.util import ensure_compacted
+from blaze_tpu.ops.util import sink_arrow
 
 
 class IpcWriterExec(PhysicalOp):
@@ -30,11 +30,11 @@ class IpcWriterExec(PhysicalOp):
         sink = ctx.resources.setdefault(self.resource_id, [])
         nbytes = 0
         for cb in self.children[0].execute(partition, ctx):
-            cb = ensure_compacted(cb)
-            if cb.num_rows == 0:
+            rb = sink_arrow(cb, ctx)
+            if rb is None:
                 continue
             part = encode_ipc_segment(
-                cb.to_arrow(), ctx.config.ipc_compression_level
+                rb, ctx.config.ipc_compression_level
             )
             nbytes += len(part)
             sink.append(part)

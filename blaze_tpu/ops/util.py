@@ -1,5 +1,5 @@
-"""Shared batch utilities for operators: device gather/compact, host-side
-dictionary unification, batch concatenation."""
+"""Shared batch utilities for operators: device gather/compact, a result
+sink's read-back, host-side dictionary unification, batch concatenation."""
 
 from __future__ import annotations
 
@@ -51,7 +51,10 @@ def _compact_indices(mask: jax.Array, capacity: int):
 
 def compact(cb: ColumnBatch, mask: Optional[jax.Array] = None) -> ColumnBatch:
     """Keep rows where mask (AND the batch's own selection) is True, packed
-    to the front (one D2H sync for the surviving row count)."""
+    to the front (one D2H sync for the surviving row count). Paid by the
+    callers that go on computing on the device with the rows (shuffle
+    write, limit, joins, sort, concat_batches, the mesh tier); a sink
+    that reads the whole batch back takes `sink_arrow` and pays none."""
     if obs_trace.ACTIVE:
         # obs seam: the compact stage - the live mask's three eager
         # launches, the index program, the wait for the row count and
@@ -74,6 +77,19 @@ def ensure_compacted(cb: ColumnBatch) -> ColumnBatch:
     if cb.selection is None:
         return cb
     return compact(cb)
+
+
+def sink_arrow(cb: ColumnBatch, ctx):
+    """A result sink's read-back: the batch leaves the device as the
+    program left it, selection and all, in `to_arrow`'s one packed
+    transfer, and is trimmed on the host - no device compaction and no
+    wait for a row count. None when no row survives."""
+    if cb.num_rows == 0:
+        return None
+    if cb.selection is not None:
+        ctx.metrics.add("sink_trim_batches", 1)
+    rb = cb.to_arrow()
+    return rb if rb.num_rows else None
 
 
 def unify_dictionaries(batches: List[ColumnBatch]) -> List[ColumnBatch]:

@@ -23,7 +23,7 @@ from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.errors import ErrorClass, classify
 from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.ops.base import ExecContext, MetricNode, PhysicalOp
-from blaze_tpu.ops.util import ensure_compacted
+from blaze_tpu.ops.util import sink_arrow
 from blaze_tpu.testing import chaos
 
 log = logging.getLogger("blaze_tpu.executor")
@@ -182,10 +182,9 @@ def execute_partition(op: PhysicalOp, partition: int, ctx: ExecContext
                     cb = next(stream, None)
                     if cb is None:
                         break
-                    cb = ensure_compacted(cb)
-                    if cb.num_rows == 0:
+                    rb = sink_arrow(cb, ctx)
+                    if rb is None:
                         continue
-                    rb = cb.to_arrow()
                 ctx.metrics.add("output_rows", rb.num_rows)
                 ctx.metrics.add("output_batches", 1)
                 yield rb

@@ -392,6 +392,10 @@ class Query:
             # batches a keyless aggregate merged into its device carry
             # with no read-back (ops/fused.py)
             out["agg_carry_batches"] = m["agg_carry_batches"]
+        if "sink_trim_batches" in m:
+            # filtered batches the result sink read back whole and
+            # trimmed on the host (ops/util.py: sink_arrow)
+            out["sink_trim_batches"] = m["sink_trim_batches"]
         if self.tracer is not None and self.state in TERMINAL_STATES:
             # per-task stage table, folded from the task's own spans:
             # {stage: {wall_s, cpu_s, n}}. A POLL after FETCH carries

@@ -784,8 +784,10 @@ WIRE_STAGES = {"frame_encode", "frame_send"}
 
 
 @pytest.mark.parametrize("wire_plane", ["async", "threaded"])
-@pytest.mark.parametrize("shape", ["q6_scan", "shuffle_write"])
+@pytest.mark.parametrize("shape", ["q6_scan", "limit_over_filter",
+                                   "shuffle_write"])
 def test_poll_stage_table(shape, wire_plane, keyed_parquet, tmp_path):
+    from blaze_tpu.ops import LimitExec
     from blaze_tpu.ops.shuffle_writer import ShuffleWriterExec
     from blaze_tpu.plan.serde import task_to_proto
     from blaze_tpu.runtime.gateway import TaskGatewayServer
@@ -793,7 +795,14 @@ def test_poll_stage_table(shape, wire_plane, keyed_parquet, tmp_path):
 
     scan = ParquetScanExec([[FileRange(keyed_parquet)]])
     if shape == "q6_scan":
+        # the result sink reads a filtered batch back whole and trims
+        # it on the host: no compaction on the device
         plan = FilterExec(scan, Col("v") > 10)
+        want = SCAN_STAGES | WIRE_STAGES
+    elif shape == "limit_over_filter":
+        # a limit goes on computing with the rows, so it packs them
+        # on the device first and the sink sees no selection
+        plan = LimitExec(FilterExec(scan, Col("v") > 10), 39000)
         want = SCAN_STAGES | {"compact"} | WIRE_STAGES
     else:
         plan = ShuffleWriterExec(
@@ -823,7 +832,11 @@ def test_poll_stage_table(shape, wire_plane, keyed_parquet, tmp_path):
         assert sum(stages[name]["wall_s"] for name in thread) \
             <= poll["execution_s"], thread
     assert poll["task_dispatches"] == poll["dispatches"] > 0
-    if shape == "q6_scan":
+    assert poll.get("sink_trim_batches") == \
+        (3 if shape == "q6_scan" else None)
+    if shape == "limit_over_filter":
+        assert stages["compact"]["n"] == 3
+    if shape != "shuffle_write":
         assert "shuffle_segments" not in poll
     else:
         # the parts the task encoded are the parts in its files: the
