@@ -5,7 +5,7 @@ compilation volume of LARGE MANY-OUTPUT programs in one process.
 History (rounds 2-3 of this build): the full TPC-DS differential suite
 run in a single process reliably dies with SIGSEGV inside
 `backend_compile_and_load` after a few hundred query compilations. The
-round-3 bisect (run_tests.py docstring) excluded:
+round-3 bisect (docs/JAXLIB_SEGFAULT.md) excluded:
   - thread concurrency        (BLAZE_TASK_THREADS=1 still crashes)
   - the engine's C++ tier     (BLAZE_DISABLE_NATIVE=1 still crashes)
   - executable eviction       (cache cap 0 + no clears still crash)
@@ -31,8 +31,9 @@ documented as the exit rather than executed here. To run it elsewhere:
     python -m venv /tmp/v && . /tmp/v/bin/activate
     pip install -U jax jaxlib
     python benchmarks/jaxlib_segfault_repro.py
-If a newer jaxlib survives, drop run_tests.py's process sharding and
-record the single-process suite wall-clock.
+If a newer jaxlib survives, drop the cache clear of tests/conftest.py
+and the child process of tests/test_tpcds_exchange.py (OWN_PROCESS),
+and see what the gate's time becomes.
 """
 
 import os

@@ -814,8 +814,7 @@ def _place_compile_cache() -> None:
     """The persistent compile cache is placed from outside: where
     JAX_COMPILATION_CACHE_DIR is set jax already reads it and nothing
     is set here; otherwise it lives at a fixed path in the checkout
-    (the path is part of the cache key - bench.py and run_tests.py
-    hand their children the same one)."""
+    (the path is part of the cache key)."""
     import os
 
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):

@@ -55,22 +55,20 @@ class EngineConfig:
     # addressing hash table built from scatter/gather, sort-free - the
     # O(n) path), "sort" (stable lexsort + boundary detection), or
     # "auto" (scatter on the CPU backend where an 8M-row sort costs
-    # ~3.5s vs ~0.1s for the table; on TPU a same-chip VALIDATED
-    # benchmarks/tpu_core_probe.json decides, falling back to sort
-    # when no chip measurement exists - resolve_core_choice below).
+    # ~3.5s vs ~0.1s for the table; sort on TPU, where the scatter
+    # core has no chip measurement yet - resolve_core_choice below).
     # Env override: BLAZE_GROUP_CORE.
     group_core: str = "auto"
     # Join-core selection for the unique-build fast path (hash-table
     # probe, no sort/searchsorted/pair-expansion): same choices and
-    # rationale as group_core; auto-on-TPU rides the probe's group
-    # measurement. Env override: BLAZE_JOIN_CORE.
+    # rationale as group_core. Env override: BLAZE_JOIN_CORE.
     join_core: str = "auto"
     # Multi-key argsort selection: "scatter" here means the packed-u64
     # single-lane value sort (one XLA sort per key); "sort" the 3-lane
     # index lexsort ladder. "auto" = packed on CPU; on TPU the lexsort
-    # ladder unless a same-chip probe artifact VALIDATED the packed
-    # permutation there (the no-X64 rewrite pass lacks full u64
-    # support, exprs/hashing.py:83 - timing alone never flips this).
+    # ladder (the no-X64 rewrite pass lacks full u64 support,
+    # exprs/hashing.py:83, so the packed permutation must be validated
+    # on the chip before timing may select it).
     # Env override: BLAZE_SORT_CORE.
     sort_core: str = "auto"
     # Evaluate pushed-down filter conjuncts host-side during parquet
@@ -95,45 +93,14 @@ class EngineConfig:
         return d
 
 
-_PROBE_CACHE = None
-
-
-def _probe_artifact():
-    """The recorded on-chip core measurement, if one exists.
-
-    bench.py's tpu_core_probe writes benchmarks/tpu_core_probe.json
-    when it reaches a real chip (the end-of-round driver run); `auto`
-    core choices then derive from MEASURED data instead of a guess.
-    Absent/stale file -> None and the heuristic stands."""
-    global _PROBE_CACHE
-    if _PROBE_CACHE is not None:
-        return _PROBE_CACHE or None
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "tpu_core_probe.json",
-    )
-    probe = {}
-    try:
-        with open(path) as f:
-            import json
-
-            probe = json.load(f)
-    except Exception:  # noqa: BLE001 - missing/corrupt = no data
-        probe = {}
-    if not isinstance(probe, dict):
-        probe = {}
-    _PROBE_CACHE = probe or False
-    return probe or None
-
-
 def resolve_core_choice(env_var: str, cfg_value: str) -> str:
     """Shared resolution for the grouping/join core knobs: env override
     beats config; "auto" picks the scatter core on CPU (where the sort
-    it replaces costs 20-35x more) and on TPU consults the recorded
-    tpu_core_probe artifact when one exists (falling back to sort, the
-    conservative guess, when no chip measurement was ever captured).
-    Unknown values raise so a typo'd knob can't silently measure the
-    wrong core."""
+    it replaces costs 20-35x more) and the sort core on TPU, the
+    conservative choice until a pair of chip runs of the benchmark's
+    own grouped cell under BLAZE_GROUP_CORE says otherwise (ROADMAP
+    S4). Unknown values raise so a typo'd knob can't silently measure
+    the wrong core."""
     mode = os.environ.get(env_var) or cfg_value
     if mode not in ("auto", "scatter", "sort"):
         raise ValueError(
@@ -144,31 +111,6 @@ def resolve_core_choice(env_var: str, cfg_value: str) -> str:
 
         if jax.default_backend() == "cpu":
             return "scatter"
-        probe = _probe_artifact()
-        if probe:
-            # the probe measures the group and sort cores; the join
-            # knob rides the group result (same scatter-table
-            # machinery). Trust requires BOTH (a) the artifact came
-            # from THIS chip generation and (b) the probe
-            # cross-validated the two cores' outputs on it - timing
-            # alone never flips a core (the packed-u64 sort path in
-            # particular is correctness-gated on TPU's partial i64
-            # support, so an unvalidated fast time must not select it)
-            try:
-                same_chip = (
-                    probe.get("device_kind")
-                    == jax.devices()[0].device_kind
-                )
-            except Exception:  # noqa: BLE001
-                same_chip = False
-            kind = "sort" if "SORT" in env_var else "group"
-            sc = probe.get(f"{kind}_scatter_s")
-            so = probe.get(f"{kind}_sort_s")
-            if (same_chip
-                    and probe.get(f"{kind}_valid") is True
-                    and isinstance(sc, (int, float))
-                    and isinstance(so, (int, float))):
-                return "scatter" if sc <= so else "sort"
         return "sort"
     return mode
 

@@ -596,8 +596,7 @@ def build_doc(d1: Dict[str, Any], dn: Dict[str, Any]) -> Dict[str, Any]:
             "subphase_share_of_wall": shares,
         },
         # regress-snapshot shape: {class: {phase: {n, p50, ...}}} -
-        # run_tests.py --smoke diffs the two most recent rounds of
-        # THIS through the existing `regress --bench` path
+        # `regress --bench` diffs two rounds of THIS
         "phases": {"snapshot": {"_all": {
             **{n: st for n, st in subs.items()},
             **({"stage_wall": dn["reconcile"] and {

@@ -25,8 +25,7 @@ module is the aggregation that makes those measurements diffable:
   * `run_probe()` executes a small fixed workload through a real
     QueryService with tracing on and returns its rollup snapshot:
     the reproducible measurement behind `regress --against
-    PHASE_BASELINE.json` (wired into `run_tests.py --smoke`) and
-    `regress --emit-baseline`.
+    PHASE_BASELINE.json` and `regress --emit-baseline`.
 
 Bounded like obs/history.py: at most `max_classes` classes (LRU), at
 most `samples_per_phase` samples per (class, phase) ring. The process-
@@ -556,7 +555,7 @@ def load_baseline(path: str) -> Dict[str, Any]:
 
 def phases_from_bench(path: str) -> Optional[Dict[str, Any]]:
     """Extract the per-phase rollup a BENCH_r*.json artifact recorded
-    (bench.py's `phases` shape). Handles both the driver wrapper
+    (`{"phases": {"snapshot": ...}}`). Handles both the driver wrapper
     ({n, cmd, rc, tail}) and a bare battery result, plus the
     MESHATTR_r*.json mesh-attribution artifacts (obs/meshprof.py),
     which carry their per-sub-phase p50s in the same snapshot shape

@@ -661,8 +661,7 @@ def dense_group_ids(
     The production scatter core no longer calls this: hash_aggregate
     reduces on RAW slots and compacts only the (out_cap,)-sized states
     (inlining the occupied/nonzero/bpos math here, minus the full-row
-    gid gather). This remains the reference formulation and the
-    bench's tpu_core_probe measurement target."""
+    gid gather). This remains the reference formulation."""
     occupied = rep_tab != jnp.int32(capacity)
     gid_of_slot = jnp.cumsum(occupied.astype(jnp.int32)) - 1
     row_gid = jnp.where(

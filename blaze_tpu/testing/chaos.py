@@ -293,9 +293,9 @@ def active(faults: List[Fault], seed: int = 0):
 
 
 def seed_offset() -> int:
-    """Seed-sweep hook (`run_tests.py --chaos --seeds N`): a nonzero
-    BLAZE_CHAOS_SEED_OFFSET shifts the seed of every FaultPlan
-    installed through `active()`, so the same chaos suite hunts race
+    """Seed-sweep hook (`BLAZE_CHAOS_SEED_OFFSET=N python -m pytest
+    tests/test_chaos.py`, once per N): a nonzero offset shifts the
+    seed of every FaultPlan installed through `active()`, so the same chaos suite hunts race
     regressions under N different probabilistic firing sequences
     instead of the one fixed seed baked into each test. A UNIFORM
     shift preserves the suite's seed invariants (same seed -> same

@@ -55,8 +55,8 @@ RTOL = 1e-6
 
 
 def gen_tables(n_rows: int, seed: int):
-    """store_sales / date_dim / item with benchmarks/run_report.py's
-    column types: int32 keys, one int64 customer key, float32 money."""
+    """store_sales / date_dim / item in TPC-DS column types: int32
+    keys, one int64 customer key, float32 money."""
     import numpy as np
     import pandas as pd
 
@@ -288,8 +288,8 @@ def repartition(paths, tables, k):
 
     def check(batches):
         ss = tables["ss"]
-        # the host oracle run_report.py uses: Spark murmur3 (seed 42)
-        # chained over the int64 key, then pmod
+        # the host oracle: Spark murmur3 (seed 42) chained over the
+        # int64 key, then pmod
         h = _chain_fixed(
             ss.ss_customer_sk.values, None, DataType.int64(),
             np.full(len(ss), 42, dtype=np.uint32),
@@ -462,7 +462,7 @@ def serve_and_send(env, workdir, extra, sends):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    # 8,388,608 rows (bench.py's battery size) run right on a v5e but
+    # 8,388,608 rows run right on a v5e but
     # take ~1,400 s cold, nearly all of it XLA sort compiles; the smoke
     # has 1,200 s, so the default is the size that was SEEN to finish
     # inside it: 1,048,576 rows, ~460 s cold (my chip run, PR 23)

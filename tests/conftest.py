@@ -40,10 +40,6 @@ _test_counter = {"n": 0}
 @pytest.fixture(autouse=True)
 def _compile_cache_hygiene():
     yield
-    import os
-
-    if os.environ.get("BLAZE_NO_CACHE_CLEAR"):
-        return
     _test_counter["n"] += 1
     if _test_counter["n"] % _CACHE_CLEAR_EVERY == 0:
         import gc

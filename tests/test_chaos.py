@@ -563,7 +563,8 @@ def _battery_shapes(tmp_path):
 
 
 def test_battery_shapes_identical_under_transient_chaos(tmp_path):
-    """run_tests.py --chaos core: each battery shape, executed with a
+    """The chaos suite's core (`python -m pytest tests/test_chaos.py`):
+    each battery shape, executed with a
     fixed chaos seed injecting ONE transient fault, produces results
     identical to the fault-free run (the retry machinery is invisible
     to correctness)."""
@@ -667,7 +668,8 @@ def _canon_bytes(t: pa.Table):
 
 
 def test_fused_relational_byte_equal_and_chaos_parity():
-    """run_tests.py --chaos --seeds N member (ISSUE 13): for each
+    """Member of the seed sweep (`BLAZE_CHAOS_SEED_OFFSET=N python -m
+    pytest tests/test_chaos.py`; ISSUE 13): for each
     relational-core shape, the FUSED plan's Arrow output is BYTE-equal
     (canonical order, serialized IPC) to the unfused operator ladder -
     and stays byte-equal when a transient kernel.dispatch fault fires

@@ -6,7 +6,8 @@ Two tiers:
     replica is drained (finish in-flight, DRAINING-reject new work),
     LEAVEs, and a replacement JOINs - all while a repeated-query mix
     runs through the router. Zero client-visible failures.
-  * subprocess e2e (slow; `run_tests.py --churn`): three `serve`
+  * subprocess e2e (slow; `python -m pytest tests/test_churn.py -m
+    slow`): three `serve`
     processes that JOIN a bootstrap-empty `route` CLI, SIGTERM-drained
     and respawned in turn under a live query mix - zero failures,
     drained replicas rejoin via JOIN - then the affinity home of a hot

@@ -4,7 +4,8 @@ store, predicted-unmeetability shedding, the structured STATS payload,
 the slow-query log, the METRICS/REPORT wire surface, cross-process
 trace stitching, and the obs-off wall-overhead guarantee.
 
-`run_tests.py --trace` selects the `trace`-named subset: the
+`python -m pytest tests/test_obs.py -k trace` selects the
+`trace`-named subset: the
 chaos-retried multi-partition query whose exported trace must validate
 against the minimal Chrome-trace-event schema (matched B/E pairs,
 monotonic ts, attempt spans present)."""

@@ -367,3 +367,64 @@ def test_lower_to_mesh_exchange_sandwich_and_fallback():
         mode=AggMode.COMPLETE,
     )
     assert lower_to_mesh(splan) is splan
+
+
+@pytest.mark.parametrize("join", ["inner", "left", "full"])
+def test_exchanges_with_empty_partitions_on_both_sides(join, tmp_path):
+    """A fact side whose keys are {1, 2} joined to a dimension whose
+    keys are {2, 3}, through insert_exchanges at four partitions: one
+    partition is empty on both sides of the shuffle, key 1's holds left
+    rows only and key 3's right rows only. The SMJ over the co-partitioned files and the
+    PARTIAL / exchange / FINAL aggregate above it give pandas' answer.
+    (The TPC-DS exchange matrix met such partitions by chance on its
+    small dimensions while it cut every plan in four.)"""
+    import pandas as pd
+
+    from blaze_tpu.exprs.hashing import hash_long_host
+    from blaze_tpu.ops.joins import JoinType, SortMergeJoinExec
+    from blaze_tpu.planner.distribute import insert_exchanges
+
+    n_parts = 4
+
+    def pmod(k):
+        return int(np.int32(np.uint32(hash_long_host(k) & 0xFFFFFFFF))
+                   ) % n_parts
+
+    left = pd.DataFrame({"lk": [1, 2] * 50, "lv": np.arange(100)})
+    right = pd.DataFrame({"rk": [2, 3] * 5, "rv": np.arange(10) * 7})
+    # three keys in three different partitions: one of the four is
+    # empty on both sides, one on the right only, one on the left only
+    assert len({pmod(1), pmod(2), pmod(3)}) == 3
+
+    def scan(df):
+        cb = ColumnBatch.from_arrow(
+            pa.RecordBatch.from_pandas(df, preserve_index=False))
+        return MemoryScanExec([[cb]], cb.schema)
+
+    plan = HashAggregateExec(
+        SortMergeJoinExec(scan(left), scan(right), ["lk"], ["rk"],
+                          JoinType(join)),
+        keys=[(Col("lk"), "lk"), (Col("rk"), "rk")],
+        aggs=[(AggExpr(AggFn.SUM, Col("lv")), "s"),
+              (AggExpr(AggFn.COUNT_STAR, None), "n")],
+        mode=AggMode.COMPLETE,
+    )
+    plan = insert_exchanges(plan, n_parts, shuffle_dir=str(tmp_path))
+    assert plan.mode is AggMode.FINAL
+    got = run_plan(plan).to_pandas()
+
+    how = {"inner": "inner", "left": "left", "full": "outer"}[join]
+    want = (
+        left.merge(right, left_on="lk", right_on="rk", how=how)
+        .groupby(["lk", "rk"], dropna=False)
+        .agg(s=("lv", lambda v: v.sum(min_count=1)), n=("lv", "size"))
+        .reset_index()
+    )
+
+    def rows(df):
+        return sorted(
+            tuple(-1 if pd.isna(x) else int(x) for x in r)  # -1: NULL
+            for r in df[["lk", "rk", "s", "n"]].itertuples(index=False)
+        )
+
+    assert rows(got) == rows(want)

@@ -263,8 +263,8 @@ def test_regress_cli_baseline_roundtrip(tmp_path, capsys):
 
 
 def test_regress_bench_artifact_diff(tmp_path, capsys):
-    """--bench OLD NEW: per-phase p50s recorded by bench.py's
-    `phases` shape diff across rounds; wrapper artifacts ({n, cmd,
+    """--bench OLD NEW: per-phase p50s recorded in the
+    `{"phases": {"snapshot": ...}}` shape diff across rounds; wrapper artifacts ({n, cmd,
     rc, tail}) and bare battery results both parse."""
     from blaze_tpu.__main__ import main as cli_main
 

@@ -17,6 +17,8 @@ exchanges.
 """
 
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pandas as pd
@@ -28,7 +30,7 @@ from blaze_tpu.ops.parquet_scan import FileRange, ParquetScanExec
 from blaze_tpu.planner.distribute import insert_exchanges
 from blaze_tpu.runtime.executor import run_plan
 
-from tests.tpcds_support import QUERIES, gen_tables
+from tests.tpcds_support import QUERIES, gen_tables, scans_of
 from tests.test_tpcds_queries import ORACLES, assert_frames_match
 
 # join/agg-heavy subset plus window/sort queries (insert_exchanges
@@ -57,11 +59,26 @@ EXCHANGE_QUERIES = [
 
 RANK_TOLERANT = {"q67", "q86"}
 
-N_EXCHANGE_PARTITIONS = 4
+# The breadth matrix crosses every exchange at two partitions: an SMJ on
+# co-partitioned shuffle files, a BHJ on a broadcast and a PARTIAL /
+# exchange / FINAL aggregate all exist at two, and a plan cut in four
+# makes about twice the distinct small programs (q7 cold: 42 s at four,
+# 21 s at two), which is what the file costs. The deep cases, the
+# join- and aggregate-heaviest plans from parquet, keep four. An empty
+# shuffle partition, which four cuts of a small dimension gave by
+# chance, is pinned in tests/test_parallel.py.
+BREADTH_PARTITIONS = 2
+DEEP_PARTITIONS = 4
+
+# q64 and q80 compile the most and the largest programs of the corpus.
+# Twice on record they were the plan under which jaxlib's compile-volume
+# SIGSEGV (docs/JAXLIB_SEGFAULT.md) took an xdist worker down, the
+# worker's warm cache and a second case with it. Their plans run in a
+# child process, so a crash costs the one case.
+OWN_PROCESS = {"q64", "q80"}
 
 
-@pytest.fixture(scope="module")
-def env(tmp_path_factory):
+def _install_config():
     from blaze_tpu.config import EngineConfig, set_config
 
     n = int(os.environ.get("BLAZE_TPCDS_ROWS", 20_000))
@@ -71,37 +88,75 @@ def env(tmp_path_factory):
             shape_buckets=(256, 4096, 65536, 1 << 20, max(n, 1 << 20)),
         )
     )
-    tables = gen_tables()
 
-    from blaze_tpu import ColumnBatch
-    from blaze_tpu.ops import MemoryScanExec
 
-    mem_scans = {}
-    for name, df in tables.items():
-        rb = pa.RecordBatch.from_pandas(df, preserve_index=False)
-        cb = ColumnBatch.from_arrow(rb)
-        mem_scans[name] = lambda cb=cb: MemoryScanExec([[cb]], cb.schema)
-
-    pq_dir = tmp_path_factory.mktemp("tpcds_parquet")
-    pq_scans = {}
-    for name, df in tables.items():
-        path = str(pq_dir / f"{name}.parquet")
-        pq.write_table(
-            pa.Table.from_pandas(df, preserve_index=False), path,
-            row_group_size=1 << 16,
-        )
-        pq_scans[name] = (
+def _pq_scans(names, pq_dir):
+    scans = {}
+    for name in names:
+        path = os.path.join(pq_dir, f"{name}.parquet")
+        scans[name] = (
             lambda path=path: ParquetScanExec([[FileRange(path)]])
         )
-    return tables, mem_scans, pq_scans
+    return scans
 
 
-def _run(scans, q, tmp_path):
+@pytest.fixture(scope="module")
+def env(tmp_path_factory):
+    _install_config()
+    tables = gen_tables()
+    pq_dir = str(tmp_path_factory.mktemp("tpcds_parquet"))
+    for name, df in tables.items():
+        pq.write_table(
+            pa.Table.from_pandas(df, preserve_index=False),
+            os.path.join(pq_dir, f"{name}.parquet"),
+            row_group_size=1 << 16,
+        )
+    return tables, scans_of(tables), pq_dir
+
+
+def _run_plan(scans, q, shuffle_dir, n_partitions):
     plan = QUERIES[q](scans, "smj")
     plan = insert_exchanges(
-        plan, N_EXCHANGE_PARTITIONS, shuffle_dir=str(tmp_path)
+        plan, n_partitions, shuffle_dir=shuffle_dir
     )
-    return run_plan(plan).to_pandas()
+    return run_plan(plan)
+
+
+def _run(env, q, tmp_path, n_partitions, from_parquet=False):
+    """The query's frame through the exchanges; `from_parquet` sources
+    every table from the fixture's parquet files."""
+    tables, mem_scans, pq_dir = env
+    if q in OWN_PROCESS:
+        return _run_in_child(
+            q, str(tmp_path), n_partitions,
+            pq_dir if from_parquet else "",
+        ).to_pandas()
+    scans = _pq_scans(tables, pq_dir) if from_parquet else mem_scans
+    return _run_plan(scans, q, str(tmp_path), n_partitions).to_pandas()
+
+
+def _run_in_child(q, shuffle_dir, n_partitions, pq_dir):
+    """Run `_child_main` in a fresh interpreter and read back the Arrow
+    table it wrote: the same `run_plan(...)` result, `to_pandas` left
+    to the caller as in process."""
+    out = os.path.join(shuffle_dir, "result.arrow")
+    subprocess.run(
+        [sys.executable, "-m", "tests.test_tpcds_exchange", q,
+         shuffle_dir, str(n_partitions), pq_dir, out],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        check=True, timeout=1200,
+    )
+    with pa.ipc.open_file(out) as r:
+        return r.read_all()
+
+
+def _child_main(q, shuffle_dir, n_partitions, pq_dir, out):
+    _install_config()
+    tables = gen_tables()  # the feather cache the parent filled: ~1 s
+    scans = _pq_scans(tables, pq_dir) if pq_dir else scans_of(tables)
+    table = _run_plan(scans, q, shuffle_dir, int(n_partitions))
+    with pa.ipc.new_file(out, table.schema) as w:
+        w.write_table(table)
 
 
 def _rank_bounds(sums, value, rel=1e-6):
@@ -203,8 +258,8 @@ def _assert_rank_tolerant_q67(got, rolled):
 
 @pytest.mark.parametrize("q", EXCHANGE_QUERIES)
 def test_query_through_shuffle_exchanges(env, q, tmp_path):
-    tables, mem_scans, _ = env
-    got = _run(mem_scans, q, tmp_path)
+    tables = env[0]
+    got = _run(env, q, tmp_path, BREADTH_PARTITIONS)
     exp = ORACLES[q](tables)
     exp.columns = list(got.columns)
     if q in RANK_TOLERANT:
@@ -227,8 +282,12 @@ PARQUET_QUERIES = ["q1", "q6", "q23", "q64", "q80", "q94"]
 
 @pytest.mark.parametrize("q", PARQUET_QUERIES)
 def test_query_through_parquet_and_exchanges(env, q, tmp_path):
-    tables, _, pq_scans = env
-    got = _run(pq_scans, q, tmp_path)
+    tables = env[0]
+    got = _run(env, q, tmp_path, DEEP_PARTITIONS, from_parquet=True)
     exp = ORACLES[q](tables)
     exp.columns = list(got.columns)
     assert_frames_match(got, exp, f"{q}/parquet-shuffle")
+
+
+if __name__ == "__main__":
+    _child_main(*sys.argv[1:])

@@ -36,6 +36,7 @@ from blaze_tpu.runtime.transport import _recv_exact
 from blaze_tpu.service import QueryService, ServiceClient
 from blaze_tpu.service import wire as wire_mod
 from blaze_tpu.service.wire import VERB_FETCH
+from blaze_tpu.service.wire_async import dispatch_pool
 from blaze_tpu.testing import chaos
 from blaze_tpu.testing.chaos import Fault
 from tests.test_service import GatedScan, wait_for
@@ -79,6 +80,13 @@ def _open_fds() -> int:
     return len(os.listdir("/proc/self/fd"))
 
 
+def _threads_outside_dispatch_pools() -> int:
+    return sum(
+        not t.name.startswith("blaze-verb-dispatch-")
+        for t in threading.enumerate()
+    )
+
+
 def test_connection_churn_no_leaks():
     """200 connect/verb/close cycles: fd count, thread count, and the
     blaze_connections{tier="service"} gauge all return to baseline."""
@@ -92,7 +100,7 @@ def test_connection_churn_no_leaks():
                 c.run(blob)
             assert wait_for(lambda: _service_conns() == 0)
             fds0 = _open_fds()
-            threads0 = threading.active_count()
+            threads0 = _threads_outside_dispatch_pools()
             for _ in range(200):
                 with ServiceClient(*srv.address) as c:
                     st = c.submit(blob)
@@ -101,7 +109,12 @@ def test_connection_churn_no_leaks():
             # closed fds are reclaimed promptly; allow a little slack
             # for loop-internal churn mid-collection
             assert wait_for(lambda: _open_fds() <= fds0 + 8)
-            assert threading.active_count() <= threads0 + 4
+            # the dispatch pool grows a thread a task up to its cap
+            # (bounded, by design): a connection that left a thread
+            # behind shows outside it
+            assert _threads_outside_dispatch_pools() <= threads0 + 4
+            pool = dispatch_pool("service")
+            assert len(pool._threads) <= pool._max_workers
 
 
 def test_slow_reader_parks_threadless(big_dataset):
@@ -236,22 +249,34 @@ def test_chaos_seams_fire_on_async_path(big_dataset):
 
 
 def test_router_stream_total_budget(big_dataset):
-    """Fleet-wide relay cap: with --stream-total-bytes smaller than
-    two concurrent streams' parts, the second stream's reader waits
-    (stream_total_waits > 0) instead of buffering past the budget,
-    and the buffered-bytes gauge drains back to zero."""
+    """Fleet-wide relay cap: with --stream-total-bytes below one part,
+    a stream parks one part (progress beats the bound) and its reader
+    waits for the second (stream_total_waits > 0) instead of buffering
+    past the budget; every part still arrives, and the buffered-bytes
+    gauge drains back to zero. The clients read nothing until the
+    relay has waited, so the wait comes about whatever the host's
+    kernel buffers absorb short of the whole 6.4 MB stream."""
+    from blaze_tpu.config import EngineConfig, set_config
     from blaze_tpu.router.proxy import Router, RouterServer
 
+    # the scan's own batches, one wire part each, whatever config an
+    # earlier module of this worker installed
+    set_config(EngineConfig())
     blob = big_dataset()
     with QueryService(max_concurrency=2) as svc:
         with TaskGatewayServer(service=svc, wire="async") as srv:
+            with ServiceClient(*srv.address) as c:
+                clean_parts = len(c.fetch(
+                    c.submit(blob, detach=True)["query_id"]
+                ))
+            assert clean_parts > 4
             router = Router(
                 ["%s:%d" % srv.address],
                 poll_interval_s=0.1,
                 heartbeat_timeout_s=2.0,
                 start=False,
                 stream_window=4,
-                stream_total_bytes=2 << 20,
+                stream_total_bytes=64 << 10,
             )
             router.registry.poll_now()
             rsrv = RouterServer(router, wire="async").start()
@@ -261,11 +286,16 @@ def test_router_stream_total_budget(big_dataset):
                         c0.submit(blob, detach=True)["query_id"]
                         for _ in range(2)
                     ]
+                peak = [0]
 
-                def slow_fetch(qid):
+                def relay_waited():
+                    peak[0] = max(peak[0], router._stream_buffered)
+                    return router.counters["stream_total_waits"] > 0
+
+                def stalled_fetch(qid):
                     # raw socket with a tiny receive window (set
-                    # BEFORE connect) so kernel buffering cannot
-                    # absorb the stream - the relay must park bytes
+                    # BEFORE connect) that reads nothing until the
+                    # relay has had to hold a part back
                     sock = socket.socket(socket.AF_INET,
                                          socket.SOCK_STREAM)
                     sock.setsockopt(socket.SOL_SOCKET,
@@ -278,16 +308,18 @@ def test_router_stream_total_budget(big_dataset):
                                 VERB_FETCH, qid, 120_000
                             )
                         )
-                        got = 0
+                        wait_for(relay_waited, timeout=30.0)
+                        got, largest = 0, 0
                         while True:
                             (ln,) = _U64.unpack(
                                 _recv_exact(sock, 8)
                             )
                             if ln == 0:
-                                return got
+                                return got, largest
                             _recv_exact(sock, ln)
                             got += 1
-                            time.sleep(0.1)  # slow consumer
+                            largest = max(largest, ln)
+                            relay_waited()
                     finally:
                         sock.close()
 
@@ -295,7 +327,7 @@ def test_router_stream_total_budget(big_dataset):
                 ts = [
                     threading.Thread(
                         target=lambda i=i, q=q: results.__setitem__(
-                            i, slow_fetch(q)
+                            i, stalled_fetch(q)
                         )
                     )
                     for i, q in enumerate(qids)
@@ -304,9 +336,13 @@ def test_router_stream_total_budget(big_dataset):
                     t.start()
                 for t in ts:
                     t.join(timeout=120)
-                assert results[0] == results[1]
-                assert results[0] and results[0] > 1
                 assert router.counters["stream_total_waits"] > 0
+                assert [r[0] for r in results] == [clean_parts] * 2
+                # under a budget below one part, one parked part a
+                # stream is all the relay may hold
+                largest = max(r[1] for r in results)
+                assert largest > router.stream_total_bytes
+                assert peak[0] <= len(qids) * largest
                 assert wait_for(
                     lambda: router._stream_buffered == 0
                 )
@@ -324,7 +360,6 @@ def test_router_fanin_exceeding_dispatch_pool_no_deadlock():
     pools keep the router->service supply graph acyclic: a fan-in
     wider than the pool must still complete promptly."""
     from blaze_tpu.router.proxy import Router, RouterServer
-    from blaze_tpu.service.wire_async import dispatch_pool
 
     pool_width = dispatch_pool("router")._max_workers
     conc = pool_width + 8  # strictly wider than any one pool

@@ -6065,9 +6065,9 @@ QUERIES.update({
 
 
 # ---------------------------------------------------------------------------
-# table cache: the matrix now runs one query per pytest SUBPROCESS
-# (run_tests.py shards around the jaxlib compile-volume segfault), so
-# without caching every process regenerates the whole synthetic corpus.
+# table cache: every xdist worker of the gate, and every child process
+# a test starts around the jaxlib compile-volume segfault, loads the
+# corpus; without caching each would regenerate all of it.
 # Frames round-trip through feather on disk, keyed by (row scale, seed,
 # generator-source hash) - a generator change invalidates the cache.
 # ---------------------------------------------------------------------------
