@@ -786,11 +786,10 @@ class FusedAggregateExec(PhysicalOp):
         """Shared per-batch aggregate dispatch: run under the retry
         ladder, fetch per the host-finalize policy, wrap the output.
         Returns (ColumnBatch | None, first)."""
-        from blaze_tpu.runtime.dispatch import host_int
-
         from blaze_tpu.config import get_config
         from blaze_tpu.ops.hash_aggregate import (
             _group_core_choice,
+            _group_count,
             run_grouped_kernel,
         )
         from blaze_tpu.runtime.pack import get_packed
@@ -819,18 +818,18 @@ class FusedAggregateExec(PhysicalOp):
             # stays set until a NON-EMPTY batch was host-fetched, so a
             # filtered-out leading batch doesn't push the sole
             # survivor onto the per-column-fetch path.
+            # A count the host knows already (a later cut of the same
+            # result, run_grouped_kernel) is not fetched again.
             if self.fetch_host and first:
-                flat = [n_groups]
+                known = isinstance(n_groups, int)
+                flat = [] if known else [n_groups]
                 for v, m in outs:
                     flat.append(v)
                     flat.append(m)
-                host = get_packed(flat)
-                host_outs = [
-                    (host[1 + 2 * i], host[2 + 2 * i])
-                    for i in range(len(outs))
-                ]
-                return host_outs, int(host[0])
-            return outs, host_int(n_groups)
+                host = iter(get_packed(flat))
+                n = n_groups if known else int(next(host))
+                return [(next(host), next(host)) for _ in outs], n
+            return outs, _group_count(n_groups)
 
         def fetch(outs, n_groups):
             if not (self.fetch_host and first) and not self.agg.keys:
@@ -850,8 +849,9 @@ class FusedAggregateExec(PhysicalOp):
         # group-capacity slicing: state arrays leave the kernel cut
         # to a static slot count so a small grouped result never
         # crosses the wire (or feeds downstream kernels) at input
-        # capacity. Overflow / hash-collision sentinels re-dispatch
-        # (run_grouped_kernel owns the shared retry ladder).
+        # capacity (run_grouped_kernel owns the tiers and the
+        # hash-collision retry; on the sort core the packed first
+        # fetch climbs the cuts of one result, a pack a rung).
         gcap = (1 if not self.agg.keys
                 else min(cap, get_config().agg_group_capacity))
         if gcap >= cap:

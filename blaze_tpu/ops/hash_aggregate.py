@@ -42,7 +42,11 @@ from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.host_lower import lower_strings_host
 from blaze_tpu.ops.project import _unflatten_cvs
 from blaze_tpu.ops.util import concat_batches, sort_indices
-from blaze_tpu.runtime.dispatch import cached_kernel, host_int
+from blaze_tpu.runtime.dispatch import (
+    cached_kernel,
+    current_task,
+    host_int,
+)
 
 
 class AggMode(enum.Enum):
@@ -105,62 +109,148 @@ def _decimal_chunks(cv):
     return [c0, c1, c2, c3]
 
 
+def _group_count(n_groups) -> int:
+    """The group count on the host: one blocking scalar read-back, or
+    nothing where a later cut of the same result is fetched and the
+    count is the host's already (run_grouped_kernel hands it on as an
+    int)."""
+    return n_groups if isinstance(n_groups, int) else host_int(n_groups)
+
+
+def _group_tiers(gcap) -> list:
+    """Static state sizes a grouped result may leave at, smallest
+    first; None is the input's capacity. BLAZE_AGG_TIER1 <= 0 disables
+    the small first tier (one fewer compiled kernel variant per
+    aggregate shape on the scatter core): the test suite sets it
+    because jaxlib's CPU client segfaults under cumulative compile
+    volume (docs/JAXLIB_SEGFAULT.md) and the ladder's extra variants
+    pushed the largest exchange-tier query over the cliff."""
+    import os
+
+    tier1 = int(os.environ.get("BLAZE_AGG_TIER1", "4096"))
+    if gcap is None:
+        return [None]
+    if tier1 <= 0 or tier1 >= gcap:
+        return [gcap, None]
+    return [tier1, gcap, None]
+
+
 def run_grouped_kernel(base_key, build, args, fetch_n, gcap,
                        scatter_class: bool = False,
                        span: str = "group_dispatch"):
-    """Dispatch a grouped-aggregate kernel under the sentinel-retry
-    ladder shared by HashAggregateExec and FusedAggregateExec:
+    """Dispatch a grouped-aggregate kernel for HashAggregateExec and
+    FusedAggregateExec, and hand its states on at the smallest tier
+    (_group_tiers) that holds the group count: most aggregates resolve
+    to a few thousand groups, so a small result never crosses the wire
+    or feeds a downstream kernel at input capacity. Correctness never
+    depends on the slot guess. What a tier is depends on the core:
 
-    - n_groups == -1: narrow-key hash collision between DIFFERENT keys
-      (vanishingly rare) -> re-run the exact full-width lexsort kernel.
-    - n_groups > tier: more groups than static output slots -> climb
-      the capacity ladder (small tier -> configured cap -> unsliced).
-      Correctness never depends on the slot guess; most aggregates
-      resolve to a few thousand groups, so the first attempt uses a
-      small scatter domain + transfer and only genuinely wide keys pay
-      a retry.
+    - sort core (_cut_tiers): an OUTPUT size. The program runs once at
+      the input's capacity and returns each tier as a cut of its
+      states; the host picks the cut once the count is known.
+    - scatter core (_climb_tiers): the size of the hash TABLE, part of
+      the program's work. The first attempt probes a small table, and
+      more groups than a tier holds re-run the program a tier up. The
+      keyless single slot (gcap == 1, a reduce and not a scatter)
+      takes this path too and never climbs.
+    - n_groups == -1, either way: narrow-key hash collision between
+      DIFFERENT keys -> re-run the exact full-width lexsort kernel, a
+      sort-core program. Rare for a few thousand groups, certain for
+      several hundred thousand (n * n / 2**33 pairs expected in a
+      32-bit hash, and the Spark hash skips a NULL, so (NULL, k) and
+      (k, NULL) always collide).
 
     `build(force_lexsort, group_cap)` returns the python kernel to jit;
-    `fetch_n(outs, n_groups) -> (outs', n)` owns the host sync policy.
+    `fetch_n(outs, n_groups) -> (outs', n)` owns the host sync policy
+    (`n_groups` is the device scalar, or the host's int where a second
+    cut of a result whose count is known is fetched: _group_count).
 
-    `scatter_class` rides through to cached_kernel for the variants
-    that actually run the scatter core (the force_lexsort retry is
-    sort-dominated and always compiles under the default runtime);
+    `scatter_class` says the variant about to build runs the scatter
+    core (_scatter_core_hint); it picks the path here and rides
+    through to cached_kernel. A wrong guess costs runtime choice and
+    launches, never correctness: either path is right for either core.
     `span` names the obs span so phases.py can band group/join
-    dispatches separately."""
-    import os
-
-    force_lex = False
-    # BLAZE_AGG_TIER1 <= 0 disables the small first tier (one fewer
-    # compiled kernel variant per aggregate shape): the test suite sets
-    # it because jaxlib's CPU client segfaults under cumulative
-    # compile volume (docs/JAXLIB_SEGFAULT.md) and the ladder's extra
-    # variants pushed the largest exchange-tier query over the cliff
-    tier1 = int(os.environ.get("BLAZE_AGG_TIER1", "4096"))
-    if gcap is None:
-        tiers = [None]
-    elif tier1 <= 0 or tier1 >= gcap:
-        tiers = [gcap, None]
+    dispatches separately. A keyed aggregate leaves `agg_tier_retries`
+    in its task's metrics (POLL): programs launched again because the
+    count outgrew a tier, 0 on the sort core."""
+    tiers = _group_tiers(gcap)
+    keyless = gcap == 1  # both callers pass 1 for no keys, and only then
+    if not keyless:
+        _count_tier_retries(0)
+    if scatter_class or keyless:
+        host_outs, n = _climb_tiers(
+            base_key, build, args, fetch_n, tiers, scatter_class, span
+        )
     else:
-        tiers = [tier1, gcap, None]
-    ti = 0
-    while True:
-        gc = tiers[ti]
+        host_outs, n = _cut_tiers(
+            base_key, build, args, fetch_n, tiers, False, span
+        )
+    if n < 0:
+        host_outs, n = _cut_tiers(
+            base_key, build, args, fetch_n, tiers, True, span
+        )
+    return host_outs, n
+
+
+def _count_tier_retries(k: int) -> None:
+    task = current_task()
+    if task is not None:
+        task.metrics.add("agg_tier_retries", k)
+
+
+def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class,
+                 span):
+    """One program a tier, smallest first, until the count fits (the
+    collision sentinel -1 leaves at once, for the caller to see)."""
+    for gc in tiers:
         fn = cached_kernel(
-            base_key + (force_lex, gc),
-            lambda fl=force_lex, g=gc: build(fl, g),
-            scatter_class=scatter_class and not force_lex,
+            base_key + (False, gc),
+            lambda g=gc: build(False, g),
+            scatter_class=scatter_class,
             span=span,
         )
-        outs, n_groups = fn(*args)
-        host_outs, n = fetch_n(outs, n_groups)
-        if n < 0 and not force_lex:
-            force_lex = True
-            continue
-        if gc is not None and n > gc:
-            ti += 1
-            continue
-        return host_outs, n
+        host_outs, n = fetch_n(*fn(*args))
+        if gc is None or n <= gc:
+            return host_outs, n
+        _count_tier_retries(1)
+
+
+def _cut_tiers(base_key, build, args, fetch_n, tiers, force_lex, span):
+    """One program at the input's capacity, its states returned whole
+    and cut to each tier; fetch the first cut with the count, then, if
+    the count outgrew it, the smallest cut that holds it. On the sort
+    core group ids are dense in sorted order, dead rows park in the
+    last segment with neutral contributions and the boundary rows come
+    first-to-last, so the first t slots are what a kernel built at t
+    slots returns whenever n <= t."""
+    cuts = tuple(t for t in tiers if t is not None)
+    fn = cached_kernel(
+        base_key + (force_lex, cuts),
+        lambda: _with_cuts(build(force_lex, None), cuts),
+        span=span,
+    )
+    by_cut, n_groups = fn(*args)
+    host_outs, n = fetch_n(by_cut[0], n_groups)
+    fit = next((i for i, t in enumerate(cuts) if n <= t), len(cuts))
+    if fit:
+        host_outs, n = fetch_n(by_cut[fit], n)
+    return host_outs, n
+
+
+def _with_cuts(inner, cuts):
+    """`inner`'s states once for each cut (the first `t` slots of every
+    array) and then whole, beside the count."""
+
+    # named like every cached_kernel program: a device trace finds
+    # them as `jit_kernel` (perfbench/trace_patterns.json)
+    def kernel(*args):
+        outs, n_groups = inner(*args)
+        return [
+            jax.tree_util.tree_map(lambda x, t=t: x[:t], outs)
+            for t in cuts
+        ] + [outs], n_groups
+
+    return kernel
 
 
 class _SegOps:
@@ -678,7 +768,7 @@ class HashAggregateExec(PhysicalOp):
             # keyless: exactly one group, no collision/overflow retry -
             # skip the blocking scalar sync (a device round trip each)
             (lambda o, ng: (o, 1)) if not self.keys
-            else (lambda o, ng: (o, host_int(ng))),
+            else (lambda o, ng: (o, _group_count(ng))),
             gcap,
             scatter_class=self._scatter_core_hint(
                 aug.schema, key_exprs_l

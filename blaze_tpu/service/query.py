@@ -392,6 +392,11 @@ class Query:
             # batches a keyless aggregate merged into its device carry
             # with no read-back (ops/fused.py)
             out["agg_carry_batches"] = m["agg_carry_batches"]
+        if "agg_tier_retries" in m:
+            # grouping programs a keyed aggregate launched again
+            # because the group count outgrew a tier, 0 included
+            # (ops/hash_aggregate.py: run_grouped_kernel)
+            out["agg_tier_retries"] = m["agg_tier_retries"]
         if "sink_trim_batches" in m:
             # filtered batches the result sink read back whole and
             # trimmed on the host (ops/util.py: sink_arrow)
