@@ -10,6 +10,12 @@ multiply-shift.
 neither loads s64 tiles natively nor bitcasts them - the split is two
 cheap emulated i64 ops outside the kernel, amortized over the whole
 column).
+
+The two jitted entry points keep their names: a device trace lists their
+programs on the `XLA Modules` line as `jit_partition_ids_int32(...)` and
+`jit_partition_ids_int64(...)`, and the benchmark's
+`shuffle_hash_roofline` finds them by those names
+(tests/test_repart_key.py pins them).
 """
 
 from __future__ import annotations

@@ -58,6 +58,7 @@ from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.host_lower import lower_strings_host
 from blaze_tpu.ops.util import ensure_compacted, take_batch
+from blaze_tpu.runtime.dispatch import current_task
 from blaze_tpu.runtime import native
 from blaze_tpu.runtime.memory import get_pool
 
@@ -234,6 +235,16 @@ class PartitionBuffers:
         return [length for _, length in partition_ranges(index_path)]
 
 
+def _pallas_murmur3():
+    """The Pallas murmur3 module where its programs run: a real TPU
+    (Mosaic compiles for nothing else)."""
+    if jax.default_backend() != "tpu":
+        return None
+    from blaze_tpu.ops.kernels import murmur3_pallas
+
+    return murmur3_pallas
+
+
 def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
                         num_partitions: int) -> np.ndarray:
     """Spark-murmur3 pmod partition id per live row (batch must be
@@ -241,16 +252,17 @@ def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
     there; C++/numpy host path otherwise."""
     schema = cb.schema
     dtypes = [infer_dtype(e, schema) for e in key_exprs]
-    # pallas fast path: single non-nullable int key on real TPU hardware
+    # pallas fast path: one bound int key whose batch holds no NULL (a
+    # batch carries a validity buffer only where it does, so the data
+    # picks the path a batch at a time), where the program can run
     # (SURVEY 7: murmur3 partition hash as a Pallas kernel)
+    mp = _pallas_murmur3()
     if (
-        len(key_exprs) == 1
+        mp is not None
+        and len(key_exprs) == 1
         and isinstance(key_exprs[0], ir.BoundCol)
         and cb.columns[key_exprs[0].index].validity is None
-        and jax.default_backend() == "tpu"
     ):
-        from blaze_tpu.ops.kernels import murmur3_pallas as mp
-
         col = cb.columns[key_exprs[0].index]
         tid = dtypes[0].id.value
         if mp.supports(tid, cb.capacity):
@@ -260,6 +272,10 @@ def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
                 else mp.partition_ids_int64
             )
             pids = fn(col.values, num_partitions)
+            task = current_task()
+            if task is not None:
+                # POLL's `shuffle_pallas_batches`
+                task.metrics.add("shuffle_pallas_batches", 1)
             return np.asarray(pids)[: cb.num_rows]
     if all(device_hash_supported(dt) for dt in dtypes):
         cols = []

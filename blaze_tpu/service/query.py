@@ -388,6 +388,10 @@ class Query:
         if "shuffle_segments_written" in m:
             # parts a shuffle write encoded (ops/shuffle_writer.py)
             out["shuffle_segments"] = m["shuffle_segments_written"]
+        if "shuffle_pallas_batches" in m:
+            # batches whose partition ids the Pallas murmur3 program
+            # computed (ops/shuffle_writer.py: spark_partition_ids)
+            out["shuffle_pallas_batches"] = m["shuffle_pallas_batches"]
         if "agg_carry_batches" in m:
             # batches a keyless aggregate merged into its device carry
             # with no read-back (ops/fused.py)
