@@ -22,10 +22,6 @@ import os
 import tempfile
 from typing import Callable, Iterator, List, Optional, Sequence
 
-import numpy as np
-
-import jax.numpy as jnp
-
 from blaze_tpu.types import Schema
 from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.exprs import ir
@@ -33,9 +29,10 @@ from blaze_tpu.io.ipc import partition_ranges, read_file_segment
 from blaze_tpu.ops.base import ExecContext
 from blaze_tpu.ops.shuffle_writer import (
     PartitionBuffers,
+    sort_by_partition,
     spark_partition_ids,
 )
-from blaze_tpu.ops.util import ensure_compacted, take_batch
+from blaze_tpu.ops.util import ensure_compacted
 
 
 class BucketedInput:
@@ -72,11 +69,12 @@ def subdivide_pid_fn(key_exprs: Sequence[ir.Expr], parent_modulus: int,
     this so each level allocates `fanout` buckets, not parent * fanout
     (of which all but `fanout` would stay empty)."""
 
-    def pid(cb: ColumnBatch) -> np.ndarray:
+    def pid(cb: ColumnBatch):
+        # a device array or numpy, as `spark_partition_ids` hands it
         wide = spark_partition_ids(
             cb, list(key_exprs), parent_modulus * fanout
         )
-        return (wide // parent_modulus).astype(np.int32)
+        return wide // parent_modulus
 
     return pid
 
@@ -111,11 +109,8 @@ def bucket_stream(
             pid_fn(cb) if pid_fn is not None
             else spark_partition_ids(cb, list(key_exprs), n_buckets)
         )
-        pid_full = jnp.full(cb.capacity, n_buckets, dtype=jnp.int32)
-        pid_full = pid_full.at[: len(pids)].set(jnp.asarray(pids))
-        order = jnp.argsort(pid_full, stable=True)
-        rb_sorted = take_batch(cb, order, cb.num_rows).to_arrow()
-        bufs.stage(rb_sorted, np.bincount(pids, minlength=n_buckets))
+        cb_sorted, counts = sort_by_partition(cb, pids, n_buckets)
+        bufs.stage(cb_sorted.to_arrow(), counts)
 
     for cb in head:
         feed(cb)

@@ -21,16 +21,21 @@ the stable sort's order within a batch, across freezes and spills; the
 file format (io/ipc.py) does not change.
 
 TPU-first layout (SURVEY 7 step 5): partition ids are computed on-device
-(bit-exact Spark murmur3 over the key columns) and the row scatter is ONE
-stable device argsort by partition id - the counting-sort scatter of the
-reference (rs:349-371) becomes an XLA sort - followed by a single D2H
-transfer of the already-partition-contiguous batch. String/f64 keys hash
-through the C++ host runtime instead (TPU has no string compute; its f64
-is not bit-exact - exprs/hashing.device_hash_supported).
+(bit-exact Spark murmur3 over the key columns, one program) and stay
+there: a second program (`sort_by_partition`) does the row scatter as ONE
+stable device sort by partition id - the counting-sort scatter of the
+reference (rs:349-371) becomes an XLA sort - gathers every buffer and
+counts the rows of each partition, followed by a single D2H transfer of
+the already-partition-contiguous batch. Nothing waits for the device
+between the first launch and that transfer. String/f64 keys hash through
+the C++ host runtime instead (TPU has no string compute; its f64 is not
+bit-exact - exprs/hashing.device_hash_supported) and enter the sort as a
+host array.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
@@ -57,8 +62,9 @@ from blaze_tpu.io.ipc import encode_ipc_segment
 from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.host_lower import lower_strings_host
+from blaze_tpu.ops.project import _unflatten_cvs
 from blaze_tpu.ops.util import ensure_compacted, take_batch
-from blaze_tpu.runtime.dispatch import current_task
+from blaze_tpu.runtime.dispatch import cached_kernel, current_task
 from blaze_tpu.runtime import native
 from blaze_tpu.runtime.memory import get_pool
 
@@ -245,11 +251,28 @@ def _pallas_murmur3():
     return murmur3_pallas
 
 
+def _build_partition_ids(schema: Schema, layout: Tuple, exprs, dtypes,
+                         num_partitions: int):
+    """Key expressions, the murmur3 chain with validity and pmod traced
+    together. Its own name, so a device trace tells its program from the
+    fused `kernel`s and from the Pallas programs."""
+    cap = layout[0]
+
+    def shuffle_partition_ids(bufs):
+        ev = DeviceEvaluator(schema, _unflatten_cvs(layout, bufs), cap)
+        cols = [ev.evaluate(e) + (dt,) for e, dt in zip(exprs, dtypes)]
+        return pmod(hash_columns_device(cols, cap), num_partitions)
+
+    return shuffle_partition_ids
+
+
 def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
-                        num_partitions: int) -> np.ndarray:
-    """Spark-murmur3 pmod partition id per live row (batch must be
-    compacted). Device fast path when all key dtypes hash bit-exactly
-    there; C++/numpy host path otherwise."""
+                        num_partitions: int):
+    """Spark-murmur3 pmod partition id per row (batch must be
+    compacted). Where all key dtypes hash bit-exactly on the device: a
+    device array of the batch's capacity, left there (whatever lies at
+    and past `num_rows` is padding's hash; `sort_by_partition` masks
+    it). Otherwise the C++/numpy host path: numpy, `num_rows` long."""
     schema = cb.schema
     dtypes = [infer_dtype(e, schema) for e in key_exprs]
     # pallas fast path: one bound int key whose batch holds no NULL (a
@@ -276,19 +299,16 @@ def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
             if task is not None:
                 # POLL's `shuffle_pallas_batches`
                 task.metrics.add("shuffle_pallas_batches", 1)
-            return np.asarray(pids)[: cb.num_rows]
+            return pids
     if all(device_hash_supported(dt) for dt in dtypes):
-        cols = []
-        ev = DeviceEvaluator(
-            schema, [(c.values, c.validity) for c in cb.columns],
-            cb.capacity,
+        exprs, layout = tuple(key_exprs), cb.layout()
+        fn = cached_kernel(
+            ("shuffle_ids", exprs, schema, layout, num_partitions),
+            lambda: _build_partition_ids(
+                schema, layout, exprs, dtypes, num_partitions
+            ),
         )
-        for e, dt in zip(key_exprs, dtypes):
-            v, m = ev.evaluate(e)
-            cols.append((v, m, dt))
-        h = hash_columns_device(cols, cb.capacity)
-        pids = pmod(h, num_partitions)
-        return np.asarray(pids)[: cb.num_rows]
+        return fn(cb.device_buffers())
     # host path: exact Spark chain incl. utf8 bytes via the C++ runtime
     n = cb.num_rows
     h = np.full(n, 42, dtype=np.uint32)
@@ -316,6 +336,67 @@ def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
             validity = np.asarray(m)[:n] if m is not None else None
             h = _chain_fixed(np.asarray(v)[:n], validity, dt, h)
     return native.pmod_np(h, num_partitions)
+
+
+def _build_sort_by_partition(num_partitions: int):
+    def shuffle_sort_gather(pids, num_rows, bufs):
+        cap = pids.shape[0]
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        # dead rows sort behind the last partition
+        keys = jnp.where(
+            iota < num_rows, pids.astype(jnp.int32),
+            jnp.int32(num_partitions),
+        )
+        keys, order = jax.lax.sort_key_val(keys, iota, is_stable=True)
+        bounds = jnp.searchsorted(
+            keys, jnp.arange(num_partitions + 1, dtype=jnp.int32)
+        )
+        return (
+            [jnp.take(b, order, axis=0) for b in bufs],
+            (bounds[1:] - bounds[:-1]).astype(jnp.int32),
+        )
+
+    return shuffle_sort_gather
+
+
+@functools.lru_cache(maxsize=64)
+def _device_rows(num_rows: int) -> jax.Array:
+    """A row count as the device scalar the sort takes, kept: nearly
+    every batch of a task is full, and a fresh scalar is one more
+    transfer a batch (0.16 ms of the launching thread on a v5e)."""
+    return jax.device_put(np.int32(num_rows))
+
+
+def sort_by_partition(cb: ColumnBatch, pids, num_partitions: int
+                      ) -> Tuple[ColumnBatch, jax.Array]:
+    """`cb`'s live rows in partition order (stable within a partition)
+    and the rows of each partition, by ONE device program: the scatter is
+    a stable sort by partition id, then the gather of every buffer and
+    the counts. `pids` is what `spark_partition_ids` returns, a device
+    array or numpy; `num_rows` is traced, so a short last batch compiles
+    nothing. Launch only: neither result is waited for here (the counts
+    follow the batch's own read-back to the host)."""
+    if isinstance(pids, jax.Array):
+        task = current_task()
+        if task is not None:
+            # POLL's `shuffle_device_ids_batches`: no read-back between
+            # the hash and the sort
+            task.metrics.add("shuffle_device_ids_batches", 1)
+    else:
+        # the host path's are `num_rows` long
+        pids = np.pad(
+            np.asarray(pids, dtype=np.int32), (0, cb.capacity - len(pids)),
+            constant_values=num_partitions,
+        )
+    fn = cached_kernel(
+        ("shuffle_sort_gather", num_partitions),
+        lambda: _build_sort_by_partition(num_partitions),
+    )
+    taken, counts = fn(pids, _device_rows(cb.num_rows), cb.device_buffers())
+    counts.copy_to_host_async()
+    return ColumnBatch.from_device_buffers(
+        cb.schema, cb.layout(), taken, cb.num_rows, cb.dictionaries()
+    ), counts
 
 
 def _chain_fixed(values, validity, dt, h):
@@ -527,6 +608,7 @@ class ShuffleWriterExec(PhysicalOp):
                          np.arange(cb.num_rows, cb.capacity)])),
                     cb.num_rows,
                 ).to_arrow()
+                counts = np.bincount(pids, minlength=self.num_partitions)
             elif self.mode == "range":
                 # host path: key ordering incl. strings/NULLs needs real
                 # values (ordering on dictionary codes would be wrong);
@@ -542,29 +624,22 @@ class ShuffleWriterExec(PhysicalOp):
                 )
                 order = np.argsort(pids, kind="stable")
                 rb_sorted = rb.take(order)
+                counts = np.bincount(pids, minlength=self.num_partitions)
             else:
-                # obs seam: hash, sort by partition and gather, up to
-                # the read-back (`d2h`, inside to_arrow)
+                # obs seam: the ids' program and the sort-and-gather
+                # program, launched and not waited for, up to the
+                # read-back (`d2h`, inside to_arrow)
                 with (obs_trace.span("shuffle_partition")
                       if obs_trace.ACTIVE else obs_trace.NULL):
-                    pids = spark_partition_ids(
-                        aug, exprs, self.num_partitions
+                    cb_sorted, counts = sort_by_partition(
+                        cb,
+                        spark_partition_ids(
+                            aug, exprs, self.num_partitions
+                        ),
+                        self.num_partitions,
                     )
-                    # scatter = one stable device argsort by
-                    # partition id
-                    pid_full = jnp.full(
-                        cb.capacity, self.num_partitions,
-                        dtype=jnp.int32,
-                    )
-                    pid_full = pid_full.at[: len(pids)].set(
-                        jnp.asarray(pids)
-                    )
-                    order_dev = jnp.argsort(pid_full, stable=True)
-                    cb_sorted = take_batch(cb, order_dev, cb.num_rows)
                 rb_sorted = cb_sorted.to_arrow()
-            bufs.stage(rb_sorted, np.bincount(
-                pids, minlength=self.num_partitions
-            ))
+            bufs.stage(rb_sorted, counts)
             ctx.metrics.add("shuffle_rows_written", cb.num_rows)
         lengths = bufs.finalize(self.data_file, self.index_file)
         ctx.metrics.add("shuffle_segments_written", bufs.segments)

@@ -392,6 +392,13 @@ class Query:
             # batches whose partition ids the Pallas murmur3 program
             # computed (ops/shuffle_writer.py: spark_partition_ids)
             out["shuffle_pallas_batches"] = m["shuffle_pallas_batches"]
+        if "shuffle_device_ids_batches" in m:
+            # batches whose partition ids went from the hash to the
+            # sort on the device, with no read-back between
+            # (ops/shuffle_writer.py: sort_by_partition)
+            out["shuffle_device_ids_batches"] = (
+                m["shuffle_device_ids_batches"]
+            )
         if "agg_carry_batches" in m:
             # batches a keyless aggregate merged into its device carry
             # with no read-back (ops/fused.py)

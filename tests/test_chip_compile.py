@@ -224,6 +224,68 @@ def test_keyless_carry_kernel(one_chip, sales_parquet, agg, with_carry):
         .compile()
 
 
+def _shuffle_batch(table):
+    """One 16,384-row batch shaped as the benchmark's shuffle cells
+    scan it (`store_sales`: 23 columns, nullable `int` keys, decimal(7,2)
+    money, a `bigint` ticket; `inventory`: four `int`s, one nullable)."""
+    import decimal
+
+    import pyarrow as pa
+
+    from blaze_tpu import ColumnBatch
+
+    rng = np.random.default_rng(32)
+    n = 16384
+
+    def ints(nullable):
+        return pa.array(rng.integers(1, 300_000, n).astype(np.int32),
+                        mask=rng.random(n) < 0.045 if nullable else None)
+
+    if table == "inventory":
+        cols = {"inv_date_sk": ints(False), "inv_item_sk": ints(False),
+                "inv_warehouse_sk": ints(False),
+                "inv_quantity_on_hand": ints(True)}
+        key = "inv_item_sk"
+    else:
+        money = pa.array(
+            [decimal.Decimal(int(c)).scaleb(-2)
+             for c in rng.integers(0, 3_000_000, n)],
+            pa.decimal128(7, 2), mask=rng.random(n) < 0.045)
+        cols = {"ss_item_sk": ints(False),
+                "ss_ticket_number": pa.array(np.arange(n, dtype=np.int64))}
+        cols.update({f"ss_key_{i}": ints(True) for i in range(9)})
+        cols.update({f"ss_money_{i}": money for i in range(12)})
+        key = "ss_key_0"
+    return ColumnBatch.from_arrow(pa.RecordBatch.from_pydict(cols)), key
+
+
+@pytest.mark.parametrize("table", ["inventory", "store_sales"])
+def test_shuffle_partition_programs(one_chip, table):
+    """The two jitted programs of the stage `shuffle_partition`
+    (ops/shuffle_writer.py) at the serve batch's capacity: the murmur3
+    chain of a nullable key, and the sort by partition with the gather
+    of every buffer and the 200 counts."""
+    from blaze_tpu.exprs import ir
+    from blaze_tpu.ops.shuffle_writer import (
+        _build_partition_ids, _build_sort_by_partition,
+    )
+
+    cb, key = _shuffle_batch(table)
+    assert cb.capacity == 16384
+    bufs = [jax.ShapeDtypeStruct(b.shape, b.dtype, sharding=one_chip)
+            for b in cb.device_buffers()]
+    i = cb.schema.index_of(key)
+    dt = cb.schema.fields[i].dtype
+    ids = _build_partition_ids(cb.schema, cb.layout(),
+                               (ir.BoundCol(i, dt),), [dt], N_PARTS)
+    jax.jit(ids).lower(bufs).compile()
+    pids = jax.ShapeDtypeStruct((16384,), jnp.int32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(_build_sort_by_partition(N_PARTS)).lower(
+        pids, rows, bufs).compile()
+    assert "sort" in compiled.as_text()
+
+
 # ---- kernels with no path from blaze_tpu/: the refusal, on record ----
 # Interpret mode passes all of these (tests/test_pallas_kernels.py);
 # the v5e compiler does not. The BLAZE_SEGREDUCE selector that reached

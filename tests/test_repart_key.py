@@ -11,7 +11,12 @@ On a TPU such a key's batches take the Pallas murmur3 program
 the tests steer that choice themselves: `_pallas_murmur3` is made to
 answer with the same programs in interpret mode. The data picks the path a
 batch at a time, so one case gives a nullable key a batch without a NULL
-and sees both paths answer one task, row for row as the reference."""
+and sees both paths answer one task, row for row as the reference.
+
+On either device path the ids go from their program to the sort-and-gather
+program without a read-back, which POLL counts a batch at a time
+(`shuffle_device_ids_batches`); a string key, hashed on the host, has no
+such count."""
 
 import copy
 import functools
@@ -120,6 +125,7 @@ def test_never_null_key_takes_the_pallas_program(
     assert got["rows"] == frame["rows"] and got["partitions"] == 200
     # every batch's ids came from the program, inside the one stage
     assert poll["shuffle_pallas_batches"] == batches
+    assert poll["shuffle_device_ids_batches"] == batches
     assert poll["stages"]["shuffle_partition"]["n"] == batches
     assert poll["shuffle_segments"] == 200
 
@@ -152,7 +158,43 @@ def test_nullable_key_with_a_batch_that_holds_no_null(
         assert poll["shuffle_pallas_batches"] == 1
     else:
         assert "shuffle_pallas_batches" not in poll
+    # the Pallas program's ids and the jitted chain's alike
+    assert poll["shuffle_device_ids_batches"] == batches
     assert poll["stages"]["shuffle_partition"]["n"] == batches
+
+
+def test_string_key_has_no_device_ids(client, tmp_path):
+    """A key the device cannot hash (`native`'s murmur3 over the utf8
+    bytes, on the host): the ids reach the sort as a host array, so POLL
+    carries neither count, and every key's rows still share a partition."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from perfbench import segipc
+    from perfbench.templates import repart_key
+
+    rows = 20000
+    path = str(tmp_path / "split.parquet")
+    pq.write_table(pa.table({
+        "k": [f"item-{i % 997}" for i in range(rows)],
+        "v": pa.array(range(rows), pa.int32())}), path)
+    out = {"data": str(tmp_path / "t.data"),
+           "index": str(tmp_path / "t.index")}
+    st = client.submit(repart_key.build(path, params("k"), out))
+    assert not client.fetch(st["query_id"])
+    poll = client.poll(st["query_id"])
+    assert poll["state"] == "DONE"
+    assert "shuffle_device_ids_batches" not in poll
+    assert "shuffle_pallas_batches" not in poll
+    assert poll["stages"]["shuffle_partition"]["n"] == 2
+    parts = segipc.read_partitions(out["data"], out["index"])
+    assert len(parts) == 200
+    seen = {}
+    for p, t in enumerate(parts):
+        for k in (t.column("k").unique().to_pylist() if t else ()):
+            assert seen.setdefault(k, p) == p
+    assert sum(t.num_rows for t in parts if t) == rows
+    assert len(seen) == 997
 
 
 @pytest.mark.parametrize("seed", SEEDS)
