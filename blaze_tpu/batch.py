@@ -150,66 +150,9 @@ class ColumnBatch:
     def from_arrow(rb, capacity: Optional[int] = None) -> "ColumnBatch":
         """Build from a pyarrow RecordBatch (dictionary-encode strings,
         pad to a shape bucket, move to device)."""
-        import pyarrow as pa
-        import pyarrow.compute as pc
-
-        schema = from_arrow_schema(rb.schema)
         n = rb.num_rows
         cap = capacity or get_config().bucket_for(n)
-        # (vals, cap, tail_fill) triples: padding and transfer-packing
-        # fuse into one host copy (pack.put_packed_padded)
-        entries: List[Tuple[np.ndarray, int, int]] = []
-        col_meta: List[Tuple[DataType, bool, Optional[object]]] = []
-        for i, field in enumerate(schema):
-            arr = rb.column(i)
-            if isinstance(arr, pa.ChunkedArray):
-                arr = arr.combine_chunks()
-            dt = field.dtype
-            has_nulls = arr.null_count > 0
-            null_np = np.asarray(arr.is_null()) if has_nulls else None
-            dictionary = None
-            if dt.is_dictionary_encoded:
-                if not pa.types.is_dictionary(arr.type):
-                    arr = pc.dictionary_encode(arr)
-                dictionary = arr.dictionary
-                np_vals = arr.indices.fill_null(0).to_numpy(
-                    zero_copy_only=False)
-                np_vals = np.ascontiguousarray(np_vals, dtype=np.int32)
-            elif dt.id is TypeId.DECIMAL:
-                if dt.is_wide_decimal:
-                    np_vals = _decimal_limbs(arr)  # (n, 2) [lo, hi]
-                else:
-                    np_vals = _decimal_unscaled_i64(arr)
-            elif dt.id is TypeId.TIMESTAMP_US:
-                arr = arr.cast(pa.timestamp("us"))
-                np_vals = arr.to_numpy(zero_copy_only=False).astype(
-                    "datetime64[us]").view(np.int64)
-            elif dt.id is TypeId.DATE32:
-                np_vals = arr.to_numpy(zero_copy_only=False).astype(
-                    "datetime64[D]").view(np.int64).astype(np.int32)
-            elif dt.id is TypeId.NULL:
-                np_vals = np.zeros(n, dtype=np.int8)
-            else:
-                if has_nulls:
-                    # pyarrow surfaces nullable ints as float64 with NaN;
-                    # fill first (nulls are tracked in validity anyway).
-                    arr = arr.fill_null(
-                        False if dt.id is TypeId.BOOL else 0)
-                np_vals = arr.to_numpy(zero_copy_only=False)
-            phys = dt.physical_dtype()
-            if np_vals.dtype != phys:
-                np_vals = np_vals.astype(phys)
-            entries.append((np_vals, cap, 0))
-            has_validity = has_nulls or dt.id is TypeId.NULL
-            if has_validity:
-                if dt.id is TypeId.NULL:
-                    # all-invalid including the padding tail
-                    entries.append((np.zeros(0, dtype=bool), cap, 0))
-                else:
-                    entries.append(
-                        (~null_np, cap, 1)  # padding rows stay "valid"
-                    )
-            col_meta.append((dt, has_validity, dictionary, True))
+        schema, entries, col_meta = _host_entries(rb, cap)
         from blaze_tpu.runtime.pack import put_packed_padded_lazy
 
         buf, metas, pairs = put_packed_padded_lazy(entries)
@@ -396,6 +339,73 @@ class ColumnBatch:
         return ColumnBatch.from_arrow(rb)
 
 
+def _host_entries(rb, cap: int):
+    """A RecordBatch's columns as the host arrays a packed transfer
+    takes: (schema, entries, col_meta) with an entry `(vals, cap,
+    tail_fill)` for each column's values and, where it holds a NULL,
+    its validity; `col_meta` is PackedColumnBatch's."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    schema = from_arrow_schema(rb.schema)
+    n = rb.num_rows
+    # (vals, cap, tail_fill) triples: padding and transfer-packing
+    # fuse into one host copy (pack.put_packed_padded)
+    entries: List[Tuple[np.ndarray, int, int]] = []
+    col_meta: List[Tuple[DataType, bool, Optional[object]]] = []
+    for i, field in enumerate(schema):
+        arr = rb.column(i)
+        if isinstance(arr, pa.ChunkedArray):
+            arr = arr.combine_chunks()
+        dt = field.dtype
+        has_nulls = arr.null_count > 0
+        null_np = np.asarray(arr.is_null()) if has_nulls else None
+        dictionary = None
+        if dt.is_dictionary_encoded:
+            if not pa.types.is_dictionary(arr.type):
+                arr = pc.dictionary_encode(arr)
+            dictionary = arr.dictionary
+            np_vals = arr.indices.fill_null(0).to_numpy(
+                zero_copy_only=False)
+            np_vals = np.ascontiguousarray(np_vals, dtype=np.int32)
+        elif dt.id is TypeId.DECIMAL:
+            if dt.is_wide_decimal:
+                np_vals = _decimal_limbs(arr)  # (n, 2) [lo, hi]
+            else:
+                np_vals = _decimal_unscaled_i64(arr)
+        elif dt.id is TypeId.TIMESTAMP_US:
+            arr = arr.cast(pa.timestamp("us"))
+            np_vals = arr.to_numpy(zero_copy_only=False).astype(
+                "datetime64[us]").view(np.int64)
+        elif dt.id is TypeId.DATE32:
+            np_vals = arr.to_numpy(zero_copy_only=False).astype(
+                "datetime64[D]").view(np.int64).astype(np.int32)
+        elif dt.id is TypeId.NULL:
+            np_vals = np.zeros(n, dtype=np.int8)
+        else:
+            if has_nulls:
+                # pyarrow surfaces nullable ints as float64 with NaN;
+                # fill first (nulls are tracked in validity anyway).
+                arr = arr.fill_null(
+                    False if dt.id is TypeId.BOOL else 0)
+            np_vals = arr.to_numpy(zero_copy_only=False)
+        phys = dt.physical_dtype()
+        if np_vals.dtype != phys:
+            np_vals = np_vals.astype(phys)
+        entries.append((np_vals, cap, 0))
+        has_validity = has_nulls or dt.id is TypeId.NULL
+        if has_validity:
+            if dt.id is TypeId.NULL:
+                # all-invalid including the padding tail
+                entries.append((np.zeros(0, dtype=bool), cap, 0))
+            else:
+                entries.append(
+                    (~null_np, cap, 1)  # padding rows stay "valid"
+                )
+        col_meta.append((dt, has_validity, dictionary, True))
+    return schema, entries, col_meta
+
+
 class PackedColumnBatch(ColumnBatch):
     """A ColumnBatch whose device columns still live inside the single
     packed H2D wire buffer (runtime/pack.put_packed_padded_lazy).
@@ -523,6 +533,47 @@ class PackedColumnBatch(ColumnBatch):
             return bufs
 
         return unflatten
+
+
+class DealtBatch:
+    """One decoded batch cut into runs of rows, a run on each device of
+    a mesh, still packed (runtime/pack.put_packed_dealt): what a scan
+    yields to the mesh group-by in place of a ColumnBatch. `buf` is
+    [n_dev, bytes] sharded on its first axis; `runs` the live rows of
+    each run, `run_cap` its capacity; `col_meta` is PackedColumnBatch's.
+    No operator but the mesh group-by's staging reads one."""
+
+    __slots__ = ("schema", "num_rows", "buf", "metas", "pairs",
+                 "run_cap", "runs", "col_meta")
+
+    @staticmethod
+    def from_arrow(rb, schema: Schema, present: Optional[Sequence[int]],
+                   capacity: int, sharding) -> "DealtBatch":
+        """`from_arrow_pruned` for a dealt batch: `rb` holds the columns
+        of `schema` at `present` (all of them for None); every entry is
+        padded to `capacity`, a whole batch's, whatever rows `rb` has,
+        so that a split's batches share one layout."""
+        from blaze_tpu.runtime.pack import put_packed_dealt
+
+        _, entries, sub_meta = _host_entries(rb, capacity)
+        self = DealtBatch()
+        self.schema = schema
+        self.num_rows = rb.num_rows
+        (self.buf, self.metas, self.pairs, self.run_cap,
+         self.runs) = put_packed_dealt(entries, rb.num_rows, sharding)
+        it = iter(sub_meta)
+        self.col_meta = [
+            next(it) if present is None or i in present
+            else (f.dtype, False, None, False)
+            for i, f in enumerate(schema)
+        ]
+        return self
+
+    def layout(self) -> Tuple:
+        """What a program that unpacks this batch is keyed by."""
+        return (self.metas, self.pairs, self.run_cap, tuple(
+            (has_validity, packed)
+            for _, has_validity, _, packed in self.col_meta))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -86,6 +86,7 @@ STAGE_SPANS = frozenset({
     "decode_batch", "h2d", "compact", "d2h", "agg_fetch",
     "shuffle_partition", "shuffle_encode", "shuffle_finalize",
     "frame_encode", "frame_send",
+    "mesh_stage_in", "mesh_sync", "mesh_gather",
     "cache_probe", "service_admit",
 })
 

@@ -106,8 +106,28 @@ class ParquetScanExec(PhysicalOp):
         proj = ",".join(self.projection) if self.projection else "*"
         return f"{groups};proj={proj};prune={self.pruning_predicate!r}"
 
-    def execute(self, partition: int, ctx: ExecContext
+    def batch_bound(self, partition: int, batch_size: int) -> int:
+        """The most batches `execute` can yield for this partition, from
+        the files' footers alone: what a consumer that sizes a buffer
+        before the scan runs (the mesh group-by) goes by. The filters
+        pushed down only ever lower the count."""
+        import pyarrow.parquet as pq
+
+        from blaze_tpu.io.object_store import store_for
+
+        total = 0
+        for fr in self.file_groups[partition]:
+            pf = pq.ParquetFile(store_for(fr.path).open_input(fr.path))
+            for g in self._select_row_groups(pf, fr):
+                total += -(-pf.metadata.row_group(g).num_rows
+                           // batch_size)
+        return total
+
+    def execute(self, partition: int, ctx: ExecContext, deal=None
                 ) -> Iterator[ColumnBatch]:
+        """`deal`, a sharding over a mesh's devices, has the prefetch
+        thread cut every batch into a run of rows a device and yield
+        `DealtBatch`es (batch.py) for the mesh group-by's staging."""
         import pyarrow.parquet as pq
 
         from blaze_tpu.io.object_store import store_for
@@ -201,7 +221,8 @@ class ParquetScanExec(PhysicalOp):
                             if rb is None:
                                 break
                             cb = self._decode_batch(
-                                rb, ctx, filters, keep_names, present)
+                                rb, ctx, filters, keep_names, present,
+                                deal)
                         if cb is not None:
                             yield cb
 
@@ -210,7 +231,7 @@ class ParquetScanExec(PhysicalOp):
         yield from prefetch(decode(), depth=2)
 
     def _decode_batch(self, rb, ctx: ExecContext, filters, keep_names,
-                      present) -> Optional[ColumnBatch]:
+                      present, deal=None) -> Optional[ColumnBatch]:
         """One decoded RecordBatch to a packed device batch (None when
         the pushed-down filters leave no row)."""
         ctx.metrics.add("input_rows", rb.num_rows)
@@ -223,14 +244,21 @@ class ParquetScanExec(PhysicalOp):
             )
         if rb.num_rows == 0:
             return None
+        if present is not None:
+            import pyarrow as pa
+
+            rb = pa.record_batch(
+                [rb.column(c) for c in keep_names], names=keep_names,
+            )
+        if deal is not None:
+            from blaze_tpu.batch import DealtBatch
+
+            return DealtBatch.from_arrow(
+                rb, self._schema, present,
+                ctx.config.bucket_for(ctx.config.batch_size), deal)
         if present is None:
             return ColumnBatch.from_arrow(rb)
-        import pyarrow as pa
-
-        sub = pa.record_batch(
-            [rb.column(c) for c in keep_names], names=keep_names,
-        )
-        return ColumnBatch.from_arrow_pruned(sub, self._schema, present)
+        return ColumnBatch.from_arrow_pruned(rb, self._schema, present)
 
     # ------------------------------------------------------------------
     def _select_row_groups(self, pf, fr: FileRange,

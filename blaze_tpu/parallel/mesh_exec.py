@@ -103,6 +103,74 @@ def to_mesh(global_np: np.ndarray, mesh, axis: str = "data"):
     return jax.device_put(global_np, sharding)
 
 
+def _partitions(child: PhysicalOp, ctx: ExecContext,
+                n_dev: int) -> Iterator[ColumnBatch]:
+    """Every child partition in turn as one compacted batch. Raises
+    NotImplementedError for what no mesh operator stages (string
+    columns, more partitions than devices)."""
+    if child.partition_count > n_dev:
+        raise NotImplementedError(
+            "more partitions than devices; use the exchange tier"
+        )
+    for f in child.schema.fields:
+        if f.dtype.is_string_like or f.dtype.is_dictionary_encoded:
+            raise NotImplementedError(
+                "string columns use the file-shuffle tier"
+            )
+    for p in range(child.partition_count):
+        yield ensure_compacted(concat_batches(
+            list(child.execute(p, ctx)), schema=child.schema
+        ))
+
+
+def stack_nullable(child: PhysicalOp, ctx: ExecContext, mesh,
+                   axis: str = "data"):
+    """`stack_partitions` for the group-by, whose program takes
+    validity: every column comes with a [n_dev, cap] validity stack
+    (all true where the child's batches had none), and a child of ONE
+    partition is dealt over the devices, a run of its rows each,
+    instead of landing whole on device 0.
+
+    Returns (stacked_cols, stacked_validity, num_rows_arr, the rows a
+    device as a host array, staged_bytes)."""
+    from blaze_tpu.runtime.pack import deal_cuts
+
+    n_dev = int(mesh.shape[axis])
+    per_part = list(_partitions(child, ctx, n_dev))
+    if len(per_part) == 1 and n_dev > 1:
+        cuts = [(0, lo, hi)
+                for lo, hi in deal_cuts(per_part[0].num_rows, n_dev)]
+        cap = ctx.config.bucket_for(max(cuts[0][2], 1))
+    else:
+        cuts = [(p, 0, b.num_rows) for p, b in enumerate(per_part)]
+        cap = max(max((b.capacity for b in per_part), default=1), 1)
+    stacked, valids, nbytes = [], [], 0
+    for ci, f in enumerate(child.schema.fields):
+        host = np.zeros((n_dev, cap), dtype=f.dtype.physical_dtype())
+        ok = None  # a column that holds no NULL stages no validity
+        # one read-back a partition's column, whatever its cuts
+        vals = [np.asarray(b.columns[ci].values) for b in per_part]
+        oks = [None if b.columns[ci].validity is None
+               else np.asarray(b.columns[ci].validity) for b in per_part]
+        for d, (p, lo, hi) in enumerate(cuts):
+            host[d, :hi - lo] = vals[p][lo:hi]
+            if oks[p] is not None:
+                if ok is None:
+                    ok = np.ones((n_dev, cap), dtype=np.bool_)
+                ok[d, :hi - lo] = oks[p][lo:hi]
+        nbytes += host.nbytes + (0 if ok is None else ok.nbytes)
+        stacked.append(to_mesh(host, mesh, axis))
+        valids.append(None if ok is None else to_mesh(ok, mesh, axis))
+    rows = np.zeros(n_dev, dtype=np.int32)
+    rows[:len(cuts)] = [hi - lo for _, lo, hi in cuts]
+    # staging accounting: one logical H2D per staged stack (+1 for the
+    # row counts) - the mesh analog of the packed-batch H2D
+    dispatch.record(
+        "h2d_batches",
+        len(stacked) + sum(v is not None for v in valids) + 1)
+    return stacked, valids, to_mesh(rows, mesh, axis), rows, nbytes
+
+
 def stack_partitions(child: PhysicalOp, ctx: ExecContext, mesh,
                      axis: str = "data"):
     """Materialize every child partition and stage the columns as
@@ -118,21 +186,8 @@ def stack_partitions(child: PhysicalOp, ctx: ExecContext, mesh,
     validity masks) - callers treat that as ineligibility and fall
     back."""
     n_dev = int(mesh.shape[axis])
-    if child.partition_count > n_dev:
-        raise NotImplementedError(
-            "more partitions than devices; use the exchange tier"
-        )
-    for f in child.schema.fields:
-        if f.dtype.is_string_like or f.dtype.is_dictionary_encoded:
-            raise NotImplementedError(
-                "string columns use the file-shuffle tier"
-            )
     per_part = []
-    for p in range(child.partition_count):
-        b = concat_batches(
-            list(child.execute(p, ctx)), schema=child.schema
-        )
-        b = ensure_compacted(b)
+    for b in _partitions(child, ctx, n_dev):
         # fail fast BEFORE materializing the remaining partitions: a
         # nullable input detected here falls back to the original plan,
         # and everything collected so far is sunk cost

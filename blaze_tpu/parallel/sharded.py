@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -45,21 +45,140 @@ class DistAgg:
     expr: Optional[ir.Expr]  # bound against input schema; None for COUNT_*
 
 
+# a key's sort flag, two bits a key in one `flags` operand: 0 a value,
+# 1 NULL, 2 NaN (a float key's NaNs are one group); a dead slot's flags
+# are _DEAD, which sorts last
+_NULL, _NAN, _DEAD = 1, 2, 1 << 30
+
+_BLOCK = 512
+
+
+def _running(x: jax.Array, scan) -> jax.Array:
+    """`scan` (lax.cumsum, lax.cummax) along a vector, as runs of
+    _BLOCK with the runs' totals scanned in turn: the chip's compiler
+    takes a second over this where it takes minutes over one scan of
+    400,000 `i64` (177 s for `jnp.cumsum`, 54 s and 33 MB of code for
+    an `associative_scan`)."""
+    n = x.shape[0]
+    pad = (-n) % _BLOCK
+    m = jnp.concatenate([x, jnp.zeros(pad, x.dtype)]).reshape(-1, _BLOCK)
+    inner = scan(m, axis=1)
+    ends = scan(inner[:, -1], axis=0)
+    if scan is lax.cumsum:
+        out = inner + (ends - inner[:, -1])[:, None]
+    else:  # cummax of values that are never negative
+        out = jnp.maximum(inner, jnp.concatenate(
+            [jnp.zeros(1, x.dtype), ends[:-1]])[:, None])
+    return out.reshape(-1)[:n]
+
+
+def _reduce_sorted(flags, kvals, states, kinds, n: int):
+    """One segmented reduce of `n` slots: sort by (flags, key values),
+    so that a group's slots lie together and dead slots last, and
+    combine each group's states by their kind. Returns (flags, kvals,
+    states, rep) in sorted order; `rep` marks the last slot of each
+    group, which holds the group's reduced states.
+
+    The sort carries the keys and a row number only, the states follow
+    by a gather: each operand more multiplies the time XLA takes to
+    compile a TPU sort (8 s for two operands of 262,144 rows, 36 s for
+    four, 123 s for six). An integer sum is a running sum less what it
+    was where the group started: no scatter, which on a TPU costs a
+    hundred times what the sort of the same rows does (PERF.md, PR
+    30). Float sums (a difference of running sums would round) and
+    min/max go through `jax.ops.segment_*`."""
+    rows = jnp.arange(n, dtype=jnp.int32)
+    srt = lax.sort((flags,) + tuple(kvals) + (rows,),
+                   num_keys=1 + len(kvals))
+    flags, kvals, order = srt[0], list(srt[1:-1]), srt[-1]
+    live = flags != _DEAD
+    differs = jnp.zeros(n, dtype=jnp.bool_)
+    for k in [flags] + kvals:
+        differs = differs | jnp.concatenate(
+            [jnp.ones(1, jnp.bool_), k[1:] != k[:-1]])
+    # a slot that differs from the one before it starts a group; the
+    # slot before that one is the last of its group
+    rep = live & jnp.concatenate([differs[1:], jnp.ones(1, jnp.bool_)])
+    start = _running(jnp.where(differs, rows, 0), lax.cummax)
+    gid = None
+    out = []
+    for s, kind in zip(states, kinds):
+        s = jnp.take(s, order)
+        if kind == "sum" and jnp.issubdtype(s.dtype, jnp.integer):
+            run = _running(s, lax.cumsum)
+            out.append(run - jnp.take(run - s, start))
+            continue
+        if gid is None:
+            gid = _running(differs.astype(jnp.int32), lax.cumsum) - 1
+        red = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+               "max": jax.ops.segment_max}[kind]
+        out.append(jnp.take(red(
+            s, gid, num_segments=n, indices_are_sorted=True), gid))
+    return flags, kvals, out, rep
+
+
+def _front(first: jax.Array, classes: int, n: int) -> jax.Array:
+    """The order that moves the slots with the lower `first` (one of
+    `classes` small non-negative classes a slot) to the front, each
+    class in slot order: one sort of one operand, the slot's number in
+    the key's low part."""
+    if classes * n >= 1 << 31:
+        raise NotImplementedError("shards too long for a 32-bit key")
+    key = lax.sort(first.astype(jnp.int32) * n
+                   + jnp.arange(n, dtype=jnp.int32))
+    return key % n
+
+
+class GroupByResult(NamedTuple):
+    """What one launch hands back, every array stacked [n_dev, ...]:
+    `keys` and `aggs` are (values, validity or None) pairs with a
+    device's groups in its first `counts[d]` slots; `overflow[d]` is
+    true where device d had more groups for one target than a bucket
+    holds (the result is then short of them: the caller falls back)."""
+
+    keys: list
+    aggs: list
+    counts: jax.Array
+    overflow: jax.Array
+
+
+def _neutral(kind: str, dtype):
+    """What a state of this kind holds for no row at all."""
+    if kind == "sum":
+        return jnp.zeros((), dtype)
+    lo = kind == "max"
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.asarray(-jnp.inf if lo else jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.min if lo else info.max, dtype)
+
+
 class DistributedGroupBy:
     """filter -> project-keys -> partial agg -> ICI repartition -> final.
 
     All group-key dtypes must be device-hashable (ints/dates/f32/bool);
     string keys go through the file-shuffle tier instead (host hashing).
-    """
+    Validity goes through the program: a NULL key is a group of its own
+    (on whichever device its hash, which skips a NULL, names), a NULL
+    input adds nothing, and SUM/MIN/MAX/AVG over no value at all is
+    NULL.
+
+    `slack` sizes the exchange's buckets: None keeps the worst case (a
+    shard's every group to one target, `cap` slots a bucket); a number
+    gives `cap * slack / n_dev`, the expected share times a margin, and
+    the launch reports `overflow` where a bucket would not hold its
+    groups."""
 
     def __init__(self, mesh: Mesh, schema: Schema,
                  keys: Sequence[ir.Expr],
                  aggs: Sequence[DistAgg],
                  filter_pred: Optional[ir.Expr] = None,
-                 axis: str = "data"):
+                 axis: str = "data",
+                 slack: Optional[float] = None):
         self.mesh = mesh
         self.axis = axis
         self.schema = schema
+        self.slack = slack
         self.keys = [bind_opt(k, schema) for k in keys]
         self.aggs = [
             DistAgg(a.fn, bind_opt(a.expr, schema)
@@ -75,299 +194,223 @@ class DistributedGroupBy:
         self._traced_sigs = set()
 
     # ------------------------------------------------------------------
-    def _sig(self, stacked_cols, num_rows) -> Tuple:
-        return (
-            tuple((tuple(c.shape), str(c.dtype)) for c in stacked_cols),
-            (tuple(num_rows.shape), str(num_rows.dtype)),
+    @staticmethod
+    def signature(*args) -> Tuple:
+        """(shape, dtype) of every array argument, None where a
+        column brings no validity: what a compiled program is for."""
+        return tuple(
+            None if a is None else (tuple(a.shape), str(a.dtype))
+            for a in jax.tree.leaves(args, is_leaf=lambda x: x is None)
         )
 
+    def bucket_cap(self, cap: int) -> int:
+        """Slots of one exchange bucket for shards of `cap` rows."""
+        n_dev = self.mesh.shape[self.axis]
+        if self.slack is None or n_dev == 1:
+            return cap
+        return min(cap, -(-int(cap * self.slack) // n_dev))
+
     def prepare(self, stacked_cols: Sequence[jax.Array],
-                num_rows: jax.Array) -> bool:
+                rows: jax.Array, valids=None) -> bool:
         """Trace + compile ahead of the launch (jax AOT `lower().compile()`)
         so the caller can time the trace as its own sub-phase. Returns True
         iff a trace actually ran (first time this instance sees this arg
         signature); a warm repeat is a no-op returning False. Where the
         installed jax lacks the AOT path the jitted function stays in
         place and the first launch folds the trace (mesh_trace ~ 0)."""
-        sig = self._sig(stacked_cols, num_rows)
+        args = self._args(stacked_cols, rows, valids)
+        sig = self.signature(*args)
         if self._fn is None:
-            self._fn = self._compile(
-                tuple(c.shape for c in stacked_cols),
-                tuple(c.dtype for c in stacked_cols),
-            )
+            self._fn = self._compile()
         if sig in self._traced_sigs:
             return False
         self._traced_sigs.add(sig)
         try:
-            self._exec = self._fn.lower(
-                *stacked_cols, num_rows
-            ).compile()
+            self._exec = self._fn.lower(*args).compile()
             self._exec_sig = sig
         except Exception:  # noqa: BLE001 - AOT unsupported: trace at launch
             self._exec = None
             self._exec_sig = None
         return True
 
+    @staticmethod
+    def _args(stacked_cols, rows, valids):
+        cols = list(stacked_cols)
+        valids = list(valids) if valids is not None else [None] * len(cols)
+        return rows, cols, valids
+
+    def run(self, stacked_cols: Sequence[jax.Array], rows: jax.Array,
+            valids=None) -> GroupByResult:
+        """stacked_cols: [n_dev, cap] per input column (sharded or
+        shardable on axis 0); valids: its validity ([n_dev, cap] bool)
+        or None, a column; rows: [n_dev] live rows a shard (its first
+        rows) or a [n_dev, cap] bool mask of the live ones."""
+        args = self._args(stacked_cols, rows, valids)
+        if self._fn is None:
+            self._fn = self._compile()
+        if self._exec is not None and self._exec_sig == self.signature(*args):
+            return GroupByResult(*self._exec(*args))
+        return GroupByResult(*self._fn(*args))
+
     def __call__(self, stacked_cols: Sequence[jax.Array],
                  num_rows: jax.Array):
-        """stacked_cols: [n_dev, cap] per input column (sharded or
-        shardable on axis 0); num_rows: [n_dev] live rows per shard.
-        Returns (key_out, agg_out, group_counts): stacked [n_dev, ...] with
+        """The values alone, for inputs without NULLs: (key_out,
+        agg_out, group_counts), stacked [n_dev, ...] with
         group_counts[d] = groups owned by device d."""
-        if self._fn is None:
-            self._fn = self._compile(
-                tuple(c.shape for c in stacked_cols),
-                tuple(c.dtype for c in stacked_cols),
-            )
-        if (self._exec is not None
-                and self._exec_sig == self._sig(stacked_cols, num_rows)):
-            return self._exec(*stacked_cols, num_rows)
-        return self._fn(*stacked_cols, num_rows)
+        r = self.run(stacked_cols, num_rows)
+        return ([k for k, _ in r.keys], [a for a, _ in r.aggs], r.counts)
 
     # ------------------------------------------------------------------
-    def _compile(self, shapes, dtypes):
+    def _compile(self):
         mesh, axis = self.mesh, self.axis
         n_dev = mesh.shape[axis]
         schema = self.schema
         keys = self.keys
         aggs = self.aggs
         pred = self.filter_pred
-        n_keys = len(keys)
+        key_dtypes = [_key_dtype(k, schema) for k in keys]
 
-        def group_reduce(key_vals: List[jax.Array],
-                         agg_ins: List[jax.Array],
-                         live: jax.Array, cap: int):
-            """Sort-based segmented reduce of one shard's rows.
+        def key_operands(key_cvs, live):
+            """The keys as sort operands: one `flags` word and a value
+            a key, NULLs and NaNs at the value 0 so that equal flags
+            compare equal."""
+            flags = jnp.zeros(live.shape, jnp.int32)
+            kvals = []
+            for i, (v, m) in enumerate(key_cvs):
+                flag = jnp.zeros(live.shape, jnp.int32)
+                if jnp.issubdtype(v.dtype, jnp.floating):
+                    flag = jnp.where(jnp.isnan(v), _NAN, flag)
+                if m is not None:
+                    flag = jnp.where(m, flag, _NULL)
+                kvals.append(jnp.where(flag != 0, 0, v).astype(v.dtype))
+                flags = flags | (flag << (2 * i))
+            return jnp.where(live, flags, _DEAD), kvals
 
-            Returns (sorted key cols at boundaries, reduced states,
-            n_groups, live_groups mask)."""
-            pri = [jnp.where(live, 0, 1).astype(jnp.int8)]
-            for k in key_vals:
-                if jnp.issubdtype(k.dtype, jnp.floating):
-                    pri.append(jnp.where(jnp.isnan(k), jnp.inf, k))
-                    pri.append(jnp.isnan(k).astype(jnp.int8))
-                else:
-                    pri.append(k)
-            order = jnp.lexsort(tuple(reversed(pri)))
-            s_live = jnp.take(live, order)
-            diff = jnp.zeros(cap, dtype=jnp.bool_)
-            s_keys = []
-            for k in key_vals:
-                sk = jnp.take(k, order)
-                s_keys.append(sk)
-                if jnp.issubdtype(k.dtype, jnp.floating):
-                    # NaN groups with NaN, distinct from real +inf
-                    nf = jnp.take(jnp.isnan(k).astype(jnp.int8), order)
-                    cv = jnp.where(jnp.isnan(sk), jnp.inf, sk)
-                    diff = diff | (
-                        cv != jnp.concatenate([cv[:1], cv[:-1]])
-                    ) | (nf != jnp.concatenate([nf[:1], nf[:-1]]))
-                else:
-                    diff = diff | (
-                        sk != jnp.concatenate([sk[:1], sk[:-1]])
-                    )
-            first = s_live & ~jnp.concatenate(
-                [jnp.zeros(1, dtype=jnp.bool_), s_live[:-1]]
-            )
-            boundary = s_live & (diff | first)
-            gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-            gid = jnp.where(s_live, gid, cap - 1)
-            n_groups = jnp.sum(boundary.astype(jnp.int32))
-            bpos = jnp.nonzero(boundary, size=cap, fill_value=0)[0]
-            out_keys = [jnp.take(sk, bpos) for sk in s_keys]
-            states = []
-            for (a, x) in zip(aggs, agg_ins):
-                sx = jnp.take(x, order) if x is not None else None
-                if a.fn in (AggFn.COUNT, AggFn.COUNT_STAR):
-                    states.append(
-                        jax.ops.segment_sum(
-                            s_live.astype(jnp.int64), gid,
-                            num_segments=cap,
-                        )
-                    )
-                elif a.fn in (AggFn.SUM, AggFn.AVG):
+        def key_flag(flags, i):
+            return (flags >> (2 * i)) & 3
+
+        def row_states(ev, live):
+            """Every row as the partial state of a group of one."""
+            states, kinds = [], []
+
+            def put(s, kind):
+                states.append(s)
+                kinds.append(kind)
+
+            for a in aggs:
+                if a.fn is AggFn.COUNT_STAR:
+                    put(live.astype(jnp.int64), "sum")
+                    continue
+                x, m = ev.evaluate(a.expr)
+                ok = live if m is None else live & m
+                if a.fn is AggFn.COUNT:
+                    put(ok.astype(jnp.int64), "sum")
+                    continue
+                if a.fn in (AggFn.SUM, AggFn.AVG):
                     # accumulate in SUM's result type (int64 /
                     # float64, exprs/typing.py) like the single-device
                     # aggregate: an int32 or f32 column must not wrap
                     # or round in its own width
                     wide = (jnp.float64
-                            if jnp.issubdtype(sx.dtype, jnp.floating)
+                            if jnp.issubdtype(x.dtype, jnp.floating)
                             else jnp.int64)
-                    v = jnp.where(s_live, sx, jnp.zeros_like(sx)).astype(
-                        wide)
-                    states.append(
-                        jax.ops.segment_sum(v, gid, num_segments=cap)
-                    )
-                    if a.fn is AggFn.AVG:
-                        states.append(
-                            jax.ops.segment_sum(
-                                s_live.astype(jnp.int64), gid,
-                                num_segments=cap,
-                            )
-                        )
+                    put(jnp.where(ok, x, 0).astype(wide), "sum")
                 elif a.fn in (AggFn.MIN, AggFn.MAX):
-                    if jnp.issubdtype(sx.dtype, jnp.floating):
-                        neutral = jnp.inf if a.fn is AggFn.MIN else -jnp.inf
-                    else:
-                        info = jnp.iinfo(sx.dtype)
-                        neutral = (
-                            info.max if a.fn is AggFn.MIN else info.min
-                        )
-                    v = jnp.where(s_live, sx, jnp.asarray(neutral, sx.dtype))
-                    red = (jax.ops.segment_min if a.fn is AggFn.MIN
-                           else jax.ops.segment_max)
-                    states.append(red(v, gid, num_segments=cap))
+                    kind = "min" if a.fn is AggFn.MIN else "max"
+                    put(jnp.where(ok, x, _neutral(kind, x.dtype)), kind)
                 else:
                     raise NotImplementedError(a.fn)
-            live_groups = jnp.arange(cap, dtype=jnp.int32) < n_groups
-            return out_keys, states, n_groups, live_groups
+                # the values the state holds: none at all is NULL
+                put(ok.astype(jnp.int64), "sum")
+            return states, kinds
 
-        def merge_reduce(key_vals, states_in, live, cap):
-            """Final merge: same grouping, states combine by their merge op
-            (sum for SUM/COUNT/AVG parts, min/max for MIN/MAX)."""
-            pri = [jnp.where(live, 0, 1).astype(jnp.int8)]
-            for k in key_vals:
-                if jnp.issubdtype(k.dtype, jnp.floating):
-                    pri.append(jnp.where(jnp.isnan(k), jnp.inf, k))
-                    pri.append(jnp.isnan(k).astype(jnp.int8))
-                else:
-                    pri.append(k)
-            order = jnp.lexsort(tuple(reversed(pri)))
-            s_live = jnp.take(live, order)
-            diff = jnp.zeros(cap, dtype=jnp.bool_)
-            s_keys = []
-            for k in key_vals:
-                sk = jnp.take(k, order)
-                s_keys.append(sk)
-                if jnp.issubdtype(k.dtype, jnp.floating):
-                    # NaN groups with NaN, distinct from real +inf
-                    nf = jnp.take(jnp.isnan(k).astype(jnp.int8), order)
-                    cv = jnp.where(jnp.isnan(sk), jnp.inf, sk)
-                    diff = diff | (
-                        cv != jnp.concatenate([cv[:1], cv[:-1]])
-                    ) | (nf != jnp.concatenate([nf[:1], nf[:-1]]))
-                else:
-                    diff = diff | (
-                        sk != jnp.concatenate([sk[:1], sk[:-1]])
-                    )
-            first = s_live & ~jnp.concatenate(
-                [jnp.zeros(1, dtype=jnp.bool_), s_live[:-1]]
-            )
-            boundary = s_live & (diff | first)
-            gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-            gid = jnp.where(s_live, gid, cap - 1)
-            n_groups = jnp.sum(boundary.astype(jnp.int32))
-            bpos = jnp.nonzero(boundary, size=cap, fill_value=0)[0]
-            out_keys = [jnp.take(sk, bpos) for sk in s_keys]
-            out_states = []
-            si = 0
-            for a in aggs:
-                width = 2 if a.fn is AggFn.AVG else 1
-                for w in range(width):
-                    x = jnp.take(states_in[si], order)
-                    if a.fn in (AggFn.MIN, AggFn.MAX) and w == 0:
-                        if jnp.issubdtype(x.dtype, jnp.floating):
-                            neutral = (jnp.inf if a.fn is AggFn.MIN
-                                       else -jnp.inf)
-                        else:
-                            info = jnp.iinfo(x.dtype)
-                            neutral = (info.max if a.fn is AggFn.MIN
-                                       else info.min)
-                        v = jnp.where(s_live, x,
-                                      jnp.asarray(neutral, x.dtype))
-                        red = (jax.ops.segment_min if a.fn is AggFn.MIN
-                               else jax.ops.segment_max)
-                        out_states.append(
-                            red(v, gid, num_segments=cap)
-                        )
-                    else:
-                        v = jnp.where(s_live, x, jnp.zeros_like(x))
-                        out_states.append(
-                            jax.ops.segment_sum(v, gid, num_segments=cap)
-                        )
-                    si += 1
-            return out_keys, out_states, n_groups
-
-        def per_shard(num_rows_s, *cols_s):
-            cols = [c[0] for c in cols_s]
-            nr = num_rows_s[0]
-            cap = cols[0].shape[0]
-            ev = DeviceEvaluator(
-                schema, [(c, None) for c in cols], cap
-            )
-            live = jnp.arange(cap, dtype=jnp.int32) < nr
+        def per_shard(rows_s, cols_s, valids_s):
+            cols = [
+                (c[0], None if m is None else m[0])
+                for c, m in zip(cols_s, valids_s)
+            ]
+            cap = cols[0][0].shape[0]
+            ev = DeviceEvaluator(schema, cols, cap)
+            if rows_s.ndim == 2:
+                live = rows_s[0]
+            else:
+                live = jnp.arange(cap, dtype=jnp.int32) < rows_s[0]
             if pred is not None:
                 live = live & ev.evaluate_predicate(pred)
-            key_vals = [ev.evaluate(k)[0] for k in keys]
-            agg_ins = [
-                ev.evaluate(a.expr)[0] if a.expr is not None else None
-                for a in aggs
-            ]
-            out_keys, states, _, live_g = group_reduce(
-                key_vals, agg_ins, live, cap
-            )
+            states, kinds = row_states(ev, live)
+            flags, kvals = key_operands(
+                [ev.evaluate(k) for k in keys], live)
+            flags, kvals, states, rep = _reduce_sorted(
+                flags, kvals, states, kinds, cap)
             # ---- ICI repartition of partial groups by key hash ----
-            kcols = [
-                (k, None, _key_dtype(keys[i], schema))
-                for i, k in enumerate(out_keys)
-            ]
-            target = pmod(hash_columns_device(kcols, cap), n_dev)
-            payload = out_keys + states
+            target = jnp.where(rep, pmod(hash_columns_device(
+                [(v, key_flag(flags, i) == 0, dt)
+                 for i, (v, dt) in enumerate(zip(kvals, key_dtypes))],
+                cap), n_dev), n_dev).astype(jnp.int32)
+            bcap = self.bucket_cap(cap)
+            order = _front(target, n_dev + 1, cap)
+            sends = jnp.stack([
+                jnp.sum((target == t).astype(jnp.int32))
+                for t in range(n_dev)])
+            starts = jnp.cumsum(sends) - sends
+            got = lax.all_to_all(sends, axis, 0, 0, tiled=True)
             exchanged = []
-            for arr in payload:
-                b = _bucketize(arr, target, live_g, n_dev, cap)
-                ex = lax.all_to_all(
-                    b[None], axis, split_axis=1, concat_axis=0
-                )
-                exchanged.append(ex.reshape(n_dev * cap))
-            lv = _bucket_live(target, live_g, n_dev, cap)
-            lx = lax.all_to_all(
-                lv[None], axis, split_axis=1, concat_axis=0
-            ).reshape(n_dev * cap)
+            for arr in [flags] + kvals + states:
+                # a target's groups lie together: its bucket is a slice
+                arr = jnp.concatenate(
+                    [jnp.take(arr, order), jnp.zeros(bcap, arr.dtype)])
+                b = jnp.stack([
+                    lax.dynamic_slice(arr, (starts[t],), (bcap,))
+                    for t in range(n_dev)])
+                exchanged.append(lax.all_to_all(
+                    b, axis, 0, 0, tiled=True).reshape(n_dev * bcap))
             # ---- final merge on the owning shard ----
-            big = n_dev * cap
-            fk, fs, ng = merge_reduce(
-                exchanged[:n_keys], exchanged[n_keys:], lx, big
-            )
-            # finalize AVG into a float column
-            final_cols = []
+            big = n_dev * bcap
+            live_rx = (jnp.arange(bcap, dtype=jnp.int32)[None, :]
+                       < jnp.minimum(got, bcap)[:, None]).reshape(big)
+            flags = jnp.where(live_rx, exchanged[0], _DEAD)
+            kvals = exchanged[1:1 + len(keys)]
+            states = [
+                jnp.where(live_rx, s, _neutral(kind, s.dtype))
+                for s, kind in zip(exchanged[1 + len(keys):], kinds)
+            ]
+            flags, kvals, fs, rep = _reduce_sorted(
+                flags, kvals, states, kinds, big)
+            # the groups to the front, in key order
+            order = _front(~rep, 2, big)
+            flags = jnp.take(flags, order)
+            kvals = [jnp.take(v, order) for v in kvals]
+            fs = [jnp.take(s, order) for s in fs]
+            key_out = []
+            for i, v in enumerate(kvals):
+                if jnp.issubdtype(v.dtype, jnp.floating):
+                    v = jnp.where(key_flag(flags, i) == _NAN, jnp.nan, v)
+                key_out.append(
+                    (v[None, :], (key_flag(flags, i) != _NULL)[None, :]))
+            agg_out = []
             si = 0
             for a in aggs:
-                if a.fn is AggFn.AVG:
-                    s, c = fs[si], fs[si + 1]
-                    final_cols.append(
-                        s.astype(jnp.float64)
-                        / jnp.maximum(c, 1).astype(jnp.float64)
-                    )
-                    si += 2
-                else:
-                    final_cols.append(fs[si])
+                if a.fn in (AggFn.COUNT, AggFn.COUNT_STAR):
+                    agg_out.append((fs[si][None, :], None))
                     si += 1
-            return (
-                tuple(k[None, :] for k in fk)
-                + tuple(c[None, :] for c in final_cols)
-                + (ng[None],)
-            )
+                    continue
+                v, some = fs[si], fs[si + 1] > 0
+                if a.fn is AggFn.AVG:
+                    v = (v.astype(jnp.float64)
+                         / jnp.maximum(fs[si + 1], 1).astype(jnp.float64))
+                agg_out.append((v[None, :], some[None, :]))
+                si += 2
+            return (key_out, agg_out,
+                    jnp.sum(rep.astype(jnp.int32))[None],
+                    (jnp.max(sends) > bcap)[None])
 
-        n_out = n_keys + len(aggs) + 1
-        fn = shard_map(
-            per_shard, mesh=mesh,
-            in_specs=(P(axis),) + tuple(P(axis) for _ in shapes),
-            out_specs=tuple([P(axis)] * n_out),
-        )
+        def mesh_groupby(rows, cols, valids):
+            # every input and every output is sharded on its first axis
+            return shard_map(
+                per_shard, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+            )(rows, cols, valids)
 
-        @jax.jit
-        def run(*args):
-            num_rows = args[-1]
-            cols = args[:-1]
-            outs = fn(num_rows, *cols)
-            return (
-                list(outs[:n_keys]),
-                list(outs[n_keys:-1]),
-                outs[-1],
-            )
-
-        return run
+        return jax.jit(mesh_groupby)
 
 
 class DistributedBroadcastJoin:

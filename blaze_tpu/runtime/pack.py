@@ -256,6 +256,14 @@ def _put_packed_padded_lazy(entries):
         norm.append((vals, cap, fill))
     metas, total = _aligned_metas(norm)
     buf = np.empty(total, dtype=np.uint8)
+    _fill_packed(buf, norm, metas, pairs)
+    record("h2d_batches")
+    dev = jax.device_put(buf)
+    return dev, metas, pairs
+
+
+def _fill_packed(buf: np.ndarray, norm, metas, pairs: bool) -> None:
+    """Write each entry, padded to its capacity, into its segment."""
     for (vals, cap, fill), (dt_s, shape, off, nb) in zip(norm, metas):
         n = vals.shape[0] if vals.ndim else 0
         dt = np.dtype(dt_s)
@@ -280,9 +288,51 @@ def _put_packed_padded_lazy(entries):
             view = seg.view(dt).reshape(shape)
             view[:n] = vals
             view[n:] = fill
+
+
+def deal_cuts(num_rows: int, n_dev: int) -> List[Tuple[int, int]]:
+    """[(first row, end row)] of the `n_dev` runs a batch of `num_rows`
+    rows is dealt in: equal shares, the last ones shorter or empty."""
+    share = -(-num_rows // n_dev)
+    return [(min(d * share, num_rows), min((d + 1) * share, num_rows))
+            for d in range(n_dev)]
+
+
+def put_packed_dealt(entries: Sequence[Tuple[np.ndarray, int, int]],
+                     num_rows: int, sharding
+                     ) -> Tuple[jax.Array, Tuple, bool, int, List[int]]:
+    """`put_packed_padded_lazy` for a batch dealt over a mesh: the
+    batch's rows are cut into as many runs as `sharding` has devices,
+    every run is packed as a batch of its own (its first segment holds
+    the run's row count) into one row of a [n_dev, bytes] host buffer,
+    and one sharded `device_put` lands a run on each device. Every
+    entry's capacity is a whole batch's; a run's is its share of it.
+
+    Returns (buffer, metas, f64_pairs, run capacity, rows a run);
+    `build_unpack_at(metas, pairs)` splits a device's row, the count
+    first."""
+    with (obs_trace.span("h2d", n_arrays=len(entries))
+          if obs_trace.ACTIVE else obs_trace.NULL):
+        return _put_packed_dealt(entries, num_rows, sharding)
+
+
+def _put_packed_dealt(entries, num_rows: int, sharding):
+    pairs = _f64_pairs()
+    n_dev = len(sharding.device_set)
+    cuts = deal_cuts(num_rows, n_dev)
+    norm = [(np.asarray(v), -(-cap // n_dev), fill)
+            for v, cap, fill in entries]
+    run_cap = max(c for _, c, _ in norm)
+    count = (np.zeros(1, np.int32), 4, 0)
+    metas, total = _aligned_metas([count] + norm)
+    buf = np.empty((n_dev, total), dtype=np.uint8)
+    for d, (lo, hi) in enumerate(cuts):
+        run = [(np.full(1, hi - lo, np.int32), 4, 0)] + [
+            (v[lo:hi] if v.ndim else v, c, f) for v, c, f in norm]
+        _fill_packed(buf[d], run, metas, pairs)
     record("h2d_batches")
-    dev = jax.device_put(buf)
-    return dev, metas, pairs
+    dev = jax.device_put(buf, sharding)
+    return dev, metas, pairs, run_cap, [hi - lo for lo, hi in cuts]
 
 
 def unpack_kernel(metas, pairs: bool):

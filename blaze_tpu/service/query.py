@@ -408,6 +408,14 @@ class Query:
             # because the group count outgrew a tier, 0 included
             # (ops/hash_aggregate.py: run_grouped_kernel)
             out["agg_tier_retries"] = m["agg_tier_retries"]
+        if "mesh_group_runs" in m:
+            # a task whose plan was lowered onto the mesh group-by
+            # (parallel/mesh_ops.py): mesh programs that produced its
+            # answer (0 where the op fell back to one device), the
+            # fall-backs, and the rows placed on the devices
+            out["mesh_group_runs"] = m["mesh_group_runs"]
+            out["mesh_degraded"] = m.get("mesh.degraded", 0)
+            out["mesh_rows_in"] = m.get("mesh_rows_in", 0)
         if "sink_trim_batches" in m:
             # filtered batches the result sink read back whole and
             # trimmed on the host (ops/util.py: sink_arrow)

@@ -339,3 +339,84 @@ def test_compact_perm_is_refused(one_chip, monkeypatch):
     )
     # outputs[1], the (1, 1) SMEM count block
     assert "divisible by 8 and 128" in msg
+
+
+# ---- the mesh group-by, for the four chips of the described host -------
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The described host's four devices as the mesh the group-by is
+    given (`one_chip` has loaded the TPU library and set the cache)."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices), ("data",))
+
+
+def test_mesh_groupby_programs(four_chips):
+    """Query 1's mesh program (two nullable `int` keys, a nullable
+    `decimal(7,2)` sum, the date filter) and the program that appends a
+    dealt batch to the chips' column buffers compile for the four chips
+    (at 4,096 rows a shard: XLA's time over the sorts grows with their
+    length, 62 s on the chip's host at the cell's 262,144). The mesh
+    program holds all-to-alls and sorts and no scatter: a scatter of
+    `i64` is what three quarters of `q1_group.s4`'s device time are."""
+    import types
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from blaze_tpu.exprs import Col
+    from blaze_tpu.exprs.ir import AggFn
+    from blaze_tpu.parallel import mesh_ops
+    from blaze_tpu.parallel.sharded import DistAgg, DistributedGroupBy
+    from blaze_tpu.runtime.pack import _aligned_metas
+    from blaze_tpu.types import DataType, Field, Schema
+
+    n_dev, cap, run_cap = 4, 4096, 1024
+    shard = NamedSharding(four_chips, P("data"))
+
+    def stack(dtype, *shape):
+        return jax.ShapeDtypeStruct((n_dev,) + shape, dtype,
+                                    sharding=shard)
+
+    schema = Schema([Field("d", DataType.int32(), True),
+                     Field("c", DataType.int32(), True),
+                     Field("s", DataType.int32(), True),
+                     Field("a", DataType.decimal(7, 2), True)])
+    dtypes = (jnp.int32, jnp.int32, jnp.int32, jnp.int64)
+    gb = DistributedGroupBy(
+        four_chips, schema, keys=[Col("c"), Col("s")],
+        aggs=[DistAgg(AggFn.SUM, Col("a"))],
+        filter_pred=(Col("d") >= 2451545) & (Col("d") <= 2451910),
+        slack=1.5)
+    compiled = gb._compile().lower(
+        stack(jnp.bool_, cap), [stack(dt, cap) for dt in dtypes],
+        [stack(jnp.bool_, cap) for _ in dtypes]).compile()
+    text = compiled.as_text()
+    assert " all-to-all(" in text and " sort(" in text
+    assert " scatter(" not in text
+
+    # a dealt batch as the scan packs it: the run's rows, the date
+    # (the scan's filter leaves it no NULL), three nullable columns
+    entries = [(np.zeros(1, np.int32), 4, 0),
+               (np.zeros(1, np.int32), run_cap, 0)]
+    for dt in (np.int32, np.int32, np.int64):
+        entries += [(np.zeros(1, dt), run_cap, 0),
+                    (np.zeros(1, np.bool_), run_cap, 1)]
+    metas, nbytes = _aligned_metas(entries)
+    batch = types.SimpleNamespace(
+        metas=metas, pairs=True, run_cap=run_cap,
+        col_meta=[(None, False, None, True)]
+        + [(None, True, None, True)] * 3)
+    acc = (stack(jnp.int32), stack(jnp.bool_, cap),
+           [stack(dt, cap) for dt in dtypes],
+           [stack(jnp.bool_, cap) for _ in dtypes])
+    appended = jax.jit(
+        mesh_ops._build_deal_append(four_chips, batch),
+        donate_argnums=(0,),
+    ).lower(acc, stack(jnp.uint8, nbytes)).compile()
+    # the buffers are updated where they lie
+    assert appended.memory_analysis().alias_size_in_bytes \
+        >= n_dev * cap * (4 + 4 + 4 + 8) // n_dev

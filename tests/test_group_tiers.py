@@ -311,13 +311,20 @@ def client():
     from blaze_tpu.runtime.gateway import TaskGatewayServer
     from blaze_tpu.service import QueryService, ServiceClient
 
-    with QueryService(max_concurrency=2) as svc:
+    # one device's path: with the eight virtual devices of conftest.py
+    # the default would lower query 1's task onto the mesh group-by,
+    # which has no tiers (tests/test_mesh_group_q1.py)
+    with QueryService(max_concurrency=2, mesh_mode="off") as svc:
         with TaskGatewayServer(service=svc) as srv:
             with ServiceClient(*srv.address) as c:
                 yield c
 
 
-@pytest.mark.parametrize("core,retries", [("sort", 0), ("scatter", 2)])
+# the scatter core climbs two tiers in each of the split's three
+# per-batch programs (until PR 33 the eight virtual devices sent this
+# task to the mesh group-by, which fell back to the unfused aggregate
+# for the NULLs: one merge, two climbs)
+@pytest.mark.parametrize("core,retries", [("sort", 0), ("scatter", 6)])
 def test_poll_of_a_keyed_aggregate_carries_the_count(core, retries,
                                                      client, tmp_path):
     """Query 1's task through the served path (the cell `q1_group.s4`'s

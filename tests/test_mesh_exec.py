@@ -213,6 +213,105 @@ def test_mesh_groupby_empty_partitions():
     pd.testing.assert_frame_equal(got, want, check_dtype=False)
 
 
+def nullable_scan(n_parts=1, rows=6000, with_string=False):
+    """A source whose key, second key and amounts each hold NULLs, the
+    amounts `decimal(7,2)`; `with_string` adds a string column."""
+    import decimal
+
+    rng = np.random.default_rng(29)
+    parts, frames, schema = [], [], None
+    for _ in range(n_parts):
+        def holes(values):
+            return pd.Series(values, dtype=object).where(
+                rng.random(rows) > 0.1, None)
+
+        frame = pd.DataFrame({
+            "k": holes(rng.integers(0, 40, rows)),
+            "j": holes(rng.integers(0, 3, rows)),
+            "cents": holes(rng.integers(-5000, 90000, rows)),
+        })
+        # one group's amounts are all NULL
+        frame.loc[frame["k"] == 7, "cents"] = None
+        frames.append(frame)
+        cols = {
+            "k": pa.array(frame["k"], pa.int32()),
+            "j": pa.array(frame["j"], pa.int32()),
+            "amt": pa.array(
+                [None if c is None else decimal.Decimal(int(c)).scaleb(-2)
+                 for c in frame["cents"]], pa.decimal128(7, 2)),
+        }
+        if with_string:
+            cols["t"] = pa.array(["x"] * rows, pa.string())
+        cb = ColumnBatch.from_arrow(pa.record_batch(cols))
+        schema = cb.schema
+        parts.append([cb])
+    return MemoryScanExec(parts, schema), pd.concat(frames)
+
+
+def nullable_agg(source):
+    return HashAggregateExec(
+        source,
+        keys=[(Col("k"), "k"), (Col("j"), "j")],
+        aggs=[(AggExpr(AggFn.SUM, Col("amt")), "total"),
+              (AggExpr(AggFn.COUNT, Col("amt")), "n")],
+        mode=AggMode.COMPLETE,
+    )
+
+
+def test_mesh_groupby_takes_nullable_fixed_width_input():
+    """NULL keys on either column and on both, a group of NULL amounts
+    alone, `decimal(7,2)` summed exactly as `decimal(17,2)`: on the
+    mesh, nothing degraded, and a single partition's rows dealt over
+    the devices."""
+    source, frame = nullable_scan()
+    low = lower_plan_to_mesh(nullable_agg(source), mode="on")
+    assert isinstance(low, MeshGroupByExec)
+    ctx = ExecContext()
+    got = run_plan(low, ctx)
+    assert ctx.metrics.counters.get("mesh.degraded") is None
+    assert ctx.metrics.counters["mesh_group_runs"] == 1
+    assert ctx.metrics.counters["mesh_rows_in"] == len(frame)
+    assert got.schema.field("total").type == pa.decimal128(17, 2)
+    exp = frame.groupby(["k", "j"], dropna=False).agg(
+        total=("cents", lambda c: c.dropna().sum() if c.notna().any()
+               else None),
+        n=("cents", "count")).reset_index()
+
+    def keyed(rows):
+        return {(None if pd.isna(k) else int(k),
+                 None if pd.isna(j) else int(j)):
+                (None if pd.isna(t) else int(t), int(n))
+                for k, j, t, n in rows}
+
+    g = got.to_pandas()
+    have = keyed(zip(g["k"], g["j"],
+                     [None if t is None else t.scaleb(2) for t in
+                      g["total"]], g["n"]))
+    want = keyed(zip(exp["k"], exp["j"], exp["total"], exp["n"]))
+    assert len(g) == len(want) and have == want
+    assert any(k is None and j is None for k, j in want)
+    assert any(t is None for t, _ in want.values())
+
+
+@pytest.mark.parametrize("shape", ["string column", "more partitions"])
+def test_mesh_groupby_still_falls_back(shape):
+    """What the mesh group-by leaves to the other tiers: a child with a
+    string column is not lowered at all, and a child with more
+    partitions than the mesh has devices keeps its exchange."""
+    if shape == "string column":
+        source, _ = nullable_scan(with_string=True)
+        plan = nullable_agg(source)
+        assert lower_plan_to_mesh(plan, mode="on") is plan
+        with pytest.raises(NotImplementedError):
+            MeshGroupByExec(source, plan.keys, plan.aggs)
+    else:
+        source, _ = nullable_scan(n_parts=len(jax.devices()) + 1,
+                                  rows=50)
+        sw = insert_exchanges(nullable_agg(source), 4,
+                              shuffle_dir=tempfile.mkdtemp())
+        assert lower_plan_to_mesh(sw, mode="on") is sw
+
+
 def test_mesh_pipeline_differential():
     def chain(src):
         return ProjectExec(

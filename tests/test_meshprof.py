@@ -137,9 +137,13 @@ def test_note_trace_first_vs_retrace():
 
 
 def test_subphase_spans_parent_pinned_and_chrome_clean():
-    """Every stage sub-phase lands as a child span of `mesh_execute`
-    on its own track, and the exported document stays
-    validate_chrome-clean."""
+    """Every stage sub-phase of the group-by is in the trace once: the
+    three that are stage spans (`obs/trace.py: STAGE_SPANS`, folded
+    into POLL's `stages`) live on the thread that ran them, the others
+    as child spans of `mesh_execute` on its own track; the exported
+    document stays validate_chrome-clean."""
+    import threading
+
     low = lowered_groupby()
     ctx = ExecContext()
     obs_trace.enable()
@@ -161,6 +165,11 @@ def test_subphase_spans_parent_pinned_and_chrome_clean():
     for sub in ("mesh_lower", "mesh_trace", "mesh_stage_in",
                 "mesh_launch", "mesh_sync", "mesh_gather"):
         assert sub in by_name, f"missing sub-phase span {sub}"
+        assert names.count(sub) == 1, f"{sub} counted twice"
+        if sub in obs_trace.STAGE_SPANS:
+            # ...a stage span of the thread that ran the stage
+            assert by_name[sub].tid == threading.get_ident()
+            continue
         # ...pinned under mesh_execute on the sub-phase track
         assert by_name[sub].parent_id == parent.span_id
         assert by_name[sub].tid == meshprof.MESH_SUB_TID
