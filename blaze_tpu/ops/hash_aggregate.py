@@ -12,7 +12,11 @@ boundaries by comparing adjacent sorted keys (SQL semantics: NULL groups
 with NULL), then `jax.ops.segment_*` reductions with a static segment count
 (the batch capacity), so every step is one fused XLA program with static
 shapes. Variance/stddev state is (count, sum, sum-of-squares) so every
-merge is a plain segment_sum.
+merge is a plain sum of states. Where the rows lie sorted by group (the
+sort core, keyed) an integer sum takes no scatter: it is a running sum
+read where the groups start (`_SegOps.sum`, ops/running.py), the same
+bits as `segment_sum`'s; float sums, min/max, the scatter core and the
+keyless slot reduce as before.
 
 PARTIAL mode streams: each input batch aggregates independently (bounded
 state, like the reference's partial aggregation). FINAL/COMPLETE are
@@ -29,6 +33,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from blaze_tpu.config import get_config
 from blaze_tpu.types import DataType, Field, Schema, TypeId
@@ -41,6 +46,7 @@ from blaze_tpu.exprs.typing import infer_dtype
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.host_lower import lower_strings_host
 from blaze_tpu.ops.project import _unflatten_cvs
+from blaze_tpu.ops.running import running_scan
 from blaze_tpu.ops.util import concat_batches, sort_indices
 from blaze_tpu.runtime.dispatch import (
     cached_kernel,
@@ -170,20 +176,24 @@ def run_grouped_kernel(base_key, build, args, fetch_n, gcap,
     through to cached_kernel. A wrong guess costs runtime choice and
     launches, never correctness: either path is right for either core.
     `span` names the obs span so phases.py can band group/join
-    dispatches separately. A keyed aggregate leaves `agg_tier_retries`
-    in its task's metrics (POLL): programs launched again because the
-    count outgrew a tier, 0 on the sort core."""
+    dispatches separately. A keyed aggregate leaves two counts in its
+    task's metrics (POLL): `agg_tier_retries`, programs launched again
+    because the count outgrew a tier, 0 on the sort core; and
+    `agg_running_sum_launches`, programs launched whose integer sums
+    are read off a running sum and not scattered (_rows_by_group, the
+    rule `_SegOps.sum` follows), 0 on the scatter core."""
     tiers = _group_tiers(gcap)
     keyless = gcap == 1  # both callers pass 1 for no keys, and only then
     if not keyless:
-        _count_tier_retries(0)
-    if scatter_class or keyless:
-        host_outs, n = _climb_tiers(
-            base_key, build, args, fetch_n, tiers, scatter_class, span
-        )
-    else:
+        _count("agg_tier_retries", 0)
+        _count("agg_running_sum_launches", 0)
+    if _rows_by_group(keyless, scatter_class):
         host_outs, n = _cut_tiers(
             base_key, build, args, fetch_n, tiers, False, span
+        )
+    else:
+        host_outs, n = _climb_tiers(
+            base_key, build, args, fetch_n, tiers, scatter_class, span
         )
     if n < 0:
         host_outs, n = _cut_tiers(
@@ -192,10 +202,10 @@ def run_grouped_kernel(base_key, build, args, fetch_n, gcap,
     return host_outs, n
 
 
-def _count_tier_retries(k: int) -> None:
+def _count(name: str, k: int) -> None:
     task = current_task()
     if task is not None:
-        task.metrics.add("agg_tier_retries", k)
+        task.metrics.add(name, k)
 
 
 def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class,
@@ -212,7 +222,7 @@ def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class,
         host_outs, n = fetch_n(*fn(*args))
         if gc is None or n <= gc:
             return host_outs, n
-        _count_tier_retries(1)
+        _count("agg_tier_retries", 1)
 
 
 def _cut_tiers(base_key, build, args, fetch_n, tiers, force_lex, span):
@@ -230,6 +240,8 @@ def _cut_tiers(base_key, build, args, fetch_n, tiers, force_lex, span):
         span=span,
     )
     by_cut, n_groups = fn(*args)
+    # a cut program is keyed and on the sort core, forced lexsort or not
+    _count("agg_running_sum_launches", int(_rows_by_group(False, False)))
     host_outs, n = fetch_n(by_cut[0], n_groups)
     fit = next((i for i, t in enumerate(cuts) if n <= t), len(cuts))
     if fit:
@@ -253,16 +265,27 @@ def _with_cuts(inner, cuts):
     return kernel
 
 
+def _rows_by_group(keyless: bool, scatter: bool) -> bool:
+    """Whether a grouping program leaves its rows sorted by group: the
+    sort core with keys. There `_SegOps.sum` reads an integer sum off a
+    running sum and a tier is a cut of one result (`_cut_tiers`). The
+    scatter core keeps the rows in input order and the keyless slot's
+    dead rows lie anywhere: both reduce by segment."""
+    return not (keyless or scatter)
+
+
 class _SegOps:
     """Segmented reductions sized to the group-slot capacity (out_cap),
     not the row capacity. The keyless single-group case collapses to
     plain masked reductions - an XLA reduce instead of a scatter, which
-    matters enormously on TPU where scatters serialize."""
+    matters enormously on TPU where scatters serialize. So does an
+    integer sum over rows sorted by group (`starts`: each group's first
+    row, `n_groups` of them): a running sum read where the groups
+    start."""
 
     def __init__(self, gid, out_cap: int, keyless: bool,
-                 domain: int = None, compact_slots=None):
-        import os
-
+                 domain: int = None, compact_slots=None,
+                 starts=None, n_groups=None):
         self.gid = gid
         self.out_cap = out_cap
         self.scalar = keyless and out_cap == 1
@@ -276,6 +299,8 @@ class _SegOps:
         # neutral element first.
         self.domain = out_cap if domain is None else domain
         self.compact_slots = compact_slots
+        self.starts = starts
+        self.n_groups = n_groups
 
     def _finish(self, r):
         if self.compact_slots is not None:
@@ -285,9 +310,33 @@ class _SegOps:
     def sum(self, x):
         if self.scalar:
             return jnp.sum(x, axis=0, keepdims=True)
+        if (self.starts is not None and x.ndim == 1
+                and jnp.issubdtype(x.dtype, jnp.integer)):
+            return self._sum_by_group(x)
         return self._finish(jax.ops.segment_sum(
             x, self.gid, num_segments=self.domain
         ))
+
+    def _sum_by_group(self, x):
+        """`segment_sum`'s bits with no scatter: a group's sum is the
+        running sum where the next group starts less the running sum
+        where it starts itself, the last group closed by the total
+        (dead rows add 0 wherever they lie; integers wrap alike on both
+        sides). A float sum would round differently and stays a
+        segment sum."""
+        if self.out_cap < x.shape[0]:
+            # groups past the slots add nothing: segment_sum drops them
+            x = jnp.where(self.gid < self.out_cap, x, jnp.zeros_like(x))
+        run = running_scan(x, lax.cumsum)
+        start = jnp.take(run - x, self.starts)
+        slot = jnp.arange(self.out_cap, dtype=jnp.int32)
+        end = jnp.where(
+            slot + 1 < self.n_groups,
+            jnp.concatenate([start[1:], run[-1:]]),
+            run[-1],
+        )
+        return jnp.where(slot < self.n_groups, end - start,
+                         jnp.zeros_like(start))
 
     def min(self, x):
         if self.scalar:
@@ -1063,15 +1112,17 @@ class HashAggregateExec(PhysicalOp):
                 # dead rows park in the last segment; every reduction
                 # masks them to its neutral element so they never count
                 gid_sorted = jnp.where(s_live, gid_sorted, out_cap - 1)
-                n_groups = jnp.where(
-                    collision,
-                    jnp.int32(-1),
-                    jnp.sum(boundary.astype(jnp.int32)),
-                )
-                # boundary row index per group, padded
-                bpos = jnp.nonzero(
-                    boundary, size=out_cap, fill_value=0
-                )[0]
+                n_live = jnp.sum(boundary.astype(jnp.int32))
+                n_groups = jnp.where(collision, jnp.int32(-1), n_live)
+                # boundary row index per group, padded with 0: the
+                # boundary rows' numbers sorted to the front (a TPU sorts
+                # a lane of i32 in no time; jnp.nonzero counts the rows
+                # into place with a scatter-add of i64)
+                first = lax.sort(jnp.where(
+                    boundary, jnp.arange(capacity, dtype=jnp.int32),
+                    jnp.int32(capacity),
+                ))[:out_cap]
+                bpos = jnp.where(first < capacity, first, 0)
             elif not n_keys:
                 idx = None
                 s_live = live
@@ -1092,9 +1143,12 @@ class HashAggregateExec(PhysicalOp):
                     km = jnp.take(_tk(m, idx), bpos)
                 outs.append((kv, km))
 
+            by_group = _rows_by_group(n_keys == 0, use_scatter)
             segops = _SegOps(
                 gid_sorted, out_cap, n_keys == 0,
                 domain=seg_domain, compact_slots=seg_compact,
+                starts=bpos if by_group else None,
+                n_groups=n_live if by_group else None,
             )
             for i, (a, name) in enumerate(aggs):
                 outs.extend(

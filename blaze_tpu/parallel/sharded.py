@@ -36,6 +36,7 @@ from blaze_tpu.exprs.eval import DeviceEvaluator
 from blaze_tpu.exprs.hashing import hash_columns_device, pmod
 from blaze_tpu.exprs.ir import AggFn
 from blaze_tpu.exprs.typing import infer_dtype
+from blaze_tpu.ops.running import running_scan as _running
 from blaze_tpu.parallel.repartition import _bucket_live, _bucketize
 
 
@@ -49,27 +50,6 @@ class DistAgg:
 # 1 NULL, 2 NaN (a float key's NaNs are one group); a dead slot's flags
 # are _DEAD, which sorts last
 _NULL, _NAN, _DEAD = 1, 2, 1 << 30
-
-_BLOCK = 512
-
-
-def _running(x: jax.Array, scan) -> jax.Array:
-    """`scan` (lax.cumsum, lax.cummax) along a vector, as runs of
-    _BLOCK with the runs' totals scanned in turn: the chip's compiler
-    takes a second over this where it takes minutes over one scan of
-    400,000 `i64` (177 s for `jnp.cumsum`, 54 s and 33 MB of code for
-    an `associative_scan`)."""
-    n = x.shape[0]
-    pad = (-n) % _BLOCK
-    m = jnp.concatenate([x, jnp.zeros(pad, x.dtype)]).reshape(-1, _BLOCK)
-    inner = scan(m, axis=1)
-    ends = scan(inner[:, -1], axis=0)
-    if scan is lax.cumsum:
-        out = inner + (ends - inner[:, -1])[:, None]
-    else:  # cummax of values that are never negative
-        out = jnp.maximum(inner, jnp.concatenate(
-            [jnp.zeros(1, x.dtype), ends[:-1]])[:, None])
-    return out.reshape(-1)[:n]
 
 
 def _reduce_sorted(flags, kvals, states, kinds, n: int):

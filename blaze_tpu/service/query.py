@@ -408,6 +408,14 @@ class Query:
             # because the group count outgrew a tier, 0 included
             # (ops/hash_aggregate.py: run_grouped_kernel)
             out["agg_tier_retries"] = m["agg_tier_retries"]
+        if "agg_running_sum_launches" in m:
+            # grouping programs of a keyed aggregate whose integer sums
+            # were read off a running sum, no scatter: every one on the
+            # sort core, 0 on the scatter core
+            # (ops/hash_aggregate.py: _SegOps.sum)
+            out["agg_running_sum_launches"] = (
+                m["agg_running_sum_launches"]
+            )
         if "mesh_group_runs" in m:
             # a task whose plan was lowered onto the mesh group-by
             # (parallel/mesh_ops.py): mesh programs that produced its

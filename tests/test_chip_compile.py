@@ -224,6 +224,98 @@ def test_keyless_carry_kernel(one_chip, sales_parquet, agg, with_carry):
         .compile()
 
 
+@pytest.fixture(scope="module")
+def returns_parquet(tmp_path_factory):
+    """One batch of the four columns query 1's CTE reads from
+    `store_returns` (a date, two nullable `int` keys, a nullable
+    decimal(7,2) stored as parquet INT32) and the amount as a `double`."""
+    import decimal
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(34)
+    n = 16384
+
+    def ints(hi):
+        return pa.array(rng.integers(1, hi, n).astype(np.int32),
+                        mask=rng.random(n) < 0.045)
+
+    cents = rng.integers(0, 3_000_000, n)
+    path = str(tmp_path_factory.mktemp("q1") / "returns.parquet")
+    pq.write_table(pa.table({
+        "sr_returned_date_sk": pa.array(
+            rng.integers(2451545, 2451911, n).astype(np.int32)),
+        "sr_customer_sk": ints(12_000_000),
+        "sr_store_sk": ints(1002),
+        "sr_return_amt": pa.array(
+            [decimal.Decimal(int(c)).scaleb(-2) for c in cents],
+            pa.decimal128(7, 2), mask=rng.random(n) < 0.045),
+        "amt_double": pa.array(cents / 100.0, mask=rng.random(n) < 0.045),
+    }), path, store_decimal_as_integer=True)
+    return path
+
+
+@pytest.mark.parametrize("amount,scatters", [
+    pytest.param("sr_return_amt", 0, id="decimal_sum"),
+    pytest.param("amt_double", 1, id="double_sum"),
+])
+def test_q1_batch_grouping_program(one_chip, returns_parquet, amount,
+                                   scatters):
+    """Query 1's per-batch grouping program on the sort core, what
+    `auto` resolves to on the chip (pinned here, where it resolves to
+    the scatter core): unpack of the packed wire buffer, the date
+    filter, the sort by the keys' hash, boundaries, and the states cut
+    at the first tier; `q1_group.s4` launches it 64 times a task. Its
+    integer sums (a decimal's four i64 limbs, the any-valid count) are
+    read off a running sum and each group's first row comes from a sort,
+    so it holds no scatter: an `i64` scatter of 16,384 updates is 1.2 ms
+    on the chip where the sort of the same rows is microseconds
+    (PERF.md, PR 30). A `double` sum stays a segment sum, the one
+    scatter: a difference of running sums would round differently. The
+    merge over 737,280 partial rows is compiled on the chip, not here."""
+    import dataclasses
+
+    from blaze_tpu.batch import packed_view
+    from blaze_tpu.config import get_config, set_config
+    from blaze_tpu.exprs import AggExpr, AggFn, Col
+    from blaze_tpu.ops import (
+        AggMode, ExecContext, FilterExec, HashAggregateExec,
+    )
+    from blaze_tpu.ops.fused import fuse_pipelines
+    from blaze_tpu.ops.hash_aggregate import _with_cuts
+    from blaze_tpu.ops.parquet_scan import FileRange, ParquetScanExec
+
+    date = Col("sr_returned_date_sk")
+    fused = fuse_pipelines(HashAggregateExec(
+        FilterExec(
+            ParquetScanExec(
+                [[FileRange(returns_parquet)]],
+                projection=["sr_returned_date_sk", "sr_customer_sk",
+                            "sr_store_sk", amount]),
+            (date >= 2451545) & (date <= 2451910)),
+        keys=[(Col("sr_customer_sk"), "ctr_customer_sk"),
+              (Col("sr_store_sk"), "ctr_store_sk")],
+        aggs=[(AggExpr(AggFn.SUM, Col(amount)), "ctr_total_return")],
+        mode=AggMode.COMPLETE,
+    )).children[0]
+    cb = next(iter(fused.children[0].execute(0, ExecContext())))
+    pv = packed_view(cb)
+    assert pv is not None and cb.capacity == 16384
+    prior = get_config()
+    set_config(dataclasses.replace(prior, group_core="sort"))
+    try:
+        kernel = _with_cuts(fused._build_kernel_packed(pv), (4096,))
+        buf = jax.ShapeDtypeStruct(pv.buf.shape, pv.buf.dtype,
+                                   sharding=one_chip)
+        text = jax.jit(lambda b: kernel(b, None, None)).lower(buf) \
+            .compile().as_text()
+    finally:
+        set_config(prior)
+    assert " sort(" in text
+    assert text.count(" scatter(") == scatters
+
+
 def _shuffle_batch(table):
     """One 16,384-row batch shaped as the benchmark's shuffle cells
     scan it (`store_sales`: 23 columns, nullable `int` keys, decimal(7,2)

@@ -324,9 +324,11 @@ def client():
 # per-batch programs (until PR 33 the eight virtual devices sent this
 # task to the mesh group-by, which fell back to the unfused aggregate
 # for the NULLs: one merge, two climbs)
-@pytest.mark.parametrize("core,retries", [("sort", 0), ("scatter", 6)])
+@pytest.mark.parametrize("core,retries,running", [("sort", 0, 3),
+                                                  ("scatter", 6, 0)])
 def test_poll_of_a_keyed_aggregate_carries_the_count(core, retries,
-                                                     client, tmp_path):
+                                                     running, client,
+                                                     tmp_path):
     """Query 1's task through the served path (the cell `q1_group.s4`'s
     plan over a rehearsal split): exact against the benchmark's plain
     reference, and POLL says how many grouping programs ran again. The
@@ -347,6 +349,9 @@ def test_poll_of_a_keyed_aggregate_carries_the_count(core, retries,
         "groups_wrong": 0, "answer_shape_wrong": 0}
     assert poll["state"] == "DONE" and not poll.get("cache_hits")
     assert poll["agg_tier_retries"] == retries
+    # two per-batch programs and the merge read their integer sums off a
+    # running sum on the sort core; the scatter core scatters them
+    assert poll["agg_running_sum_launches"] == running
 
 
 def test_poll_without_a_keyed_aggregate_has_no_count(client, tmp_path):
@@ -368,3 +373,4 @@ def test_poll_without_a_keyed_aggregate_has_no_count(client, tmp_path):
         poll = client.poll(st["query_id"])
         assert poll["state"] == "DONE" and poll["task_dispatches"] > 0
         assert "agg_tier_retries" not in poll
+        assert "agg_running_sum_launches" not in poll
