@@ -17,7 +17,8 @@ module is the aggregation that makes those measurements diffable:
     the `_all` aggregate class that survives fingerprint drift across
     hosts. The fold is trace-driven where a trace exists (span-name ->
     phase map) and timings-driven where it does not, so obs-off
-    serving still rolls up the lifecycle phases.
+    serving still rolls up the lifecycle phases (and `dispatch`, from
+    the task's launch counter).
   * `compare()` diffs two rollup snapshots phase-by-phase with a
     noise band (relative factor + absolute floor, per-phase
     overridable) and returns the regressions - the machine check
@@ -53,9 +54,7 @@ PHASES = (
     "arrow_decode",  # parquet file-range decode (prefetch threads);
                      # pre-split rollups called this "decode"
     "h2d",          # packed host->device staging
-    "dispatch",     # compiled-kernel launches
-    "join",         # fused join-probe kernel launches
-    "group",        # fused grouped-aggregate kernel launches
+    "dispatch",     # program launches: the task's `launch_ns`
     # mesh stage anatomy (obs/meshprof.py): the sub-phases of one
     # mesh_execute stage, folded from its child spans when tracing
     "mesh_lower",     # planner pass (lower_plan_to_mesh)
@@ -80,9 +79,6 @@ SPAN_PHASE = {
     "plan_decode": "plan_decode",
     "parquet_decode": "arrow_decode",
     "h2d": "h2d",
-    "kernel_dispatch": "dispatch",
-    "join_dispatch": "join",
-    "group_dispatch": "group",
     "execute_partition": "execute",
     "result_stream": "stream",
     "router_place": "router",
@@ -116,6 +112,9 @@ STAGE_PHASE = {
     n: SPAN_PHASE[n] for n in sorted(obs_trace.STAGE_SPANS)
     if SPAN_PHASE.get(n)
 }
+# POLL's `stages` and `waits`, folded in one pass
+POLL_PHASE = {**STAGE_PHASE,
+              **{n: n for n in sorted(obs_trace.WAIT_SPANS)}}
 
 ALL_CLASS = "_all"
 
@@ -204,11 +203,11 @@ class PhaseRollup:
 
     def fold_query(self, q) -> None:
         """Fold one FINISHED service Query: lifecycle phases from its
-        monotonic timings, execution-interior phases (decode/h2d/
-        dispatch) from its span tree when tracing was on. Called from
-        the exactly-once terminal hook."""
+        monotonic timings, `dispatch` from its launch counter,
+        execution-interior phases (decode/h2d) from its span tree when
+        tracing was on. Called from the exactly-once terminal hook."""
         t = q.timings
-        durations: Dict[str, float] = {}
+        durations = _launch_phase(q.ctx)
         sub = t.get("submitted")
         fin = t.get("finished")
         if sub is not None and fin is not None:
@@ -284,10 +283,16 @@ class PhaseRollup:
             self._folded = 0
 
 
+def _launch_phase(ctx) -> Dict[str, float]:
+    """`dispatch`: the seconds the task's threads spent launching its
+    programs (runtime/dispatch.py), tracing on or off."""
+    return {"dispatch": ctx.launch_ns / 1e9} if ctx.launches else {}
+
+
 def fold_span_dicts(span_dicts) -> Dict[str, float]:
     """Sum one query's span durations into phase totals (seconds).
-    Multiple spans of one phase (per-file decode, per-kernel dispatch)
-    sum: the result is 'seconds this query spent in that phase'."""
+    Multiple spans of one phase (per-file decode, per-batch h2d) sum:
+    the result is 'seconds this query spent in that phase'."""
     totals: Dict[str, float] = {}
     for d in span_dicts:
         phase = SPAN_PHASE.get(str(d.get("name", "")))
@@ -337,11 +342,6 @@ PHASE_BANDS: Dict[str, tuple] = {
     # band is widened accordingly (a real regression here is a
     # multiple of the whole stream, e.g. a lost first-part wakeup)
     "stream": (4.0, 0.25),
-    # fused join-probe / grouped-carry dispatch phases: one kernel
-    # launch per batch, so small-row probes measure low-millisecond
-    # p50s with the same scheduler-load wobble as the hop phases
-    "join": (2.0, 0.05),
-    "group": (2.0, 0.05),
     # plan_decode: protobuf-walk time, tens of microseconds to
     # low-single-digit milliseconds - and ZERO on a decoded-plan-cache
     # hit, so cross-round p50s swing with the cache hit mix, not with
@@ -504,10 +504,9 @@ def run_probe(rounds: int = 6, rows: int = 1 << 18,
             if i < warmup:
                 continue  # compilation round: not a phase sample
             t = q.timings
-            durations = {
-                "e2e": t["finished"] - t["submitted"],
-                "execute": t["finished"] - t["run_start"],
-            }
+            durations = _launch_phase(q.ctx)
+            durations["e2e"] = t["finished"] - t["submitted"]
+            durations["execute"] = t["finished"] - t["run_start"]
             if "admitted" in t:
                 durations["queue_wait"] = (
                     t["admitted"] - t["submitted"]

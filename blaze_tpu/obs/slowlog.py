@@ -57,7 +57,7 @@ def build_payload(q, threshold_s: float) -> Dict[str, Any]:
     tracer = getattr(q, "tracer", None)
     if tracer is not None:
         # per-span-name duration rollup: where inside execution the
-        # time went (attempt / parquet_decode / h2d / kernel_dispatch
+        # time went (attempt / parquet_decode / h2d / wait_batch
         # / cache_probe / host_degrade ...)
         rollup: Dict[str, Dict[str, float]] = {}
         for s in list(tracer.spans):

@@ -8,8 +8,8 @@ worker processes, and none of those hops were visible in one place.
 This module is the span layer that stitches them: one TraceRecorder
 per query, opened at SUBMIT and closed at the terminal state, with
 child spans for queue-wait, admission, per-attempt partition
-execution, parquet decode, H2D staging, per-dispatch kernel
-execution, host-engine degradation, cache probes, and result
+execution, parquet decode, H2D staging, the waits at the scan's
+prefetch queue, host-engine degradation, cache probes, and result
 streaming. Chaos faults and cancellations land as span events.
 
 Design constraints (same discipline as testing/chaos.py):
@@ -60,8 +60,8 @@ ACTIVE = False
 _enable_count = 0
 _lock = threading.Lock()
 
-# bounded per-trace span count: a runaway query (or a per-dispatch
-# span storm) degrades to a truncated trace, never unbounded memory
+# bounded per-trace span count: a runaway query (or a span storm)
+# degrades to a truncated trace, never unbounded memory
 MAX_SPANS_PER_TRACE = int(os.environ.get("BLAZE_TRACE_MAX_SPANS",
                                          20000))
 _MAX_RETAINED_TRACES = 256
@@ -79,9 +79,8 @@ LIFECYCLE_TID = 0
 # time between its two ends (`Span.cpu_ns`; wall less cpu is what the
 # thread waited: GIL, device, disk). Not stages: spans a generator is
 # suspended inside (execute_partition, attempt, execute, the file
-# range's parquet_decode), the lifecycle spans `record_span` writes
-# after the fact, and kernel_dispatch, which the runtime's own
-# PjitFunction events already name on the profiler's side.
+# range's parquet_decode) and the lifecycle spans `record_span` writes
+# after the fact.
 STAGE_SPANS = frozenset({
     "decode_batch", "h2d", "compact", "d2h", "agg_fetch",
     "shuffle_partition", "shuffle_encode", "shuffle_finalize",
@@ -89,6 +88,18 @@ STAGE_SPANS = frozenset({
     "mesh_stage_in", "mesh_sync", "mesh_gather",
     "cache_probe", "service_admit",
 })
+
+# Wait spans: a thread blocked at the scan's prefetch queue
+# (runtime/prefetch.py), opened only when the call would block. Each
+# gets a stage's profiler annotation, so `idle_gaps` can name a device
+# gap by it, and no CPU reading: a wait has no CPU time to speak of.
+# POLL folds them into `waits`, apart from `stages`: a wait is never
+# taken out of a stage, nor a stage out of a wait.
+WAIT_SPANS = frozenset({
+    "wait_batch",  # the draining thread, for a decoded batch
+    "wait_room",   # the prefetch thread, for room in the queue
+})
+_ANNOTATED = STAGE_SPANS | WAIT_SPANS
 
 
 # one getpid for the process, not one a span: a system call is dear
@@ -282,9 +293,10 @@ class TraceRecorder:
         objects, one small output dict.
 
         With `stage_table` each value is `{"wall_s", "cpu_s", "n"}`
-        (POLL's `stages`), and a span folded into a phase is taken out
-        of the folded span that encloses it (h2d out of decode_batch),
-        so that the stages of one thread never count a second twice."""
+        (POLL's `stages` and `waits`), and a stage span is taken out of
+        the folded stage span that encloses it (h2d out of
+        decode_batch), so that the stages of one thread never count a
+        second twice. A wait span is never taken out of a stage."""
         acc: Dict[str, List[int]] = {}  # phase -> [wall_ns, cpu_ns, n]
         with self._lock:
             spans = self.spans
@@ -301,11 +313,13 @@ class TraceRecorder:
                 a[0] += end - s.start_ns
                 a[1] += s.cpu_ns
                 a[2] += 1
-                if stage_table and 0 < s.parent_id <= len(spans):
+                if (stage_table and s.name in STAGE_SPANS
+                        and 0 < s.parent_id <= len(spans)):
                     # span ids count from 1 in append order
                     up = spans[s.parent_id - 1]
                     outer = acc.get(phase_of.get(up.name))
-                    if outer is not None and up.end_ns is not None:
+                    if (outer is not None and up.end_ns is not None
+                            and up.name in STAGE_SPANS):
                         outer[0] -= end - s.start_ns
                         outer[1] -= s.cpu_ns
         if stage_table:
@@ -420,11 +434,12 @@ class _SpanCtx:
         st.append((self._rec, sp))
         self.span = sp
         self._pushed = True
-        if self._name in STAGE_SPANS:
+        if self._name in _ANNOTATED:
             self._ann = _stage_annotation(self._name)
             if self._ann is not None:
                 self._ann.__enter__()
-            self._cpu0 = time.thread_time_ns()
+            if self._name in STAGE_SPANS:
+                self._cpu0 = time.thread_time_ns()
         return sp
 
     def __exit__(self, exc_type, exc, tb):
@@ -432,8 +447,8 @@ class _SpanCtx:
             return False
         if self._cpu0 is not None:
             self.span.cpu_ns = time.thread_time_ns() - self._cpu0
-            if self._ann is not None:
-                self._ann.__exit__(exc_type, exc, tb)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1][1] is self.span:
             st.pop()

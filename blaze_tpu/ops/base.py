@@ -63,6 +63,14 @@ class ExecContext:
     # kernel launches made on behalf of this task alone
     # (runtime/dispatch.py counts them; POLL's `task_dispatches`)
     task_dispatches: int = 0
+    # every program launch made on behalf of this task (cached kernels
+    # and the plain jits runtime/dispatch.py's `launch` wraps), the
+    # launching threads' wall time inside the calls, and the arrays
+    # the calls handed back (POLL's `launches`, `launch_s`,
+    # `launch_buffers`)
+    launches: int = 0
+    launch_ns: int = 0
+    launch_buffers: int = 0
 
 
 class PhysicalOp:

@@ -377,8 +377,7 @@ class FusedAggregateExec(PhysicalOp):
                 return None
         return tuple(plan)
 
-    def _execute_grouped_carry(self, specs, plan,
-                               span: str = "group_dispatch"):
+    def _execute_grouped_carry(self, specs, plan):
         """Stream a KEYED aggregate through a persistent device carry:
         ONE dispatch per input batch, the grouped state re-merged
         in-kernel into a fixed set of carry slots instead of being
@@ -423,7 +422,7 @@ class FusedAggregateExec(PhysicalOp):
                         self._build_grouped_carry_kernel(
                             b, plan, s, c, None, None
                         ),
-                    scatter_class=True, span=span,
+                    scatter_class=True,
                 )
                 (n_dev, outs), packed = fn(args)
                 if nxt is None:
@@ -433,8 +432,7 @@ class FusedAggregateExec(PhysicalOp):
                     if n > s_b:
                         # overflow: rare re-dispatch under the ladder
                         out, _ = self._run_agg(
-                            key_suffix, build_fn, args, cap, True,
-                            span=span,
+                            key_suffix, build_fn, args, cap, True
                         )
                     if out is not None:
                         yield out
@@ -450,7 +448,7 @@ class FusedAggregateExec(PhysicalOp):
                         self._build_grouped_carry_kernel(
                             b, plan, s, c, slots, st
                         ),
-                    scatter_class=True, span=span,
+                    scatter_class=True,
                 )
                 (n_dev, outs), packed = fn(args, carry)
             # batch-level overflow already rides in n (the kernel
@@ -483,11 +481,11 @@ class FusedAggregateExec(PhysicalOp):
             ]
             yield ColumnBatch(self._schema, cols, carry_n)
             first = False
-        out, first = self._run_agg(*demote, first, span=span)
+        out, first = self._run_agg(*demote, first)
         if out is not None:
             yield out
         for spec in it:
-            out, first = self._run_agg(*spec, first, span=span)
+            out, first = self._run_agg(*spec, first)
             if out is not None:
                 yield out
 
@@ -713,14 +711,10 @@ class FusedAggregateExec(PhysicalOp):
             )
             plan = self._grouped_carry_plan()
             if plan is not None:
-                yield from self._execute_grouped_carry(
-                    specs, plan, span="join_dispatch"
-                )
+                yield from self._execute_grouped_carry(specs, plan)
                 return
             for spec in specs:
-                out, first = self._run_agg(
-                    *spec, first, span="join_dispatch"
-                )
+                out, first = self._run_agg(*spec, first)
                 if out is not None:
                     yield out
             return
@@ -778,11 +772,10 @@ class FusedAggregateExec(PhysicalOp):
              else pb.num_rows),
             p_layout[0],
             first,
-            span="join_dispatch",
         )
 
     def _run_agg(self, key_suffix, build_kernel, args, cap: int,
-                 first: bool, span: str = "group_dispatch"):
+                 first: bool):
         """Shared per-batch aggregate dispatch: run under the retry
         ladder, fetch per the host-finalize policy, wrap the output.
         Returns (ColumnBatch | None, first)."""
@@ -858,7 +851,7 @@ class FusedAggregateExec(PhysicalOp):
             gcap = None
         host_outs, n = run_grouped_kernel(
             base_key, build_kernel, args, fetch, gcap,
-            scatter_class=scatter, span=span,
+            scatter_class=scatter,
         )
         if self.fetch_host and first and n > 0:
             first = False

@@ -142,8 +142,7 @@ def _group_tiers(gcap) -> list:
 
 
 def run_grouped_kernel(base_key, build, args, fetch_n, gcap,
-                       scatter_class: bool = False,
-                       span: str = "group_dispatch"):
+                       scatter_class: bool = False):
     """Dispatch a grouped-aggregate kernel for HashAggregateExec and
     FusedAggregateExec, and hand its states on at the smallest tier
     (_group_tiers) that holds the group count: most aggregates resolve
@@ -175,10 +174,9 @@ def run_grouped_kernel(base_key, build, args, fetch_n, gcap,
     core (_scatter_core_hint); it picks the path here and rides
     through to cached_kernel. A wrong guess costs runtime choice and
     launches, never correctness: either path is right for either core.
-    `span` names the obs span so phases.py can band group/join
-    dispatches separately. A keyed aggregate leaves two counts in its
-    task's metrics (POLL): `agg_tier_retries`, programs launched again
-    because the count outgrew a tier, 0 on the sort core; and
+    A keyed aggregate leaves two counts in its task's metrics (POLL):
+    `agg_tier_retries`, programs launched again because the count
+    outgrew a tier, 0 on the sort core; and
     `agg_running_sum_launches`, programs launched whose integer sums
     are read off a running sum and not scattered (_rows_by_group, the
     rule `_SegOps.sum` follows), 0 on the scatter core."""
@@ -189,15 +187,15 @@ def run_grouped_kernel(base_key, build, args, fetch_n, gcap,
         _count("agg_running_sum_launches", 0)
     if _rows_by_group(keyless, scatter_class):
         host_outs, n = _cut_tiers(
-            base_key, build, args, fetch_n, tiers, False, span
+            base_key, build, args, fetch_n, tiers, False
         )
     else:
         host_outs, n = _climb_tiers(
-            base_key, build, args, fetch_n, tiers, scatter_class, span
+            base_key, build, args, fetch_n, tiers, scatter_class
         )
     if n < 0:
         host_outs, n = _cut_tiers(
-            base_key, build, args, fetch_n, tiers, True, span
+            base_key, build, args, fetch_n, tiers, True
         )
     return host_outs, n
 
@@ -208,8 +206,7 @@ def _count(name: str, k: int) -> None:
         task.metrics.add(name, k)
 
 
-def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class,
-                 span):
+def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class):
     """One program a tier, smallest first, until the count fits (the
     collision sentinel -1 leaves at once, for the caller to see)."""
     for gc in tiers:
@@ -217,7 +214,6 @@ def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class,
             base_key + (False, gc),
             lambda g=gc: build(False, g),
             scatter_class=scatter_class,
-            span=span,
         )
         host_outs, n = fetch_n(*fn(*args))
         if gc is None or n <= gc:
@@ -225,7 +221,7 @@ def _climb_tiers(base_key, build, args, fetch_n, tiers, scatter_class,
         _count("agg_tier_retries", 1)
 
 
-def _cut_tiers(base_key, build, args, fetch_n, tiers, force_lex, span):
+def _cut_tiers(base_key, build, args, fetch_n, tiers, force_lex):
     """One program at the input's capacity, its states returned whole
     and cut to each tier; fetch the first cut with the count, then, if
     the count outgrew it, the smallest cut that holds it. On the sort
@@ -237,7 +233,6 @@ def _cut_tiers(base_key, build, args, fetch_n, tiers, force_lex, span):
     fn = cached_kernel(
         base_key + (force_lex, cuts),
         lambda: _with_cuts(build(force_lex, None), cuts),
-        span=span,
     )
     by_cut, n_groups = fn(*args)
     # a cut program is keyed and on the sort core, forced lexsort or not

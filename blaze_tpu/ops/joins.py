@@ -263,8 +263,7 @@ class _JoinCore:
                     return kernel
 
                 span_fn = cached_kernel(
-                    ("join_keyspan", eq_layout, cap), build_span,
-                    span="join_dispatch",
+                    ("join_keyspan", eq_layout, cap), build_span
                 )
                 kmin, kmax = (
                     int(x) for x in np.asarray(
@@ -301,7 +300,7 @@ class _JoinCore:
                     dfn = cached_kernel(
                         ("join_table_direct", eq_layout, cap, tsize_d),
                         build_direct,
-                        scatter_class=True, span="join_dispatch",
+                        scatter_class=True,
                     )
                     base = jnp.asarray(kmin, jnp.int64)
                     tab, dup = dfn(
@@ -348,7 +347,7 @@ class _JoinCore:
 
             fn = cached_kernel(
                 ("join_table", eq_layout, cap, tsize, kr), build_table,
-                scatter_class=True, span="join_dispatch",
+                scatter_class=True,
             )
             tab, dup = fn(
                 _flatten_cols(build_cols),
@@ -385,9 +384,7 @@ class _JoinCore:
 
             return kernel
 
-        fn = cached_kernel(
-            ("join_index", dtypes, cap), build, span="join_dispatch"
-        )
+        fn = cached_kernel(("join_index", dtypes, cap), build)
         h_sorted, order = fn(
             tuple(v for v, _, _ in bufs), tuple(m for _, m, _ in bufs),
             self.build.num_rows,
@@ -556,7 +553,6 @@ class _JoinCore:
                 ("join_lookup", mode, b_eq_layout, p_eq_layout, bcap,
                  pcap),
                 build_lookup,
-                span="join_dispatch",
             )
             match_idx, matched = fn(
                 _flatten_cols(unified_b),
@@ -604,10 +600,7 @@ class _JoinCore:
 
             return kernel
 
-        fn = cached_kernel(
-            ("join_counts", pdtypes, pcap), build_counts,
-            span="join_dispatch",
-        )
+        fn = cached_kernel(("join_counts", pdtypes, pcap), build_counts)
         counts, lo, total_dev = fn(
             tuple(v for v, _, _ in pbufs),
             tuple(m for _, m, _ in pbufs),
@@ -720,7 +713,7 @@ class _JoinCore:
             ("join_emit", k_layout, b_layout, p_layout, bcap, pcap,
              pair_cap, n_b, n_p),
             build_emit,
-            scatter_class=True, span="join_dispatch",
+            scatter_class=True,
         )
         bkey_bufs = tuple(b2.values for b2 in unified_b)
         pkey_bufs = tuple(
@@ -787,7 +780,7 @@ class _JoinCore:
             ("join_emit_table", b_layout, bcap, pcap,
              len(out_build_cols)),
             build_emit,
-            scatter_class=True, span="join_dispatch",
+            scatter_class=True,
         )
         bout, valid, mb = fn(
             match_idx, matched, _flatten_cols(out_build_cols),

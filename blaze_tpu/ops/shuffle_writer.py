@@ -64,7 +64,7 @@ from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.host_lower import lower_strings_host
 from blaze_tpu.ops.project import _unflatten_cvs
 from blaze_tpu.ops.util import ensure_compacted, take_batch
-from blaze_tpu.runtime.dispatch import cached_kernel, current_task
+from blaze_tpu.runtime.dispatch import cached_kernel, current_task, launch
 from blaze_tpu.runtime import native
 from blaze_tpu.runtime.memory import get_pool
 
@@ -294,7 +294,7 @@ def spark_partition_ids(cb: ColumnBatch, key_exprs: Sequence[ir.Expr],
                 if tid in ("int32", "date32")
                 else mp.partition_ids_int64
             )
-            pids = fn(col.values, num_partitions)
+            pids = launch(fn, col.values, num_partitions)
             task = current_task()
             if task is not None:
                 # POLL's `shuffle_pallas_batches`

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from blaze_tpu.config import get_config
 from blaze_tpu.obs import trace as obs_trace
+from blaze_tpu.runtime.dispatch import launch
 from blaze_tpu.types import Schema, TypeId
 from blaze_tpu.batch import Column, ColumnBatch, row_mask
 
@@ -33,7 +34,7 @@ def take_batch(cb: ColumnBatch, indices: jax.Array, num_rows: int
         bufs.append(c.values)
         if c.validity is not None:
             bufs.append(c.validity)
-    taken = _take_many(bufs, indices)
+    taken = launch(_take_many, bufs, indices)
     cols = []
     for c, (i, has_m) in zip(cb.columns, slots):
         cols.append(
@@ -68,7 +69,7 @@ def _compact(cb: ColumnBatch, mask: Optional[jax.Array]) -> ColumnBatch:
     live = cb.live_mask()
     if mask is not None:
         live = live & mask
-    idx, n = _compact_indices(live, cb.capacity)
+    idx, n = launch(_compact_indices, live, cb.capacity)
     return take_batch(cb, idx, int(n))
 
 
@@ -190,8 +191,8 @@ def concat_batches(batches: List[ColumnBatch],
     lengths = jnp.asarray(
         np.array([b.num_rows for b in batches], dtype=np.int32)
     )
-    vs, ms = _concat_many(
-        values_in, masks_in, lengths, cap, tuple(any_mask)
+    vs, ms = launch(
+        _concat_many, values_in, masks_in, lengths, cap, tuple(any_mask)
     )
     cols: List[Column] = []
     for ci in range(ncols):

@@ -362,8 +362,8 @@ class _TracedProgram:
     def __call__(self, *args):
         sig = meshprof.arg_signature(*args)
         if self._exec is not None and self._exec_sig == sig:
-            return self._exec(*args)
-        return self._fn(*args)
+            return dispatch.launch(self._exec, *args)
+        return dispatch.launch(self._fn, *args)
 
 
 class MeshPipelineExec(PhysicalOp):

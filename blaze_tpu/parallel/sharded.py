@@ -38,6 +38,7 @@ from blaze_tpu.exprs.ir import AggFn
 from blaze_tpu.exprs.typing import infer_dtype
 from blaze_tpu.ops.running import running_scan as _running
 from blaze_tpu.parallel.repartition import _bucket_live, _bucketize
+from blaze_tpu.runtime.dispatch import launch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -228,9 +229,10 @@ class DistributedGroupBy:
         args = self._args(stacked_cols, rows, valids)
         if self._fn is None:
             self._fn = self._compile()
+        fn = self._fn
         if self._exec is not None and self._exec_sig == self.signature(*args):
-            return GroupByResult(*self._exec(*args))
-        return GroupByResult(*self._fn(*args))
+            fn = self._exec
+        return GroupByResult(*launch(fn, *args))
 
     def __call__(self, stacked_cols: Sequence[jax.Array],
                  num_rows: jax.Array):
@@ -455,12 +457,11 @@ class DistributedBroadcastJoin:
         gathered build cols) all stacked [n_dev, cap_probe]."""
         if self._fn is None:
             self._fn = self._compile()
+        fn = self._fn
         if (self._exec is not None and self._exec_sig == self._sig(
                 probe_cols, probe_rows, build_cols, build_rows)):
-            return self._exec(
-                probe_cols, probe_rows, build_cols, build_rows
-            )
-        return self._fn(probe_cols, probe_rows, build_cols, build_rows)
+            fn = self._exec
+        return launch(fn, probe_cols, probe_rows, build_cols, build_rows)
 
     def _compile(self):
         mesh, axis = self.mesh, self.axis
@@ -602,10 +603,11 @@ class DistributedRepartition:
         stacks, live the matching row mask."""
         if self._fn is None:
             self._fn = self._compile()
+        fn = self._fn
         if (self._exec is not None
                 and self._exec_sig == self._sig(stacked_cols, num_rows)):
-            return self._exec(*stacked_cols, num_rows)
-        return self._fn(*stacked_cols, num_rows)
+            fn = self._exec
+        return launch(fn, *stacked_cols, num_rows)
 
     def _compile(self):
         mesh, axis = self.mesh, self.axis

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from typing import Any, Callable, Dict, Tuple
 
 import jax
@@ -93,9 +94,10 @@ def record(kind: str, n: int = 1) -> None:
 
 # the task this thread launches for (`.ctx`, an ExecContext): the
 # executor's scope around each pull of a partition's stream, handed on
-# to prefetch workers. `_wrap_dispatch` counts each launch on it
-# (`task_dispatches`, POLL), whatever else runs in the process and
-# whether or not tracing is on
+# to prefetch workers. `_wrap_dispatch` counts each cached kernel's
+# launch on it (`task_dispatches`, POLL) and `_launch` every program's
+# (`launches`), whatever else runs in the process and whether or not
+# tracing is on
 _task = threading.local()
 
 
@@ -155,9 +157,33 @@ class counting:
         return False
 
 
-def _wrap_dispatch(fn: Callable, kind: str,
-                   span: str = "kernel_dispatch") -> Callable:
-    from blaze_tpu.obs import trace as obs_trace
+def _launch(ctx, fn: Callable, args, kw):
+    """Call a compiled program, and where this thread launches for a
+    task, count the launch on it: `launches`, the thread's wall time in
+    the call (`launch_ns`) and the arrays it handed back
+    (`launch_buffers`). The call is where a launch costs its thread,
+    about 0.2 ms plus 40 us an output buffer on a v5e (ROADMAP S6);
+    dispatch is async, so this is not the device's time."""
+    if ctx is None:
+        return fn(*args, **kw)
+    t0 = time.perf_counter_ns()
+    out = fn(*args, **kw)
+    dt = time.perf_counter_ns() - t0
+    n = len(jax.tree_util.tree_leaves(out))
+    with _lock:  # the prefetch worker launches for its consumer's task
+        ctx.launches += 1
+        ctx.launch_ns += dt
+        ctx.launch_buffers += n
+    return out
+
+
+def launch(fn: Callable, *args, **kw):
+    """`fn(*args, **kw)` for a plain `jax.jit` entry point on a served
+    path, counted on this thread's task as `cached_kernel`'s are."""
+    return _launch(getattr(_task, "ctx", None), fn, args, kw)
+
+
+def _wrap_dispatch(fn: Callable, kind: str) -> Callable:
     from blaze_tpu.testing import chaos
 
     def wrapped(*args, **kw):
@@ -171,23 +197,13 @@ def _wrap_dispatch(fn: Callable, kind: str,
             _counts[kind] = _counts.get(kind, 0) + 1
             if ctx is not None:
                 ctx.task_dispatches += 1
-        if obs_trace.ACTIVE:
-            # obs seam: one span per kernel dispatch (the unit of the
-            # perf model); no-op when no recorder is in scope. XLA
-            # dispatch is async, so this measures launch, not device
-            # occupancy - the span COUNT is the signal. `span` gives
-            # relational-core kernels (join/group) their own phase
-            # attribution in obs/phases.py.
-            with obs_trace.span(span, kind=kind):
-                return fn(*args, **kw)
-        return fn(*args, **kw)
+        return _launch(ctx, fn, args, kw)
 
     return wrapped
 
 
 def cached_kernel(key: Tuple, build: Callable[[], Callable],
                   scatter_class: bool = False,
-                  span: str = "kernel_dispatch",
                   **jit_kwargs) -> Callable:
     """Process-wide compiled-kernel lookup.
 
@@ -197,8 +213,7 @@ def cached_kernel(key: Tuple, build: Callable[[], Callable],
 
     `scatter_class=True` marks a scatter-dominated kernel: on the CPU
     backend it compiles under the legacy (non-thunk) XLA:CPU runtime
-    (see _scatter_jit_kwargs). `span` names the obs trace span so
-    phases.py can band join/group dispatches separately."""
+    (see _scatter_jit_kwargs)."""
     with _lock:
         fn = _KERNELS.get(key)
         if fn is not None:
@@ -218,8 +233,7 @@ def cached_kernel(key: Tuple, build: Callable[[], Callable],
                     _counts.get("kernel_builds", 0) + 1
                 )
                 fn = _wrap_dispatch(
-                    jax.jit(build(), **jit_kwargs), "dispatches",
-                    span=span,
+                    jax.jit(build(), **jit_kwargs), "dispatches"
                 )
                 _KERNELS[key] = fn
                 while len(_KERNELS) > _KERNEL_CACHE_CAP:

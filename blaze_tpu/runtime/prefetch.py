@@ -15,6 +15,7 @@ import queue
 import threading
 from typing import Iterator, TypeVar
 
+from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.runtime import dispatch
 
 T = TypeVar("T")
@@ -22,13 +23,32 @@ T = TypeVar("T")
 _SENTINEL = object()
 
 
+def _wait(name: str, rec):
+    """The span of a call that is about to block (obs/trace.py
+    WAIT_SPANS), on the task's own recorder."""
+    if obs_trace.ACTIVE and rec is not None:
+        return obs_trace.span(name, rec=rec)
+    return obs_trace.NULL
+
+
 def prefetch(it: Iterator[T], depth: int = 2) -> Iterator[T]:
     """Run `it` on a background thread with `depth` items of lookahead.
     Exceptions propagate to the consumer at the point of consumption;
-    early consumer exit stops the producer."""
+    early consumer exit stops the producer. A call that blocks is a
+    wait span: `wait_batch` where the consumer waits for the producer,
+    `wait_room` where the producer waits for the consumer. A call that
+    does not block reads no clock."""
     q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
     task = dispatch.current_task()  # the consumer's: the worker's too
+    rec = getattr(task, "tracer", None)
+
+    def put(item):
+        try:
+            q.put_nowait(item)
+        except queue.Full:
+            with _wait("wait_room", rec):
+                q.put(item)
 
     def worker():
         try:
@@ -36,8 +56,8 @@ def prefetch(it: Iterator[T], depth: int = 2) -> Iterator[T]:
                 for item in it:
                     if stop.is_set():
                         return
-                    q.put(item)
-            q.put(_SENTINEL)
+                    put(item)
+            put(_SENTINEL)
         except BaseException as e:  # noqa: BLE001 - forwarded to consumer
             q.put(e)
 
@@ -45,7 +65,11 @@ def prefetch(it: Iterator[T], depth: int = 2) -> Iterator[T]:
     t.start()
     try:
         while True:
-            item = q.get()
+            try:
+                item = q.get_nowait()
+            except queue.Empty:
+                with _wait("wait_batch", rec):
+                    item = q.get()
             if item is _SENTINEL:
                 return
             if isinstance(item, BaseException):

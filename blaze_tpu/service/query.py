@@ -385,6 +385,12 @@ class Query:
         # this task's launches alone; `dispatches` above is a delta of
         # the process's counters and takes in its neighbours'
         out["task_dispatches"] = self.ctx.task_dispatches
+        # every program launch of this task, the launching threads'
+        # wall time in the calls and the arrays handed back
+        # (runtime/dispatch.py: _launch)
+        out["launches"] = self.ctx.launches
+        out["launch_s"] = round(self.ctx.launch_ns / 1e9, 6)
+        out["launch_buffers"] = self.ctx.launch_buffers
         if "shuffle_segments_written" in m:
             # parts a shuffle write encoded (ops/shuffle_writer.py)
             out["shuffle_segments"] = m["shuffle_segments_written"]
@@ -431,12 +437,20 @@ class Query:
         if self.tracer is not None and self.state in TERMINAL_STATES:
             # per-task stage table, folded from the task's own spans:
             # {stage: {wall_s, cpu_s, n}}. A POLL after FETCH carries
-            # the wire's stages (frame_encode, frame_send) too
-            from blaze_tpu.obs.phases import STAGE_PHASE
+            # the wire's stages (frame_encode, frame_send) too. Beside
+            # it the waits at the scan's prefetch queue, {wait: {wall_s,
+            # n}}, both named, 0 where no call blocked
+            from blaze_tpu.obs.phases import POLL_PHASE
+            from blaze_tpu.obs.trace import WAIT_SPANS
 
-            out["stages"] = self.tracer.phase_totals(
-                STAGE_PHASE, stage_table=True
-            )
+            stages = self.tracer.phase_totals(POLL_PHASE, stage_table=True)
+            waits = {}
+            for name in sorted(WAIT_SPANS):
+                w = stages.pop(name, None)
+                waits[name] = ({"wall_s": w["wall_s"], "n": w["n"]} if w
+                               else {"wall_s": 0.0, "n": 0})
+            out["stages"] = stages
+            out["waits"] = waits
         if self._fingerprint is not None and self._fingerprint_stable:
             # stable content fingerprint: the affinity key replica
             # routing and the runtime-history store share

@@ -137,7 +137,7 @@ def test_span_cap_degrades_to_null_spans():
 def test_attach_subtree_stitches_remote_spans():
     worker = trace.TraceRecorder("task-1", root_name="worker_task")
     with trace.span("execute", rec=worker):
-        with trace.span("kernel_dispatch"):
+        with trace.span("h2d"):
             pass
     worker.finish(state="DONE")
     dicts = worker.to_dicts()
@@ -710,7 +710,7 @@ def test_stage_annotation_is_jax_profilers():
 
 @pytest.mark.parametrize(
     "name", ["execute_partition", "attempt", "execute",
-             "kernel_dispatch", "parquet_decode", "record_span"])
+             "plan_decode", "parquet_decode", "record_span"])
 def test_other_spans_stay_off_the_profilers_side(name, annotations):
     rec = trace.TraceRecorder("t")
     if name == "record_span":

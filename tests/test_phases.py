@@ -79,7 +79,7 @@ def test_fold_span_dicts_sums_per_phase():
     spans = [
         {"name": "parquet_decode", "start_ns": 0, "end_ns": 10_000_000},
         {"name": "parquet_decode", "start_ns": 0, "end_ns": 5_000_000},
-        {"name": "kernel_dispatch", "start_ns": 0, "end_ns": 2_000_000},
+        {"name": "h2d", "start_ns": 0, "end_ns": 2_000_000},
         {"name": "attempt", "start_ns": 0, "end_ns": 9_000_000},  # structure
         {"name": "router_stream", "start_ns": 0, "end_ns": 9},  # passthrough
         {"name": "parquet_decode", "start_ns": 5, "end_ns": None},  # open
@@ -87,7 +87,7 @@ def test_fold_span_dicts_sums_per_phase():
     out = fold_span_dicts(spans)
     assert out == {
         "arrow_decode": pytest.approx(0.015),
-        "dispatch": pytest.approx(0.002),
+        "h2d": pytest.approx(0.002),
     }
 
 
@@ -162,9 +162,10 @@ def test_terminal_hook_folds_phases_into_global_rollup(agg_blob):
             assert q.wait(60.0) and q.state.value == "DONE"
         snap = phases.ROLLUP.snapshot()
         assert snap[ALL_CLASS]["e2e"]["n"] == 3
-        # the keyed aggregate's kernel launches land in the fused
-        # grouped-dispatch phase, not the generic dispatch bucket
-        for ph in ("queue_wait", "execute", "arrow_decode", "group"):
+        # the keyed aggregate's launches land in `dispatch`, from the
+        # task's launch counter (the per-launch span and the `group`
+        # phase are gone)
+        for ph in ("queue_wait", "execute", "arrow_decode", "dispatch"):
             assert ph in snap[ALL_CLASS], snap[ALL_CLASS].keys()
         # the fingerprint class rode along (stable plan)
         fp_classes = [k for k in snap if k not in (ALL_CLASS,)]
@@ -414,7 +415,7 @@ def test_phase_totals_matches_fold_span_dicts():
     rec.record_span("queue_wait", t0, t0 + 0.010)
     rec.record_span("parquet_decode", t0, t0 + 0.020)
     rec.record_span("parquet_decode", t0 + 0.020, t0 + 0.050)
-    rec.record_span("kernel_dispatch", t0, t0 + 0.001)
+    rec.record_span("plan_decode", t0, t0 + 0.001)
     rec.record_span("attempt", t0, t0 + 0.5)  # structural: unmapped
     unfinished = rec.begin("h2d")  # open span: excluded by both
     assert unfinished is not None
