@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from blaze_tpu.config import get_config
 from blaze_tpu.obs import trace as obs_trace
-from blaze_tpu.runtime.dispatch import launch
+from blaze_tpu.runtime.dispatch import current_task, launch
 from blaze_tpu.types import Schema, TypeId
 from blaze_tpu.batch import Column, ColumnBatch, row_mask
 
@@ -156,7 +156,9 @@ def unify_dictionaries(batches: List[ColumnBatch]) -> List[ColumnBatch]:
 def concat_batches(batches: List[ColumnBatch],
                    schema: Optional[Schema] = None) -> ColumnBatch:
     """Concatenate live rows of many batches into one padded batch
-    (pipeline-breaker materialization). Unifies string dictionaries."""
+    (pipeline-breaker materialization). Unifies string dictionaries.
+    Leaves `concat_slice_parts`, the parts written by the launch, in the
+    task's metrics (POLL)."""
     batches = [ensure_compacted(b) for b in batches]
     batches = [b for b in batches if b.num_rows > 0]
     if not batches:
@@ -178,14 +180,7 @@ def concat_batches(batches: List[ColumnBatch],
     values_in = [[b.columns[ci].values for b in batches]
                  for ci in range(ncols)]
     masks_in = [
-        [
-            b.columns[ci].validity
-            if b.columns[ci].validity is not None
-            else None
-            for b in batches
-        ]
-        if any_mask[ci]
-        else None
+        [b.columns[ci].validity for b in batches] if any_mask[ci] else None
         for ci in range(ncols)
     ]
     lengths = jnp.asarray(
@@ -194,6 +189,9 @@ def concat_batches(batches: List[ColumnBatch],
     vs, ms = launch(
         _concat_many, values_in, masks_in, lengths, cap, tuple(any_mask)
     )
+    task = current_task()
+    if task is not None:
+        task.metrics.add("concat_slice_parts", len(batches))
     cols: List[Column] = []
     for ci in range(ncols):
         ref = batches[0].columns[ci]
@@ -210,35 +208,43 @@ def _concat_many(values_in, masks_in, lengths, cap: int, any_mask):
 
     Row counts (`lengths`) stay TRACED: a filter upstream makes them
     data-dependent, and baking them in statically would recompile this
-    program for every distinct combination. Instead each part scatters its
-    live rows to a dynamic offset (dead/pad rows land in a dump slot), so
-    one compile covers every batch mix with the same shapes/layout."""
+    program for every distinct combination. Every part is compacted
+    (concat_batches), so its live rows are its first `lengths[i]`: each
+    part is written WHOLE at its traced offset, in part order, and the
+    next part overwrites its padding - no index array and no scatter.
+    The output holds `room` rows past `cap`, the largest part: XLA
+    clamps a slice's start so the update fits, and without the room the
+    last parts would land at a clamped, wrong offset. Rows at and past
+    the total read 0 and invalid. One compile covers every row-count
+    mix with the same shapes/layout."""
     offsets = jnp.concatenate(
         [jnp.zeros(1, dtype=jnp.int32),
          jnp.cumsum(lengths)[:-1].astype(jnp.int32)]
     )
+    live = jnp.arange(cap, dtype=jnp.int32) < jnp.sum(lengths)
+
+    def place(parts, shape, dtype):
+        room = max(p.shape[0] for p in parts)
+        out = jnp.zeros((cap + room,) + shape, dtype=dtype)
+        for i, p in enumerate(parts):
+            out = jax.lax.dynamic_update_slice_in_dim(out, p, offsets[i], 0)
+        keep = live.reshape((cap,) + (1,) * len(shape))
+        return jnp.where(keep, out[:cap], jnp.zeros((), dtype))
+
     vs = []
     ms = []
     for ci, parts in enumerate(values_in):
         # trailing dims (e.g. wide-decimal limb pairs) ride along
-        out = jnp.zeros(
-            (cap + 1,) + parts[0].shape[1:], dtype=parts[0].dtype
-        )
-        mout = jnp.zeros(cap + 1, dtype=jnp.bool_)
-        for i, p in enumerate(parts):
-            pos = jnp.arange(p.shape[0], dtype=jnp.int32)
-            keep = pos < lengths[i]
-            tgt = jnp.where(keep, offsets[i] + pos, cap)
-            out = out.at[tgt].set(p, mode="drop")
-            if any_mask[ci]:
-                mp = masks_in[ci][i]
-                mv = (
-                    mp if mp is not None
-                    else jnp.ones(p.shape[0], dtype=jnp.bool_)
-                )
-                mout = mout.at[tgt].set(mv, mode="drop")
-        vs.append(out[:cap])
-        ms.append(mout[:cap] if any_mask[ci] else None)
+        vs.append(place(parts, parts[0].shape[1:], parts[0].dtype))
+        if any_mask[ci]:
+            mparts = [
+                mp if mp is not None
+                else jnp.ones(p.shape[0], dtype=jnp.bool_)
+                for p, mp in zip(parts, masks_in[ci])
+            ]
+            ms.append(place(mparts, (), jnp.bool_))
+        else:
+            ms.append(None)
     return vs, ms
 
 

@@ -422,6 +422,10 @@ class Query:
             out["agg_running_sum_launches"] = (
                 m["agg_running_sum_launches"]
             )
+        if "concat_slice_parts" in m:
+            # parts a materialization wrote whole at their offsets, no
+            # scatter (ops/util.py: concat_batches)
+            out["concat_slice_parts"] = m["concat_slice_parts"]
         if "mesh_group_runs" in m:
             # a task whose plan was lowered onto the mesh group-by
             # (parallel/mesh_ops.py): mesh programs that produced its

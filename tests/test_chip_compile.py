@@ -316,6 +316,34 @@ def test_q1_batch_grouping_program(one_chip, returns_parquet, amount,
     assert text.count(" scatter(") == scatters
 
 
+def test_q1_merge_concat(one_chip):
+    """The FINAL merge's materialization in `q1_group.s4`: 64 per-batch
+    partial states of 16,384 rows into the 737,280-row bucket
+    (`ops/util.py: _concat_many`), two nullable `int` keys with validity
+    on some parts, `i64` sums. Each part is written whole at its offset,
+    so it holds no scatter: in the scatter form the 256 scatters of
+    737,281 rows were 0.331 s of the task's 1.109 s on a TPU v5e
+    (PERF.md, section 5)."""
+    from blaze_tpu.ops.util import _concat_many
+
+    n_parts, rows, cap = 64, 16384, 737280
+
+    def part(dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=one_chip)
+
+    values = [[part(dt) for _ in range(n_parts)]
+              for dt in (jnp.int32, jnp.int32, jnp.int64, jnp.int64)]
+    masks = [[part(jnp.bool_) if i % 2 else None for i in range(n_parts)],
+             [part(jnp.bool_) for _ in range(n_parts)], None, None]
+    lengths = jax.ShapeDtypeStruct((n_parts,), jnp.int32,
+                                   sharding=one_chip)
+    text = _concat_many.lower(
+        values, masks, lengths, cap=cap, any_mask=(True, True, False, False)
+    ).compile().as_text()
+    assert " dynamic-update-slice(" in text
+    assert " scatter(" not in text
+
+
 def _shuffle_batch(table):
     """One 16,384-row batch shaped as the benchmark's shuffle cells
     scan it (`store_sales`: 23 columns, nullable `int` keys, decimal(7,2)

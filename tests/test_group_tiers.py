@@ -352,6 +352,9 @@ def test_poll_of_a_keyed_aggregate_carries_the_count(core, retries,
     # two per-batch programs and the merge read their integer sums off a
     # running sum on the sort core; the scatter core scatters them
     assert poll["agg_running_sum_launches"] == running
+    # the merge's one concatenation of the two partial states, written
+    # whole at their offsets on either core (ops/util.py: _concat_many)
+    assert poll["concat_slice_parts"] == 2
 
 
 def test_poll_without_a_keyed_aggregate_has_no_count(client, tmp_path):
@@ -374,3 +377,4 @@ def test_poll_without_a_keyed_aggregate_has_no_count(client, tmp_path):
         assert poll["state"] == "DONE" and poll["task_dispatches"] > 0
         assert "agg_tier_retries" not in poll
         assert "agg_running_sum_launches" not in poll
+        assert "concat_slice_parts" not in poll
