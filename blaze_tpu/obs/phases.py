@@ -100,6 +100,7 @@ SPAN_PHASE = {
     "compact": "compact",
     "d2h": "d2h",
     "agg_fetch": "agg_fetch",
+    "join_build": "join_build",
     "shuffle_partition": "shuffle_partition",
     "shuffle_encode": "shuffle_encode",
     "shuffle_finalize": "shuffle_finalize",

@@ -82,7 +82,7 @@ LIFECYCLE_TID = 0
 # range's parquet_decode) and the lifecycle spans `record_span` writes
 # after the fact.
 STAGE_SPANS = frozenset({
-    "decode_batch", "h2d", "compact", "d2h", "agg_fetch",
+    "decode_batch", "h2d", "compact", "d2h", "agg_fetch", "join_build",
     "shuffle_partition", "shuffle_encode", "shuffle_finalize",
     "frame_encode", "frame_send",
     "mesh_stage_in", "mesh_sync", "mesh_gather",

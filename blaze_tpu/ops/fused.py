@@ -49,6 +49,7 @@ from blaze_tpu.ops.filter import FilterExec
 from blaze_tpu.ops.project import ProjectExec, _unflatten_cvs
 from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.runtime.dispatch import cached_kernel
+from blaze_tpu.runtime.dispatch import count as _count
 
 
 def _expr_needs_host(e: ir.Expr, schema: Schema) -> bool:
@@ -623,22 +624,13 @@ class FusedAggregateExec(PhysicalOp):
 
     def _execute_join_fused(self, join, partition: int,
                             ctx: ExecContext):
-        from blaze_tpu.ops.joins import (
-            _JoinCore,
-            _eq_layout,
-            _flatten_cols,
-        )
+        from blaze_tpu.ops.joins import _eq_layout, _flatten_cols
 
-        build = join._collect_build(ctx)
         # the build INDEX is as probe-invariant as the build relation
         # itself: share one core across partitions/executions (the
         # reference equivalently caches broadcast build relations) so
         # repeated probes don't re-pay the insert + blocking dup sync
-        with join._build_lock:
-            core = getattr(join, "_fused_core", None)
-            if core is None or core.build is not build:
-                core = _JoinCore(build, join.left_keys)
-                join._fused_core = core
+        build, core = join.build_side(ctx, shared=True)
         first = True
         fused_probe = getattr(join, "_fused_probe", None)
         folded = None
@@ -663,6 +655,7 @@ class FusedAggregateExec(PhysicalOp):
             pkey_idx = tuple(join.right_keys)
 
             def probe_spec(raw):
+                _count("join_probe_batches", 1)
                 pv = packed_view(raw)
                 if pv is not None:
                     # still-packed wire batch: the H2D buffer split
@@ -729,6 +722,7 @@ class FusedAggregateExec(PhysicalOp):
         pair-emission fallback. Returns (ColumnBatch | None, first)."""
         from blaze_tpu.ops.joins import _eq_layout, _flatten_cols
 
+        _count("join_probe_batches", 1)
         tstate, pb = core.table_state(pb, join.right_keys)
         if tstate is None:
             # duplicate build keys / sort core: fall back to the

@@ -41,6 +41,7 @@ from blaze_tpu.types import DataType, Field, Schema, TypeId
 from blaze_tpu.batch import Column, ColumnBatch, row_mask
 from blaze_tpu.exprs import ir
 from blaze_tpu.exprs.hashing import hash_columns_device
+from blaze_tpu.obs import trace as obs_trace
 from blaze_tpu.ops.base import ExecContext, PhysicalOp
 from blaze_tpu.ops.util import (
     compact,
@@ -49,6 +50,7 @@ from blaze_tpu.ops.util import (
     take_batch,
 )
 from blaze_tpu.runtime.dispatch import cached_kernel, host_int
+from blaze_tpu.runtime.dispatch import count as _count
 
 
 class JoinType(enum.Enum):
@@ -166,7 +168,10 @@ class _JoinCore:
     Either way the dispatch budget per probe batch is O(1) kernels
     (the per-dispatch cost model of runtime/dispatch.py) - instead of the ~20
     eager ops a naive translation of the reference's cursor loop
-    would dispatch."""
+    would dispatch. Whichever core runs, a probe batch's programs are
+    named `join_probe` (the lookup, or the counting kernel) and
+    `join_emit` on the device (`jit_join_probe`, `jit_join_emit` on a
+    profiler trace's module line), the build side's `join_index`."""
 
     def __init__(self, build: ColumnBatch, build_keys: List[int]):
         import threading
@@ -188,6 +193,16 @@ class _JoinCore:
         # kr -> generic downgrade (probe key wider than the 32-bit kr
         # encoding); remembered for the same reason
         self._force_generic = False
+
+    def index_build(self) -> None:
+        """Build the index before the first probe batch, where it does
+        not depend on the probe (no build key is dictionary-encoded:
+        those are re-coded against each probe batch's dictionary)."""
+        cols = [self.build.columns[i] for i in self.build_keys]
+        if any(c.dtype.is_dictionary_encoded for c in cols):
+            return
+        with self._index_lock:
+            self._ensure_index(cols)
 
     def _ensure_index(self, build_cols: List[Column]):
         # the index is probe-invariant unless a build key is
@@ -245,7 +260,7 @@ class _JoinCore:
                 and int(self.build.num_rows) > 0
             ):
                 def build_span():
-                    def kernel(eq_bufs, num_rows):
+                    def join_keyspan(eq_bufs, num_rows):
                         live = (
                             jnp.arange(cap, dtype=jnp.int32) < num_rows
                         )
@@ -260,7 +275,7 @@ class _JoinCore:
                              kmax.astype(jnp.int64)]
                         )
 
-                    return kernel
+                    return join_keyspan
 
                 span_fn = cached_kernel(
                     ("join_keyspan", eq_layout, cap), build_span
@@ -281,7 +296,7 @@ class _JoinCore:
                     tsize_d = ht.direct_table_size(span)
 
                     def build_direct():
-                        def kernel(eq_bufs, base, num_rows):
+                        def join_index(eq_bufs, base, num_rows):
                             live = (
                                 jnp.arange(cap, dtype=jnp.int32)
                                 < num_rows
@@ -295,7 +310,7 @@ class _JoinCore:
                                 v, live, cap, base, tsize_d
                             )
 
-                        return kernel
+                        return join_index
 
                     dfn = cached_kernel(
                         ("join_table_direct", eq_layout, cap, tsize_d),
@@ -320,7 +335,7 @@ class _JoinCore:
 
         if scatter_ok and not self._table_demoted:
             def build_table():
-                def kernel(eq_bufs, num_rows):
+                def join_index(eq_bufs, num_rows):
                     live = jnp.arange(cap, dtype=jnp.int32) < num_rows
                     key_cols = _unflatten_eq(eq_layout, eq_bufs)
                     # NULL join keys never match: keep them (and the
@@ -343,7 +358,7 @@ class _JoinCore:
                     )
                     return tab, dup
 
-                return kernel
+                return join_index
 
             fn = cached_kernel(
                 ("join_table", eq_layout, cap, tsize, kr), build_table,
@@ -364,7 +379,7 @@ class _JoinCore:
         dtypes = tuple(d for _, _, d in bufs)
 
         def build():
-            def kernel(values, valids, num_rows):
+            def join_index(values, valids, num_rows):
                 cols = list(zip(values, valids, dtypes))
                 h = hash_columns_device(cols, cap).astype(jnp.int32)
                 # NULL keys hash like values and are rejected later by
@@ -382,7 +397,7 @@ class _JoinCore:
                 order = jnp.argsort(h, stable=True)
                 return jnp.take(h, order), order
 
-            return kernel
+            return join_index
 
         fn = cached_kernel(("join_index", dtypes, cap), build)
         h_sorted, order = fn(
@@ -528,7 +543,7 @@ class _JoinCore:
             p_eq_layout = _eq_layout(unified_p)
 
             def build_lookup():
-                def kernel(b_eq, p_eq, tab, num_rows):
+                def join_probe(b_eq, p_eq, tab, num_rows):
                     # num_rows=None: full probe batch (constant mask
                     # folds into the downstream selects)
                     live = (
@@ -547,7 +562,7 @@ class _JoinCore:
                         live, bcap,
                     )
 
-                return kernel
+                return join_probe
 
             fn = cached_kernel(
                 ("join_lookup", mode, b_eq_layout, p_eq_layout, bcap,
@@ -588,7 +603,7 @@ class _JoinCore:
         pdtypes = tuple(d for _, _, d in pbufs)
 
         def build_counts():
-            def kernel(values, valids, h_sorted, num_rows):
+            def join_probe(values, valids, h_sorted, num_rows):
                 cols = list(zip(values, valids, pdtypes))
                 h = hash_columns_device(cols, pcap).astype(jnp.int32)
                 lo = jnp.searchsorted(h_sorted, h, side="left")
@@ -598,7 +613,7 @@ class _JoinCore:
                 counts = jnp.where(live, counts, 0)
                 return counts, lo.astype(jnp.int32), jnp.sum(counts)
 
-            return kernel
+            return join_probe
 
         fn = cached_kernel(("join_counts", pdtypes, pcap), build_counts)
         counts, lo, total_dev = fn(
@@ -608,6 +623,7 @@ class _JoinCore:
             probe_cb.num_rows,
         )
         total = host_int(total_dev)
+        _count("join_pair_syncs", 1)
         pair_cap = max(get_config().bucket_for(total), 1)
         return (
             "sorted", probe_cb, unified_b, unified_p, counts, lo,
@@ -640,7 +656,7 @@ class _JoinCore:
         n_p = len(out_probe_cols)
 
         def build_emit():
-            def kernel(counts, lo, order, bkey_bufs, pkey_bufs,
+            def join_emit(counts, lo, order, bkey_bufs, pkey_bufs,
                        bout_bufs, pout_bufs, build_rows, probe_rows,
                        matched_build):
                 # ---- expand ----
@@ -707,7 +723,7 @@ class _JoinCore:
                 pout = gather(pout_bufs, p_layout, pair_p, pcap)
                 return bout, pout, valid, mp, mb
 
-            return kernel
+            return join_emit
 
         fn = cached_kernel(
             ("join_emit", k_layout, b_layout, p_layout, bcap, pcap,
@@ -748,7 +764,7 @@ class _JoinCore:
         b_layout = _eq_layout(out_build_cols)
 
         def build_emit():
-            def kernel(match_idx, matched, bout_bufs, probe_rows,
+            def join_emit(match_idx, matched, bout_bufs, probe_rows,
                        matched_build):
                 live_p = (
                     jnp.arange(pcap, dtype=jnp.int32) < probe_rows
@@ -774,7 +790,7 @@ class _JoinCore:
                         out.append(None)
                 return out, valid, mb
 
-            return kernel
+            return join_emit
 
         fn = cached_kernel(
             ("join_emit_table", b_layout, bcap, pcap,
@@ -963,16 +979,42 @@ class HashJoinExec(PhysicalOp):
                 )
             return self._build
 
+    def build_side(self, ctx: ExecContext, shared: bool = False
+                   ) -> Tuple[ColumnBatch, "_JoinCore"]:
+        """(the build relation, its core with the index built), before
+        the first probe batch: the stage `join_build` covers reading the
+        relation, its concatenation and the index with its blocking
+        scalar. `shared` keeps one core on the op for every partition
+        (the fused path, which needs no matched-build state). Leaves
+        `join_build_rows` in the task's metrics, and the join's other two
+        counts at 0."""
+        with (obs_trace.span("join_build") if obs_trace.ACTIVE
+              else obs_trace.NULL):
+            build = self._collect_build(ctx)
+            if shared:
+                with self._build_lock:
+                    core = getattr(self, "_fused_core", None)
+                    if core is None or core.build is not build:
+                        core = self._fused_core = _JoinCore(
+                            build, self.left_keys)
+            else:
+                core = _JoinCore(build, self.left_keys)
+            core.index_build()
+        _count("join_build_rows", int(build.num_rows))
+        _count("join_probe_batches", 0)
+        _count("join_pair_syncs", 0)
+        return build, core
+
     def execute(self, partition: int, ctx: ExecContext
                 ) -> Iterator[ColumnBatch]:
         left, right = self.children
         jt = self.join_type
-        build = self._collect_build(ctx)
-        core = _JoinCore(build, self.left_keys)
+        build, core = self.build_side(ctx)
         emit_pairs = jt in (
             JoinType.INNER, JoinType.LEFT, JoinType.RIGHT, JoinType.FULL
         )
         for pb in right.execute(partition, ctx):
+            _count("join_probe_batches", 1)
             state = core.probe(pb, self.right_keys)
             pb = state[1]
             bcols = build.columns if emit_pairs else []

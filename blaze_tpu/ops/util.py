@@ -154,11 +154,13 @@ def unify_dictionaries(batches: List[ColumnBatch]) -> List[ColumnBatch]:
 
 
 def concat_batches(batches: List[ColumnBatch],
-                   schema: Optional[Schema] = None) -> ColumnBatch:
+                   schema: Optional[Schema] = None,
+                   min_capacity: int = 0) -> ColumnBatch:
     """Concatenate live rows of many batches into one padded batch
     (pipeline-breaker materialization). Unifies string dictionaries.
-    Leaves `concat_slice_parts`, the parts written by the launch, in the
-    task's metrics (POLL)."""
+    The capacity is the row count's shape bucket, and at least
+    `min_capacity`. Leaves `concat_slice_parts`, the parts written by the
+    launch, in the task's metrics (POLL)."""
     batches = [ensure_compacted(b) for b in batches]
     batches = [b for b in batches if b.num_rows > 0]
     if not batches:
@@ -169,7 +171,7 @@ def concat_batches(batches: List[ColumnBatch],
     batches = unify_dictionaries(batches)
     schema = batches[0].schema
     total = sum(b.num_rows for b in batches)
-    cap = get_config().bucket_for(total)
+    cap = max(get_config().bucket_for(total), min_capacity)
     if len(batches) == 1 and batches[0].capacity == cap:
         return batches[0]  # already compact at the right bucket
     ncols = len(schema)

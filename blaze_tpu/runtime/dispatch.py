@@ -123,6 +123,14 @@ class task_scope:
         return False
 
 
+def count(name: str, k: int) -> None:
+    """Add `k` to the counter `name` of this thread's task's metrics
+    (POLL carries it), where the thread works for a task."""
+    ctx = current_task()
+    if ctx is not None:
+        ctx.metrics.add(name, k)
+
+
 def snapshot() -> Dict[str, int]:
     with _lock:
         return dict(_counts)

@@ -426,6 +426,15 @@ class Query:
             # parts a materialization wrote whole at their offsets, no
             # scatter (ops/util.py: concat_batches)
             out["concat_slice_parts"] = m["concat_slice_parts"]
+        if "join_build_rows" in m:
+            # a task with a broadcast hash join (ops/joins.py:
+            # HashJoinExec.build_side): the broadcast rows it indexed,
+            # the probe batches it joined on the device, and the
+            # blocking read-backs of a pair count (the sort core's, one
+            # a probe batch; 0 on the table core)
+            for k in ("join_build_rows", "join_probe_batches",
+                      "join_pair_syncs"):
+                out[k] = m.get(k, 0)
         if "mesh_group_runs" in m:
             # a task whose plan was lowered onto the mesh group-by
             # (parallel/mesh_ops.py): mesh programs that produced its
