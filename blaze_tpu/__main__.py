@@ -70,7 +70,8 @@ def cmd_info(args) -> int:
         "cores": {
             "group": resolve_core_choice(
                 "BLAZE_GROUP_CORE", cfg.group_core),
-            "join": resolve_core_choice("BLAZE_JOIN_CORE", cfg.join_core),
+            "join": resolve_core_choice("BLAZE_JOIN_CORE", cfg.join_core,
+                                        chip="direct"),
             "sort": resolve_core_choice("BLAZE_SORT_CORE", cfg.sort_core),
         },
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,

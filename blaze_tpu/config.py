@@ -59,9 +59,15 @@ class EngineConfig:
     # core has no chip measurement yet - resolve_core_choice below).
     # Env override: BLAZE_GROUP_CORE.
     group_core: str = "auto"
-    # Join-core selection for the unique-build fast path (hash-table
-    # probe, no sort/searchsorted/pair-expansion): same choices and
-    # rationale as group_core. Env override: BLAZE_JOIN_CORE.
+    # Join-core selection for the unique-build fast path (a table probe,
+    # no sort/searchsorted/pair-expansion/pair-count read-back): "scatter"
+    # (every table: the direct key->row array, the key|row table, the
+    # generic hash table), "sort" (hash sort + binary searches), or
+    # "auto": the scatter choice on the CPU backend; on a TPU the direct
+    # key->row array alone, for one unique integer key column whose span
+    # fits 1 << 24 slots, and the sort core for every other build (the
+    # key|row table's lookup does not compile for a v5e,
+    # tests/test_chip_compile.py). Env override: BLAZE_JOIN_CORE.
     join_core: str = "auto"
     # Multi-key argsort selection: "scatter" here means the packed-u64
     # single-lane value sort (one XLA sort per key); "sort" the 3-lane
@@ -93,25 +99,28 @@ class EngineConfig:
         return d
 
 
-def resolve_core_choice(env_var: str, cfg_value: str) -> str:
-    """Shared resolution for the grouping/join core knobs: env override
-    beats config; "auto" picks the scatter core on CPU (where the sort
-    it replaces costs 20-35x more) and the sort core on TPU, the
-    conservative choice until a pair of chip runs of the benchmark's
-    own grouped cell under BLAZE_GROUP_CORE says otherwise (ROADMAP
-    S4). Unknown values raise so a typo'd knob can't silently measure
-    the wrong core."""
+def resolve_core_choice(env_var: str, cfg_value: str, chip: str = "sort",
+                        backend: Optional[str] = None) -> str:
+    """Shared resolution for the grouping/join/sort core knobs: env
+    override beats config; "auto" picks the scatter core on the CPU
+    (where the sort it replaces costs 20-35x more) and `chip` on any
+    other backend (`backend`, default `jax.default_backend()`). `chip`
+    is "sort" for the grouping and sort cores, the conservative choice
+    until a pair of chip runs of the benchmark's own grouped cell under
+    BLAZE_GROUP_CORE says otherwise (ROADMAP S4); the join passes
+    "direct" (ops/joins.py: _join_core_choice). Unknown values raise so a
+    typo'd knob can't silently measure the wrong core."""
     mode = os.environ.get(env_var) or cfg_value
     if mode not in ("auto", "scatter", "sort"):
         raise ValueError(
             f"{env_var}/config must be auto|scatter|sort, got {mode!r}"
         )
     if mode == "auto":
-        import jax
+        if backend is None:
+            import jax
 
-        if jax.default_backend() == "cpu":
-            return "scatter"
-        return "sort"
+            backend = jax.default_backend()
+        return "scatter" if backend == "cpu" else chip
     return mode
 
 

@@ -656,6 +656,8 @@ class FusedAggregateExec(PhysicalOp):
 
             def probe_spec(raw):
                 _count("join_probe_batches", 1)
+                if mode == "table_direct":
+                    _count("join_direct_batches", 1)
                 pv = packed_view(raw)
                 if pv is not None:
                     # still-packed wire batch: the H2D buffer split

@@ -137,12 +137,16 @@ def _key_hash_cols(cols: List[Column]) -> List[Tuple]:
     return out
 
 
-def _join_core_choice() -> str:
-    """Join-core knob (config.join_core / env BLAZE_JOIN_CORE)."""
+def _join_core_choice(backend: Optional[str] = None) -> str:
+    """Join-core knob (config.join_core / env BLAZE_JOIN_CORE):
+    "scatter" (every table core), "sort", or "direct", what `auto`
+    takes off the CPU: the direct key->row array where the build
+    qualifies, else the sort core."""
     from blaze_tpu.config import resolve_core_choice
 
     return resolve_core_choice(
-        "BLAZE_JOIN_CORE", get_config().join_core
+        "BLAZE_JOIN_CORE", get_config().join_core, chip="direct",
+        backend=backend,
     )
 
 
@@ -159,7 +163,10 @@ class _JoinCore:
       capacity) and ONE emission kernel that only gathers the build
       side: probe columns pass through untouched. Duplicate build keys
       are detected at insert time (one scalar sync per build relation)
-      and demote to the sorted core.
+      and demote to the sorted core. Off the CPU only the direct
+      key->row array of this core is taken (`_join_core_choice`
+      "direct"): one unique integer key column whose span fits 1 << 24
+      slots; every other build takes the sorted core.
     - "sorted": build rows sort by key hash; per probe batch ONE
       counting kernel + ONE blocking scalar readback (the dynamic pair
       count picks the static output bucket) + ONE emission kernel that
@@ -214,29 +221,22 @@ class _JoinCore:
         ):
             return
         cap = self.build.capacity
+        choice = _join_core_choice()
 
         # one eligibility decision for both table attempts below: when
         # True, the first block always runs and defines eq_layout /
-        # tsize / kr / ht for the second
-        scatter_ok = (
+        # kr / ht for the second
+        tables_ok = (
             not self._table_demoted
-            and _join_core_choice() == "scatter"
+            and choice != "sort"
             # wide-decimal keys are host-tier work either way; the
             # sorted path below carries the NotImplementedError guard
             and not any(c.dtype.is_wide_decimal for c in build_cols)
         )
-        if scatter_ok:
+        if tables_ok:
             from blaze_tpu.ops import hash_table as ht
 
             eq_layout = _eq_layout(build_cols)
-            # size off the LIVE row count (host-known), not the padded
-            # shape-bucket capacity: a 131k-row dim table in a 1M
-            # bucket would otherwise get an 8M-slot table whose random
-            # gathers fall out of cache
-            tsize = ht.probe_table_size(
-                max(1, int(self.build.num_rows))
-            )
-
             kr = _kr_eligible(build_cols) and not self._force_generic
 
             # dense-domain dimension keys (TPC-DS surrogate keys are
@@ -245,9 +245,12 @@ class _JoinCore:
             # a direct key->row array. Probing drops from hash + probe
             # rounds over an 8x-oversized u64 table to ONE gather into
             # a 4-byte-per-slot array that fits in L2 (measured at
-            # 131k keys / 8M probes on XLA:CPU: 398ms -> 47ms).
+            # 131k keys / 8M probes on XLA:CPU: 398ms -> 47ms). Off the
+            # CPU it is the only table core (`direct`), for an integer
+            # key of any width.
             if (
-                kr
+                (kr or choice == "direct")
+                and not self._force_generic
                 and len(build_cols) == 1
                 and jnp.issubdtype(
                     build_cols[0].values.dtype, jnp.integer
@@ -290,9 +293,14 @@ class _JoinCore:
                 )
                 span = kmax - kmin + 1
                 nrows = int(self.build.num_rows)
-                # sparse domains would waste memory and cache; beyond
-                # 8x the row count (or 16M slots) the u64 table wins
-                if 0 < span <= min(1 << 24, max(4096, 8 * nrows)):
+                # on the CPU sparse domains would waste memory and
+                # cache: beyond 8x the row count (or 16M slots) the u64
+                # table wins. HBM gathers have no L2 to fall out of, so
+                # off the CPU the 16M slots (64 MiB) alone bound it
+                limit = 1 << 24
+                if choice != "direct":
+                    limit = min(limit, max(4096, 8 * nrows))
+                if 0 < span <= limit:
                     tsize_d = ht.direct_table_size(span)
 
                     def build_direct():
@@ -333,7 +341,15 @@ class _JoinCore:
                     # (don't re-pay an insert + sync on the kr table)
                     self._table_demoted = True
 
-        if scatter_ok and not self._table_demoted:
+        if choice == "scatter" and tables_ok and not self._table_demoted:
+            # size off the LIVE row count (host-known), not the padded
+            # shape-bucket capacity: a 131k-row dim table in a 1M
+            # bucket would otherwise get an 8M-slot table whose random
+            # gathers fall out of cache
+            tsize = ht.probe_table_size(
+                max(1, int(self.build.num_rows))
+            )
+
             def build_table():
                 def join_index(eq_bufs, num_rows):
                     live = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -463,6 +479,8 @@ class _JoinCore:
             index = self._index
         if index[0] not in ("table", "table_kr", "table_direct"):
             return None, probe_cb
+        if index[0] == "table_direct":
+            _count("join_direct_batches", 1)
         return (
             (probe_cb, unified_b, unified_p, index[1], index[0]),
             probe_cb,
@@ -576,6 +594,8 @@ class _JoinCore:
                 None if probe_cb.num_rows == pcap
                 else probe_cb.num_rows,
             )
+            if mode == "table_direct":
+                _count("join_direct_batches", 1)
             # NO host sync: output capacity is statically the probe
             # capacity (each probe row matches at most one build row)
             return (
@@ -631,15 +651,18 @@ class _JoinCore:
         )
 
     def emit_pairs(self, probe_state, out_build_cols: List[Column],
-                   out_probe_cols: List[Column], build_first: bool):
+                   out_probe_cols: List[Column], build_first: bool,
+                   fold_build: bool = True):
         """ONE kernel: expand candidate pairs, verify key equality, gather
         both sides' output columns, fold matched flags. Returns
         (out_columns, valid, pair_cap, matched_probe) and updates
-        matched_build."""
+        matched_build; `fold_build` False says the caller never reads
+        matched_build, and the table core's emission then holds no
+        scatter."""
         if probe_state[0] == "table":
             return self._emit_table(
                 probe_state, out_build_cols, out_probe_cols,
-                build_first,
+                build_first, fold_build,
             )
         (_tag, probe_cb, unified_b, unified_p, counts, lo, order,
          pair_cap) = probe_state
@@ -753,11 +776,13 @@ class _JoinCore:
         return out_cols, valid, pair_cap, matched_p
 
     def _emit_table(self, probe_state, out_build_cols: List[Column],
-                    out_probe_cols: List[Column], build_first: bool):
+                    out_probe_cols: List[Column], build_first: bool,
+                    fold_build: bool):
         """Table-core emission: output row i IS probe row i (unique
         build keys guarantee at most one match per probe row), so the
         probe columns pass through untouched and only the build side
-        gathers - plus one scatter to fold matched-build flags."""
+        gathers - plus, where the caller reads matched_build, one
+        scatter to fold the matched-build flags."""
         _tag, probe_cb, match_idx, matched, pair_cap = probe_state
         bcap = self.build.capacity
         pcap = probe_cb.capacity
@@ -771,12 +796,14 @@ class _JoinCore:
                 )
                 valid = matched & live_p
                 pair_b = jnp.clip(match_idx, 0, bcap - 1)
-                mb = matched_build | (
-                    jnp.zeros(bcap, jnp.int32)
-                    .at[pair_b]
-                    .add(valid.astype(jnp.int32), mode="drop")
-                    > 0
-                )
+                mb = None
+                if fold_build:
+                    mb = matched_build | (
+                        jnp.zeros(bcap, jnp.int32)
+                        .at[pair_b]
+                        .add(valid.astype(jnp.int32), mode="drop")
+                        > 0
+                    )
                 out = []
                 it = iter(bout_bufs)
                 for _, has_m in b_layout:
@@ -794,15 +821,17 @@ class _JoinCore:
 
         fn = cached_kernel(
             ("join_emit_table", b_layout, bcap, pcap,
-             len(out_build_cols)),
+             len(out_build_cols), fold_build),
             build_emit,
-            scatter_class=True,
+            scatter_class=fold_build,
         )
         bout, valid, mb = fn(
             match_idx, matched, _flatten_cols(out_build_cols),
-            probe_cb.num_rows, self.matched_build,
+            probe_cb.num_rows,
+            self.matched_build if fold_build else None,
         )
-        self.matched_build = mb
+        if fold_build:
+            self.matched_build = mb
         bcols = _rewrap_cols(out_build_cols, bout)
         pcols = list(out_probe_cols)
         if build_first:
@@ -986,8 +1015,8 @@ class HashJoinExec(PhysicalOp):
         relation, its concatenation and the index with its blocking
         scalar. `shared` keeps one core on the op for every partition
         (the fused path, which needs no matched-build state). Leaves
-        `join_build_rows` in the task's metrics, and the join's other two
-        counts at 0."""
+        `join_build_rows` in the task's metrics, and the join's other
+        three counts at 0."""
         with (obs_trace.span("join_build") if obs_trace.ACTIVE
               else obs_trace.NULL):
             build = self._collect_build(ctx)
@@ -1003,6 +1032,7 @@ class HashJoinExec(PhysicalOp):
         _count("join_build_rows", int(build.num_rows))
         _count("join_probe_batches", 0)
         _count("join_pair_syncs", 0)
+        _count("join_direct_batches", 0)
         return build, core
 
     def execute(self, partition: int, ctx: ExecContext
@@ -1020,7 +1050,8 @@ class HashJoinExec(PhysicalOp):
             bcols = build.columns if emit_pairs else []
             pcols = pb.columns if emit_pairs else []
             out_cols, valid, pair_cap, matched_p = core.emit_pairs(
-                state, bcols, pcols, build_first=True
+                state, bcols, pcols, build_first=True,
+                fold_build=jt in self._BUILD_EMITTING,
             )
             if emit_pairs:
                 yield ColumnBatch(
@@ -1242,7 +1273,8 @@ class SortMergeJoinExec(PhysicalOp):
         bcols = build.columns if emit else []
         pcols = probe.columns if emit else []
         out_cols, valid, pair_cap, matched_p = core.emit_pairs(
-            state, bcols, pcols, build_first=False
+            state, bcols, pcols, build_first=False,
+            fold_build=jt in (JoinType.RIGHT, JoinType.FULL),
         )
         live_p = row_mask(probe.num_rows, probe.capacity)
         if emit:

@@ -222,6 +222,7 @@ class StreamingSortMergeJoinExec(PhysicalOp):
                 entry.batch.columns if emit else [],
                 probe.columns if emit else [],
                 build_first=False,
+                fold_build=jt in (JoinType.RIGHT, JoinType.FULL),
             )
             matched_any = (
                 matched_p if matched_any is None

@@ -429,11 +429,12 @@ class Query:
         if "join_build_rows" in m:
             # a task with a broadcast hash join (ops/joins.py:
             # HashJoinExec.build_side): the broadcast rows it indexed,
-            # the probe batches it joined on the device, and the
-            # blocking read-backs of a pair count (the sort core's, one
-            # a probe batch; 0 on the table core)
+            # the probe batches it joined on the device, the blocking
+            # read-backs of a pair count (the sort core's, one a probe
+            # batch; 0 on the table core), and the probe batches the
+            # direct key->row array answered
             for k in ("join_build_rows", "join_probe_batches",
-                      "join_pair_syncs"):
+                      "join_pair_syncs", "join_direct_batches"):
                 out[k] = m.get(k, 0)
         if "mesh_group_runs" in m:
             # a task whose plan was lowered onto the mesh group-by

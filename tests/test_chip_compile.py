@@ -344,6 +344,163 @@ def test_q1_merge_concat(one_chip):
     assert " scatter(" not in text
 
 
+def _steer_join_to_the_chip(monkeypatch):
+    """The join core resolved as `auto` resolves it on a TPU, the sort
+    grouping core with it."""
+    from blaze_tpu.ops import joins
+
+    monkeypatch.delenv("BLAZE_JOIN_CORE", raising=False)
+    monkeypatch.setenv("BLAZE_GROUP_CORE", "sort")
+    monkeypatch.setattr(joins, "_join_core_choice",
+                        partial(joins._join_core_choice, backend="tpu"))
+
+
+def _on_chip(args, one_chip):
+    """The shapes of a program's arguments, placed on the described chip."""
+    def leaf(x):
+        a = jnp.asarray(x)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    return jax.tree_util.tree_map(leaf, args)
+
+
+def _broadcast(slots, rows=6000, bcap=8192):
+    """A broadcast relation of `rows` unique `int` keys whose span takes a
+    direct array of `slots` slots, with an `int` and a string column, in
+    a batch of capacity `bcap`."""
+    import pyarrow as pa
+
+    from blaze_tpu import ColumnBatch
+    from blaze_tpu.ops.hash_table import direct_table_size
+
+    step = (slots // 2 + 1) // rows + 1
+    keys = 2415022 + step * np.arange(rows, dtype=np.int32)
+    assert direct_table_size(int(keys[-1] - keys[0]) + 1) == slots
+    return ColumnBatch.from_arrow(pa.record_batch({
+        "k": keys, "year": (keys % 200).astype(np.int32),
+        "brand": pa.array([f"brand #{i % 97}" for i in range(rows)]),
+    }), capacity=bcap)
+
+
+@pytest.mark.parametrize("slots", [131072, 524288])
+def test_join_direct_programs(one_chip, monkeypatch, slots):
+    """The programs of a broadcast hash join on the direct key->row
+    array, as `_JoinCore` builds them on the chip (`auto`): the key span,
+    the array's one scatter, the lookup and the inner join's emission at
+    a probe batch of 16,384 nullable keys. None holds a `while` loop (the
+    sort core's binary searches, about 0.9 ms each on a v5e), and the
+    lookup and the emission hold no scatter: an inner
+    join reads no matched-build flags, so its emission folds none."""
+    import pyarrow as pa
+
+    from blaze_tpu import ColumnBatch
+    from blaze_tpu.ops import joins
+
+    _steer_join_to_the_chip(monkeypatch)
+    programs = {}
+    real = joins.cached_kernel
+
+    def recording(key, build, **kw):
+        fn = real(key, build, **kw)
+        body = build()
+
+        def call(*args):
+            programs[key[0]] = (body, args)
+            return fn(*args)
+
+        return call
+
+    monkeypatch.setattr(joins, "cached_kernel", recording)
+    build = _broadcast(slots)
+    rng = np.random.default_rng(38)
+    probe = ColumnBatch.from_arrow(pa.record_batch({
+        "p": pa.array(rng.integers(2415022, 2415022 + slots, 16384)
+                      .astype(np.int32), mask=rng.random(16384) < 0.045),
+        "v": rng.integers(0, 1000, 16384).astype(np.int32)}))
+    core = joins._JoinCore(build, [0])
+    core.index_build()
+    assert core._index[0] == "table_direct"
+    assert core._index[1][0].shape == (slots,)
+    state = core.probe(probe, [0])
+    core.emit_pairs(state, list(build.columns), list(probe.columns),
+                    build_first=True, fold_build=False)
+    assert set(programs) == {"join_keyspan", "join_table_direct",
+                             "join_lookup", "join_emit_table"}
+    texts = {
+        name: jax.jit(body).lower(*_on_chip(args, one_chip))
+        .compile().as_text()
+        for name, (body, args) in programs.items()
+    }
+    for name, text in texts.items():
+        assert " while(" not in text, name
+    assert " scatter(" in texts["join_table_direct"]
+    assert " scatter(" not in texts["join_lookup"]
+    assert " scatter(" not in texts["join_emit_table"]
+
+
+def test_join_direct_fused_aggregate(one_chip, monkeypatch, sales_parquet):
+    """The fused join+aggregate program (`FusedAggregateExec.
+    _build_join_probe_kernel`, through its packed-input form): the scan's
+    filter, the lookup in a direct array of 131,072 slots, the build
+    side's gather and a grouped SUM, one program a probe batch."""
+    from blaze_tpu.batch import packed_view
+    from blaze_tpu.exprs import AggExpr, AggFn, Col
+    from blaze_tpu.ops import (
+        AggMode, ExecContext, FilterExec, HashAggregateExec, HashJoinExec,
+        JoinType, MemoryScanExec,
+    )
+    from blaze_tpu.ops.fused import fuse_pipelines
+    from blaze_tpu.ops.joins import _eq_layout, _flatten_cols
+    from blaze_tpu.ops.parquet_scan import FileRange, ParquetScanExec
+
+    _steer_join_to_the_chip(monkeypatch)
+    build = _broadcast(131072, bcap=16384)
+    join = HashJoinExec(
+        MemoryScanExec([[build]], build.schema),
+        FilterExec(
+            ParquetScanExec([[FileRange(sales_parquet)]],
+                            projection=["ss_quantity", "ss_net_paid"]),
+            Col("ss_quantity") >= 21),
+        ["k"], ["ss_quantity"], JoinType.INNER)
+    fused = fuse_pipelines(HashAggregateExec(
+        join, keys=[(Col("year"), "year")],
+        aggs=[(AggExpr(AggFn.SUM, Col("ss_net_paid")), "s")],
+        mode=AggMode.COMPLETE,
+    )).children[0]
+    pleaf, ppipe = join._fused_probe
+    build_cb, core = join.build_side(ExecContext(), shared=True)
+    mode, tab = core.table_state_static(join.right_keys, ppipe.schema)
+    assert mode == "table_direct"
+    raw = next(iter(pleaf.execute(0, ExecContext())))
+    pv = packed_view(raw)
+    assert pv is not None and raw.capacity == 16384
+    b_eq = _flatten_cols([build_cb.columns[i] for i in join.left_keys])
+    kernel = fused._build_join_probe_kernel_packed(
+        pv, mode, build_cb.layout(), _eq_layout(
+            [build_cb.columns[i] for i in join.left_keys]),
+        tuple(join.right_keys), ppipe)
+    args = _on_chip((build_cb.device_buffers(), pv.buf, b_eq, tab),
+                    one_chip)
+    text = jax.jit(lambda b, buf, k, t: kernel(b, buf, k, t, None, None)) \
+        .lower(*args).compile().as_text()
+    assert " while(" not in text
+
+
+def test_join_kr_lookup_is_refused(one_chip):
+    """Why the chip takes only the direct array of the table core: the
+    key|row table's lookup (`hash_table.lookup_kr`) runs out of vector
+    memory in the compacted tail's prefix sum (`jnp.nonzero`), for the
+    table a 6,000-row broadcast gets and a 16,384-row probe batch."""
+    from blaze_tpu.ops import hash_table as ht
+
+    tsize = ht.probe_table_size(6000)
+    with pytest.raises(Exception) as info:
+        _compile(ht.lookup_kr, one_chip, ((tsize,), jnp.uint64),
+                 ((16384,), jnp.uint32), ((16384,), jnp.uint32),
+                 ((16384,), jnp.bool_))
+    assert "RESOURCE_EXHAUSTED" in str(info.value)
+
+
 def _shuffle_batch(table):
     """One 16,384-row batch shaped as the benchmark's shuffle cells
     scan it (`store_sales`: 23 columns, nullable `int` keys, decimal(7,2)

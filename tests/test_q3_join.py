@@ -2,8 +2,9 @@
 split probed against the broadcast date_dim and item relations, shipped
 as file segments in the task's blob, and summed by a string brand. The
 template's blob runs through `execute_task` and through the served path
-with its defaults, on the join core the chip takes (`sort`, with the sort
-grouping core) and on the CPU's (`scatter`), and its answer is the plain
+with its defaults, on the cores the chip takes (the direct key->row array
+for both broadcasts, the sort grouping core), on both sort cores and on
+the CPU's (`scatter`), and its answer is the plain
 reference's on seeded tables at the configuration's
 `rehearsal_split_rows`: a NULL date drops its sale, a group of NULL
 amounts alone sums to NULL, a brand comes back byte for byte. Every task
@@ -11,6 +12,7 @@ on one device, as the cell's chip has."""
 
 import json
 import os
+from functools import partial
 
 import numpy as np
 import pytest
@@ -88,14 +90,50 @@ def write(frame, tmp_path):
     return path
 
 
-@pytest.fixture(params=["sort", "scatter"])
+@pytest.fixture(params=["sort", "scatter", "direct"])
 def core(request, monkeypatch):
-    """The join and grouping cores: `sort` is what `auto` takes on a
-    TPU, `scatter` what it takes on the CPU."""
-    monkeypatch.setenv("BLAZE_JOIN_CORE", request.param)
-    monkeypatch.setenv("BLAZE_GROUP_CORE", request.param)
+    """The join and grouping cores: `scatter` is what `auto` takes on the
+    CPU; `direct` what it takes on a TPU (the join resolved for that
+    backend: the direct key->row array for both broadcasts, the sort
+    grouping core); `sort` both sort cores, what the TPU took before the
+    direct array. Under `direct` the broadcasts' join cores are kept on
+    the module's list `INDEXED`."""
+    from blaze_tpu.ops import joins
+
     monkeypatch.setenv("BLAZE_MESH_DEVICES", "1")
+    if request.param == "direct":
+        monkeypatch.delenv("BLAZE_JOIN_CORE", raising=False)
+        monkeypatch.setenv("BLAZE_GROUP_CORE", "sort")
+        monkeypatch.setattr(joins, "_join_core_choice",
+                            partial(joins._join_core_choice, backend="tpu"))
+        build_side = joins.HashJoinExec.build_side
+
+        def kept(op, ctx, shared=False):
+            build, core_ = build_side(op, ctx, shared)
+            INDEXED.append(core_)
+            return build, core_
+
+        INDEXED.clear()
+        monkeypatch.setattr(joins.HashJoinExec, "build_side", kept)
+    else:
+        monkeypatch.setenv("BLAZE_JOIN_CORE", request.param)
+        monkeypatch.setenv("BLAZE_GROUP_CORE", request.param)
     return request.param
+
+
+INDEXED = []
+
+
+def check_join_counts(m, core, batches):
+    """Two joins, each probed once a scanned batch: a pair count read
+    back for each on the sort core, each answered by the direct array
+    under `direct`, whose two broadcasts are both indexed so."""
+    assert m["join_probe_batches"] == 2 * batches
+    assert m["join_pair_syncs"] == (2 * batches if core == "sort" else 0)
+    assert m["join_direct_batches"] == (
+        2 * batches if core == "direct" else 0)
+    if core == "direct":
+        assert [c._index[0] for c in INDEXED] == ["table_direct"] * 2
 
 
 @pytest.mark.parametrize("how", ["null_date", "null_amounts", "non_ascii"])
@@ -132,9 +170,7 @@ def test_task_answers_the_reference(split, core, how, tmp_path,
     rel = q3_join.relations(PARAMS)
     assert m["join_build_rows"] == len(rel["date_dim"]["d_date_sk"]) \
         + len(rel["item"]["i_item_sk"])
-    # two joins, each probed once a scanned batch
-    assert m["join_probe_batches"] == 2 * batches
-    assert m["join_pair_syncs"] == (2 * batches if core == "sort" else 0)
+    check_join_counts(m, core, batches)
 
 
 def test_served_task_polls_the_join(split, core, tmp_path):
@@ -155,8 +191,7 @@ def test_served_task_polls_the_join(split, core, tmp_path):
         "groups_wrong": 0, "answer_shape_wrong": 0}
     assert poll["state"] == "DONE" and not poll.get("degraded")
     assert poll["dispatches"] > 0 and not poll.get("cache_hits")
-    assert poll["join_probe_batches"] == 2 * batches
-    assert poll["join_pair_syncs"] == (2 * batches if core == "sort" else 0)
+    check_join_counts(poll, core, batches)
     assert poll["join_build_rows"] > 6000
     assert poll["stages"]["join_build"]["n"] == 2
     assert poll["stages"]["join_build"]["wall_s"] > 0
